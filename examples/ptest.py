@@ -32,11 +32,6 @@ def main():
             "datasets"
         )
 
-    import jax
-
-    if os.environ.get("JAX_PLATFORMS"):
-        jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
-
     from mpit_tpu.run import run
 
     r = run(cfg)

@@ -14,6 +14,16 @@ reference's rank-0-construct + bcast.
 The protocol body is `mpit_tpu.parallel.ps_roles.client_train_loop` — the
 same code the thread-mode AsyncPSTrainer runs, so both modes are
 protocol-identical by construction.
+
+Devices: an accelerator chip belongs to one process, so the ranks split the
+host before any of them initializes a jax backend. Server ranks are
+numpy-only and run on the CPU platform; client ``c`` claims the host's
+``c``-th TPU chip alone (``TPU_VISIBLE_CHIPS``). A host with fewer chips than
+clients cannot run this shape: the client without a chip exits non-zero
+saying so, and the launcher takes the world down with it (never a hang) —
+run the clients as threads instead (``examples/ptest.py --algo ps-easgd``).
+With ``JAX_PLATFORMS=cpu``, or on a host without TPU device files, every
+rank is a plain process on jax's default platform and nothing is claimed.
 """
 
 import os
@@ -22,15 +32,70 @@ import sys
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
+def _host_chips() -> int:
+    """How many TPU chips this host exposes as device files (v5e:
+    ``/dev/vfio/N``; earlier generations: ``/dev/accelN``). Counted
+    without jax: whoever asks jax for the devices takes them all."""
+    import glob
+
+    return sum(
+        len(glob.glob(pattern))
+        for pattern in ("/dev/vfio/[0-9]*", "/dev/accel[0-9]*")
+    )
+
+
+def _claim_device(rank: int, num_servers: int) -> str:
+    """Give this rank a device no other rank will touch. Must run before
+    the first jax computation (the platform choice is sticky and libtpu
+    reads the chip list once, when the backend initializes). Returns a
+    description for the rank's first output line."""
+    if os.environ.get("JAX_PLATFORMS", "").strip().lower() == "cpu":
+        return "cpu (JAX_PLATFORMS=cpu)"
+    chips = _host_chips()
+    if not chips:
+        return "jax default platform (no TPU device files on this host)"
+    if rank < num_servers:
+        from mpit_tpu.utils.vmesh import repin_platform
+
+        repin_platform("cpu")
+        return "cpu (pserver is numpy-only)"
+    client = rank - num_servers
+    if client >= chips:
+        raise SystemExit(
+            f"rank {rank}: pclient {client} has no chip — this host exposes "
+            f"{chips} TPU chip(s) and a chip belongs to one process; use "
+            "at most that many client ranks, run the clients as threads "
+            "(examples/ptest.py --algo ps-easgd), or set JAX_PLATFORMS=cpu"
+        )
+    # TPU_VISIBLE_CHIPS indexes the chips libtpu enumerates (0-based,
+    # whatever their device files are numbered: the one-chip v5e machine
+    # exposes /dev/vfio/3 and its chip is index 0); the one-chip process
+    # bounds keep libtpu from waiting for the host's other chips
+    os.environ["TPU_VISIBLE_CHIPS"] = str(client)
+    os.environ["TPU_CHIPS_PER_PROCESS_BOUNDS"] = "1,1,1"
+    os.environ["TPU_PROCESS_BOUNDS"] = "1,1,1"
+    return f"TPU chip {client} of {chips}"
+
+
 def main():
     from mpit_tpu.utils.config import TrainConfig
 
     cfg = TrainConfig.from_args(description=__doc__)
+    try:
+        rank = int(os.environ["MPIT_RANK"])
+        world = int(os.environ["MPIT_WORLD_SIZE"])
+    except KeyError:
+        raise SystemExit(
+            "MPIT_RANK/MPIT_WORLD_SIZE not set — run under "
+            "`python -m mpit_tpu.launch -n N examples/ptest_proc.py ...`"
+        )
+    claimed = _claim_device(rank, cfg.servers)
 
     import jax
 
-    if os.environ.get("JAX_PLATFORMS"):
-        jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
+    device = jax.devices()[0]
+    print(f"rank {rank}: {device.platform} device {device} [{claimed}]",
+          flush=True)
 
     import jax.numpy as jnp
     import numpy as np
@@ -50,14 +115,6 @@ def main():
     )
     from mpit_tpu.utils.params import flatten_params, unflatten_params
 
-    try:
-        rank = int(os.environ["MPIT_RANK"])
-        world = int(os.environ["MPIT_WORLD_SIZE"])
-    except KeyError:
-        raise SystemExit(
-            "MPIT_RANK/MPIT_WORLD_SIZE not set — run under "
-            "`python -m mpit_tpu.launch -n N examples/ptest_proc.py ...`"
-        )
     num_servers = cfg.servers
     num_clients = world - num_servers
     if num_clients < 1:

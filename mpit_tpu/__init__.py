@@ -33,7 +33,6 @@ reference file:line):
 
 __version__ = "0.1.0"
 
-import mpit_tpu.compat  # noqa: F401  (must precede any jax.shard_map use)
 from mpit_tpu.comm import (  # noqa: F401
     Topology,
     init,

@@ -5,7 +5,7 @@ applies inline suppressions and the checked-in baseline, and returns
 :class:`~mpit_tpu.analysis.findings.Finding` lists. The analysis modules
 are stdlib-only: scanned code is parsed, never imported, and no jax
 BACKEND is ever initialized (the parent package's import does pull in the
-jax module for its compat shims, but linting touches no devices) — safe
+jax module, but linting touches no devices) — safe
 for pre-commit hooks and bare CI containers.
 
 Suppression layers, outermost first:

@@ -3,7 +3,7 @@
 The reference fed Torch tensors from host RAM synchronously inside its
 training loop (SURVEY.md §2 comp. 8) — fine for a CPU-bound Lua harness,
 but on TPU a synchronous host→device copy in the step path serializes the
-PCIe/tunnel transfer with the compute. The TPU-native pattern is to stage
+host-link transfer with the compute. The TPU-native pattern is to stage
 upcoming batches into HBM *while the current step runs*: ``jax.device_put``
 is asynchronous (it returns immediately and the transfer proceeds in the
 background), so holding a small deque of already-dispatched batches ahead

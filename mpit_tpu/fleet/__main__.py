@@ -5,7 +5,9 @@
 
 drives one workload through the router + N replicas (threads over the
 in-process broker by default; ``--procs`` spawns each replica as an OS
-process speaking the framed ``SocketTransport``, the deployment shape),
+process speaking the framed ``SocketTransport``, the deployment shape —
+those processes are pinned to the CPU platform, said so at start and in
+the report's ``replica_platform``),
 writes the router's lifecycle journal into ``--out``, and prints one
 JSON report line. Chain::
 
@@ -156,7 +158,11 @@ def _proc_harness(out, max_batch, segment, model_seed, **kwargs):
             env["MPIT_RANK"] = str(rank)
             env["MPIT_WORLD_SIZE"] = str(len(self._addrs))
             env["MPIT_TRANSPORT_HOSTS"] = self._hosts
-            env.setdefault("JAX_PLATFORMS", "cpu")
+            # one process for each chip: this parent built params with
+            # jax and holds the accelerator, so a replica process that
+            # reached for it would fail or hang. Replica processes are
+            # CPU processes — announced in _main_run and in the report.
+            env["JAX_PLATFORMS"] = "cpu"
             cmd = [
                 sys.executable, "-m", "mpit_tpu.fleet", "replica",
                 "--seed", str(model_seed),
@@ -296,6 +302,13 @@ def _main_run(argv) -> int:
         use_controller=ns.controller,
     )
     if ns.procs:
+        print(
+            "[fleet] --procs: replica processes are pinned to "
+            "JAX_PLATFORMS=cpu (this process holds the accelerator, and a "
+            "chip serves one process); the in-process replicas (no "
+            "--procs) run on this process's device",
+            file=sys.stderr,
+        )
         harness = _proc_harness(
             ns.out, ns.max_batch, ns.segment, ns.seed, **common
         )
@@ -328,6 +341,11 @@ def _main_run(argv) -> int:
         )
     )
     report["replica_count"] = ns.replicas
+    import jax
+
+    report["replica_platform"] = (
+        "cpu" if ns.procs else jax.default_backend()
+    )
     report["router_policy"] = (
         ns.policy or os.environ.get("MPIT_FLEET_POLICY", "p2c")
     )

@@ -41,7 +41,8 @@ class Block(nn.Module):
     moe_top_k: int = 1
     # single-device attention implementation: "xla" (fused dense),
     # "flash" (pallas kernels both directions on TPU, dense elsewhere),
-    # "flash_force" (pallas everywhere — interpret mode off TPU; tests)
+    # "flash_force" (pallas everywhere — interpret mode on CPU; tests).
+    # Where the kernel is selected a T that does not tile raises
     attn_impl: str = "xla"
     # sequence-parallel scheme when seq_axis is set — see TransformerLM
     seq_impl: str = "ring"

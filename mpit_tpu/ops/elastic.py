@@ -11,8 +11,10 @@ arithmetic while the bandwidth is the bound. Grid: 1-D over row-blocks of a
 /opt/skills/guides/pallas_guide.md). α is compile-time static (a config
 constant), so it folds into the kernel.
 
-`interpret=True` runs the same kernel on CPU (tests); the public wrapper
-falls back to plain XLA elementwise ops when pallas is unusable.
+`interpret=True` runs the same kernel on CPU (tests). The public wrapper
+picks plain XLA elementwise ops off-TPU only when no kernel was asked for
+(``use_pallas=None``); an explicit ``use_pallas=True`` runs the kernel —
+compiled on TPU, interpreted on CPU — or raises.
 
 Naming: "elastic" here is EASGD's elastic *force* — the update math.
 Elastic *membership* (ranks joining/leaving/preempted mid-run) is
@@ -38,11 +40,21 @@ BLOCK_ROWS = 512  # 512×128 f32 = 256 KiB per operand block in VMEM
 
 
 def pallas_supported() -> bool:
-    """True when the pallas TPU path can run natively here."""
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+    """True when the pallas TPU path can run natively here. A backend that
+    fails to initialize raises — it must not read as "not a TPU"."""
+    return jax.default_backend() == "tpu"
+
+
+def pallas_interpret() -> bool:
+    """``interpret=`` for a kernel that was asked for: compiled on TPU,
+    interpreted on CPU (the tests), an error anywhere else."""
+    backend = jax.default_backend()
+    if backend not in ("tpu", "cpu"):
+        raise RuntimeError(
+            f"pallas TPU kernel requested on backend {backend!r}: it "
+            "compiles for tpu and interprets on cpu only"
+        )
+    return backend == "cpu"
 
 
 def _kernel(alpha, x_ref, c_ref, d_ref, newx_ref, newc_ref):
@@ -87,17 +99,16 @@ def elastic_update(x, center, total_diff, alpha: float, use_pallas=None):
     Args:
       x, center, total_diff: same-shape arrays (any rank).
       alpha: elastic coupling (static).
-      use_pallas: True = require the kernel (interpret-mode off TPU raises
-        only if pallas itself is unavailable), False = plain XLA, None =
-        kernel on TPU, XLA elsewhere.
+      use_pallas: True = require the kernel (compiled on TPU, interpret
+        mode on CPU, an error on any other backend), False = plain XLA,
+        None = kernel on TPU, XLA elsewhere.
     """
     if use_pallas is None:
         use_pallas = pallas_supported()
     if use_pallas:
-        interpret = not pallas_supported()
         return _elastic_pallas(
             jnp.asarray(x), jnp.asarray(center), jnp.asarray(total_diff),
-            float(alpha), interpret,
+            float(alpha), pallas_interpret(),
         )
     new_x = x - alpha * (x - center)
     new_c = center + alpha * total_diff

@@ -26,13 +26,23 @@ Kernel structure (the canonical pallas flash shape,
   ``m``/``l`` live lane-broadcast in (block_q, 128) scratch (the TPU
   f32 tile's lane width).
 
-`interpret=True` runs the same kernel on CPU (the correctness tests);
-the public wrapper falls back to plain XLA dense attention when pallas
-cannot run natively and a kernel wasn't explicitly requested. Default
-OFF in the model (``attn_impl="xla"``) until the TPU measurement lands —
-the elastic-update kernel taught us XLA's fusion can beat a pallas
-kernel (ops/elastic.py's 2.7× finding), so the switch stays
-evidence-gated.
+The log-sum-exp residual follows the layout the official TPU kernels
+use (``jax.experimental.pallas.ops.tpu.splash_attention``), because
+Mosaic tiles the last two block dimensions as (8, 128): the forward
+writes it lane-broadcast as ``(B·H, T, 128)`` and keeps lane 0; the dQ
+kernel reads it as ``(B·H, 1, T)`` rows turned into a column, the
+transposed dK/dV kernel as ``(B·H, 8, T)`` sublane-broadcast rows. A
+``(1, block_q)`` block of a 2-D ``(B·H, T)`` array — the first version
+of this file — is refused by the TPU lowering whenever ``B·H > 1``.
+
+`interpret=True` runs the same kernel on CPU (the correctness tests).
+The public wrapper picks plain XLA dense attention off-TPU only when no
+kernel was asked for (``use_pallas=None``); once the kernel is selected
+it runs — compiled on TPU, interpreted on CPU — or raises, and a ``T``
+that does not tile is an error, never a silent dense pass. Default OFF
+in the model (``attn_impl="xla"``): no timing against XLA's fused
+attention exists yet (PERF.md), and the elastic-update kernel taught us
+XLA's fusion can beat a pallas kernel (ops/elastic.py).
 """
 
 from __future__ import annotations
@@ -44,12 +54,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from mpit_tpu.ops.elastic import pallas_supported
+from mpit_tpu.ops.elastic import pallas_interpret, pallas_supported
 from mpit_tpu.ops.ring_attention import dense_attention
 
 _NEG_INF = float("-inf")
 _LANE = 128
-
+_SUBLANE = 8
 
 
 def _apply_causal(s, q_off, k_off, q_axis: int):
@@ -138,7 +148,7 @@ def _kernel(
             ),
             jnp.inf,
         )
-        lse_ref[0] = lse[:, 0]
+        lse_ref[0] = jnp.broadcast_to(lse, lse_ref.shape[1:])
 
 
 def _dq_kernel(
@@ -164,8 +174,8 @@ def _dq_kernel(
         k = k_ref[0]
         v = v_ref[0]
         do = do_ref[0].astype(jnp.float32)
-        lse = lse_ref[0][:, None]  # (bq, 1)
-        dd = dd_ref[0][:, None]
+        lse = lse_ref[0, 0][:, None]  # (1, bq) row block -> (bq, 1)
+        dd = dd_ref[0, 0][:, None]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -213,8 +223,8 @@ def _dkv_kernel(
         k = k_ref[0]
         v = v_ref[0]
         do = do_ref[0].astype(jnp.float32)
-        lse = lse_ref[0][None, :]  # (1, bq)
-        dd = dd_ref[0][None, :]
+        lse = lse_ref[0][:1, :]  # (8, bq) sublane-broadcast -> (1, bq)
+        dd = dd_ref[0][:1, :]
         st = jax.lax.dot_general(
             k, q, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -262,8 +272,13 @@ def _flash_pallas_bwd(q, k, v, out, lse, ct, causal, block_q, block_k,
     q_spec = lambda ax: pl.BlockSpec(
         (1, block_q, d), lambda bh, a, b_: (bh, a if ax == 1 else b_, 0)
     )
-    row_spec = lambda ax: pl.BlockSpec(
-        (1, block_q), lambda bh, a, b_: (bh, a if ax == 1 else b_)
+    # per-row residuals as lane-major rows: one row for the q-major dQ
+    # kernel, sublane-broadcast for the transposed dK/dV kernel
+    row_spec = lambda ax, rows: pl.BlockSpec(
+        (1, rows, block_q), lambda bh, a, b_: (bh, 0, a if ax == 1 else b_)
+    )
+    rows_of = lambda a, rows: jnp.broadcast_to(
+        a[:, None, :], (b * h, rows, t)
     )
     kv_spec = lambda ax: pl.BlockSpec(
         (1, block_k, d), lambda bh, a, b_: (bh, a if ax == 1 else b_, 0)
@@ -277,13 +292,13 @@ def _flash_pallas_bwd(q, k, v, out, lse, ct, causal, block_q, block_k,
         grid=(b * h, n_q, n_k),
         in_specs=[
             q_spec(1), kv_spec(2), kv_spec(2), q_spec(1),
-            row_spec(1), row_spec(1),
+            row_spec(1, 1), row_spec(1, 1),
         ],
         out_specs=q_spec(1),
         out_shape=jax.ShapeDtypeStruct((b * h, t, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
-    )(q2, k2, v2, do2, lse, dd)
+    )(q2, k2, v2, do2, rows_of(lse, 1), rows_of(dd, 1))
 
     dk, dv = pl.pallas_call(
         functools.partial(
@@ -293,7 +308,7 @@ def _flash_pallas_bwd(q, k, v, out, lse, ct, causal, block_q, block_k,
         grid=(b * h, n_k, n_q),
         in_specs=[
             q_spec(2), kv_spec(1), kv_spec(1), q_spec(2),
-            row_spec(2), row_spec(2),
+            row_spec(2, _SUBLANE), row_spec(2, _SUBLANE),
         ],
         out_specs=[kv_spec(1), kv_spec(1)],
         out_shape=[
@@ -305,7 +320,7 @@ def _flash_pallas_bwd(q, k, v, out, lse, ct, causal, block_q, block_k,
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
         interpret=interpret,
-    )(q2, k2, v2, do2, lse, dd)
+    )(q2, k2, v2, do2, rows_of(lse, _SUBLANE), rows_of(dd, _SUBLANE))
 
     return (
         _from2d(dq, b, h, t, d),
@@ -364,11 +379,11 @@ def _flash_pallas(q, k, v, causal, block_q, block_k, interpret):
         in_specs=[q_spec, kv_spec, kv_spec],
         out_specs=[
             pl.BlockSpec((1, block_q, d), lambda bh, i, j: (bh, i, 0)),
-            pl.BlockSpec((1, block_q), lambda bh, i, j: (bh, i)),
+            pl.BlockSpec((1, block_q, _LANE), lambda bh, i, j: (bh, i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b * h, t, d), q.dtype),
-            jax.ShapeDtypeStruct((b * h, t), jnp.float32),
+            jax.ShapeDtypeStruct((b * h, t, _LANE), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, _LANE), jnp.float32),  # running max m
@@ -377,7 +392,7 @@ def _flash_pallas(q, k, v, causal, block_q, block_k, interpret):
         ],
         interpret=interpret,
     )(q2, k2, v2)
-    return _from2d(out, b, h, t, d), lse
+    return _from2d(out, b, h, t, d), lse[..., 0]
 
 
 def flash_attention(
@@ -391,28 +406,41 @@ def flash_attention(
 ) -> jax.Array:
     """Tiled exact attention, ``(B, T, H, D) -> (B, T, H, D)``.
 
-    ``use_pallas``: True = require the kernel (interpret mode off TPU),
-    False = XLA dense attention, None = kernel on TPU, XLA elsewhere.
+    ``use_pallas``: True = require the kernel (compiled on TPU, interpret
+    mode on CPU), False = XLA dense attention, None = kernel on TPU, XLA
+    elsewhere.
 
     Fully trainable: the custom VJP runs the standard FlashAttention
     backward as pallas kernels too (P recomputed from the saved
     log-sum-exp; dQ and fused dK/dV passes), so no (T, T) score matrix
     materializes in either direction.
-    Falls back to dense whenever ``T`` does not tile cleanly — blocks
-    clamp to ``T`` for short sequences, but a clamped block must still
-    be sublane-aligned (a multiple of 8) and divide ``T`` — exactness
-    and compilable tiles are never traded for the kernel.
+
+    Blocks clamp to ``T`` for short sequences. Once the kernel is
+    selected, a ``T`` the blocks do not tile raises ``ValueError`` — it
+    never becomes a dense pass: a block must divide ``T`` and be
+    sublane-aligned (a multiple of 8), and compiled for the chip it must
+    also be lane-aligned (a multiple of 128) or span ``T``, because the
+    backward reads per-row residuals as ``(…, block_q)`` lane-major rows.
     """
     if use_pallas is None:
         use_pallas = pallas_supported()
+    if not use_pallas:
+        return dense_attention(q, k, v, causal=causal)
+    interpret = pallas_interpret()
     t = q.shape[1]
     block_q = min(block_q, t)
     block_k = min(block_k, t)
-    tiles = (
-        t % block_q == 0 and t % block_k == 0
-        and block_q % 8 == 0 and block_k % 8 == 0
-    )
-    if not use_pallas or not tiles:
-        return dense_attention(q, k, v, causal=causal)
-    interpret = not pallas_supported()
+    for name, blk in (("block_q", block_q), ("block_k", block_k)):
+        if t % blk or blk % 8:
+            raise ValueError(
+                f"flash_attention: T={t} does not tile with {name}={blk} "
+                f"(q shape {q.shape}): the block must divide T and be a "
+                "multiple of 8"
+            )
+        if not interpret and blk % _LANE and blk != t:
+            raise ValueError(
+                f"flash_attention: {name}={blk} at T={t} (q shape "
+                f"{q.shape}) cannot compile for TPU: a block must be a "
+                f"multiple of {_LANE} or span T"
+            )
     return _flash(q, k, v, causal, block_q, block_k, interpret)

@@ -6,9 +6,11 @@ jit over ICI), while this trainer preserves the reference's *runtime
 structure* — concurrent pserver/pclient actors exchanging tagged messages
 with real interleaving and unbounded staleness (BASELINE.json:7's
 "2 pclient + 1 pserver" shape). Clients run their τ local steps as
-jit-compiled XLA programs (one compiled function shared by all client
-threads — same shapes, one compile; the GIL is released inside XLA so
-clients genuinely overlap), and only flat numpy vectors cross the transport.
+jit-compiled XLA programs (one jitted function shared by all client
+threads — same shapes, one compile per device it runs on; the GIL is
+released inside XLA so clients genuinely overlap), each client thread on
+its own device where the process has several, and only flat numpy vectors
+cross the transport.
 """
 
 from __future__ import annotations
@@ -200,8 +202,8 @@ class AsyncPSTrainer:
         self.fetch_timeout = float(fetch_timeout)
         self.fetch_retries = int(fetch_retries)
         self.fault_log: Optional[FaultLog] = None
-        # one compiled local step shared by all client threads (same shapes,
-        # one compile; XLA releases the GIL so clients genuinely overlap)
+        # one jitted local step shared by all client threads (same shapes,
+        # one compile per device; XLA releases the GIL so clients overlap)
         self._local_step = ps_roles.make_local_step(
             model, optimizer, self.loss_fn
         )
@@ -355,6 +357,10 @@ class AsyncPSTrainer:
         errors: list[BaseException] = []
         clients: list = [None] * self.num_clients
         exchange_stats: list[dict] = [{} for _ in range(self.num_clients)]
+        # one device per client thread, round-robin over this process's
+        # devices: without it every client's arrays land on device 0 and
+        # the other chips of the host idle (default_device is per-thread)
+        local_devices = jax.local_devices()
 
         def client_main(c: int):
             client = None
@@ -374,13 +380,16 @@ class AsyncPSTrainer:
                 clients[c] = client
                 xs = shard_for_worker(x, c, self.num_clients)
                 ys = shard_for_worker(y, c, self.num_clients)
-                losses[c] = ps_roles.client_train_loop(
-                    client, self._local_step, self.optimizer, spec,
-                    xs, ys, steps, batch_size, self.tau, self.algo,
-                    self.alpha, seed=seed + 1000 + c,
-                    max_exchange_failures=self.max_exchange_failures,
-                    exchange_stats=exchange_stats[c],
-                )
+                with jax.default_device(
+                    local_devices[c % len(local_devices)]
+                ):
+                    losses[c] = ps_roles.client_train_loop(
+                        client, self._local_step, self.optimizer, spec,
+                        xs, ys, steps, batch_size, self.tau, self.algo,
+                        self.alpha, seed=seed + 1000 + c,
+                        max_exchange_failures=self.max_exchange_failures,
+                        exchange_stats=exchange_stats[c],
+                    )
                 client.stop()
             except BaseException as e:  # surface thread failures to caller
                 errors.append(e)
@@ -442,6 +451,10 @@ class AsyncPSTrainer:
                     off += n
         center_params = unflatten_params(spec, jnp.asarray(center_flat))
         stats = {
+            # the message plane that actually ran — "auto" resolves to the
+            # native broker only where its library builds or is prebuilt
+            "transport": type(raw_transports[0]).__name__,
+            "client_devices": [s.get("device") for s in exchange_stats],
             "server_counts": [dict(s.counts) for s in servers],
             # True iff every server restored a persisted center chunk —
             # the elastic-recovery signal a resumed job asserts on

@@ -10,8 +10,7 @@ replicated, and the gradient average is a single ``lax.pmean`` that XLA lowers
 to an ICI all-reduce fused into the step (no host round trip per step, unlike
 the reference's per-step MPI call from the Lua loop).
 
-Bucketed / quantized gradient exchange (docs/PERF.md "overlapped DP
-exchange"): when ``MPIT_DP_QUANT`` or ``MPIT_DP_BUCKET_BYTES`` engages it,
+Bucketed / quantized gradient exchange: when ``MPIT_DP_QUANT`` or ``MPIT_DP_BUCKET_BYTES`` engages it,
 the step is restructured into a program pipeline — one backward program
 that emits the gradient as size-targeted flat *buckets*, then per bucket a
 staged reduce-scatter + all-gather exchange whose wire hops are separate

@@ -10,7 +10,6 @@ profiler traces, checkpoint/resume.
 from __future__ import annotations
 
 import json
-import os
 import time
 from typing import Optional
 
@@ -577,9 +576,9 @@ def run(cfg: TrainConfig) -> dict:
                 prefetch=cfg.prefetch,
             )
         if metrics is not None:
-            # completion proof covering BOTH the final state and the last
-            # metrics (block_until_ready lies on this platform, and the
-            # loss alone would not prove the state update finished)
+            # completion barrier covering BOTH the final state and the
+            # last metrics (the loss alone would not prove the state
+            # update finished)
             force_completion(state, metrics)
     wall = time.perf_counter() - t_start
     trained = unit - start_unit
@@ -602,6 +601,14 @@ def run(cfg: TrainConfig) -> dict:
     results.update(
         accuracy=acc,
         final_loss=float(metrics["loss"]) if metrics is not None else None,
+        # the fewest distinct devices any leaf of the final state has
+        # shards (or replicas) on: == num_devices when every chip of the
+        # mesh holds its part of the state
+        state_min_devices=min(
+            len({s.device for s in leaf.addressable_shards})
+            for leaf in jax.tree.leaves(state)
+            if isinstance(leaf, jax.Array)
+        ),
         trained_units=trained,
         samples=samples,
         wall_s=wall,
@@ -697,6 +704,8 @@ def _run_async_ps(cfg, model, opt, x_tr, y_tr, x_te, y_te, log, results):
     results.update(
         accuracy=acc,
         final_loss=stats["mean_final_loss"],
+        transport=stats["transport"],
+        client_devices=stats["client_devices"],
         server_counts=stats["server_counts"],
         dead_clients=stats["dead_clients"],
         center_restored=stats["center_restored"],
@@ -724,11 +733,7 @@ def main(argv=None, description: Optional[str] = None) -> None:
         "count=8 JAX_PLATFORMS=cpu.",
     )
 
-    import jax
+    from mpit_tpu.utils.compile_cache import enable_compile_cache
 
-    if os.environ.get("JAX_PLATFORMS"):
-        # honor an explicit platform choice even when a sitecustomize
-        # pre-registered a hardware backend at interpreter start
-        jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
-
+    enable_compile_cache()
     print(json.dumps(run(cfg), default=repr))

@@ -108,8 +108,9 @@ class TrainConfig:
     # model here has batch statistics). Memory knob for big batches.
     grad_accum: int = 1
     # transformer dense-attention implementation: "xla" (fused dense) or
-    # "flash" (pallas tiled kernel on TPU; dense elsewhere) — the kernel
-    # stays opt-in until its TPU measurement lands (ops/flash_attention)
+    # "flash" (pallas tiled kernel on TPU, where a seq_len that does not
+    # tile raises; dense elsewhere) — opt-in: it compiles and matches on
+    # the chip (PERF.md) but has no timing against XLA yet
     attn_impl: str = "xla"
     # moe-sync only: expert count (sharded over the worker axis; must be
     # divisible by it) and the GShard capacity factor

@@ -1,8 +1,7 @@
 """Sweep LeNet EASGD round timing over (per-worker batch, tau) on the live
 backend; prints a JSON row per point (µs/round, samples/s/chip, MFU).
 
-Used to pick the headline bench operating point and to produce the README
-µs-per-round table (VERDICT round-1 item 3).
+Used to pick the headline bench operating point (per-worker batch, tau).
 """
 
 import json

@@ -482,7 +482,7 @@ class TestBenchGateDynamics:
         })
         assert bg.main(["--strict", str(tmp_path)]) == 0
         _bench_round(tmp_path, 3, {
-            **self.BASE, "platform_note": "tunnel dead",
+            **self.BASE, "platform": "tpu",
             "dynamics": {"staleness_p99": 50},
         })
         assert bg.main(["--strict", str(tmp_path)]) == 0

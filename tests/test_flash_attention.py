@@ -57,12 +57,15 @@ class TestFlashAttention:
             np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-5
         )
 
-    def test_untileable_length_falls_back_to_dense(self):
+    def test_untileable_length_raises_when_kernel_requested(self):
         # t=100 clamps the block to 100, which is not sublane-aligned
-        # (100 % 8 != 0) — the wrapper must take the dense path, never
-        # hand pallas an uncompilable tile
+        # (100 % 8 != 0). A kernel that was asked for must say so — a
+        # silent dense pass would let a run claim the kernel it never ran
         q, k, v = _qkv(t=100, seed=3)
-        got = flash_attention(q, k, v, causal=True, use_pallas=True)
+        with pytest.raises(ValueError, match="T=100 does not tile"):
+            flash_attention(q, k, v, causal=True, use_pallas=True)
+        # unrequested (None) off-TPU stays a selection: dense, any T
+        got = flash_attention(q, k, v, causal=True)
         want = dense_attention(q, k, v, causal=True)
         np.testing.assert_allclose(
             np.asarray(got), np.asarray(want), rtol=1e-6, atol=1e-6

@@ -618,8 +618,7 @@ class TestBenchGate:
         _bench_round(tmp_path, 1, {"metric": "m", "value": 1000.0,
                                    "platform": "tpu"})
         _bench_round(tmp_path, 2, {"metric": "m", "value": 1.0,
-                                   "platform": "cpu",
-                                   "platform_note": "smoke"})
+                                   "platform": "cpu"})
         assert bg.main(["--strict", str(tmp_path)]) == 0
         assert "not comparable" in capsys.readouterr().out
 

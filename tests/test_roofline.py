@@ -263,33 +263,55 @@ class TestBenchIntegration:
         assert abs(sum(ph.values()) - 1.0) <= 0.02
         assert ph["compute"] > 0
 
-    def test_backend_probe_cached_and_env_knob(self, monkeypatch):
+    def test_chip_smoke_refuses_cpu_before_any_leg(self):
+        """No TPU -> non-zero exit, no leg, no pass marker: the chip check
+        can never be satisfied by a CPU run."""
+        import subprocess
+        import sys
+
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        r = subprocess.run(
+            [sys.executable, os.path.join(repo, "chip_smoke.py")],
+            cwd=repo, capture_output=True, text=True, timeout=300,
+            env={**os.environ, "JAX_PLATFORMS": "cpu",
+                 "JAX_COMPILATION_CACHE_DIR": "/nonexistent/unused"},
+        )
+        assert r.returncode not in (0, None), r.stdout + r.stderr
+        assert "platform: cpu" in r.stdout
+        assert "leg " not in r.stdout and '"ok"' not in r.stdout
+        assert "no TPU" in r.stderr
+
+    def test_compile_cache_placement(self, monkeypatch):
+        """JAX_COMPILATION_CACHE_DIR set: jax reads it itself and the
+        helper sets nothing. Unset: one fixed in-checkout path."""
+        import jax
+
+        from mpit_tpu.utils import compile_cache
+
+        updates = []
+        monkeypatch.setattr(
+            jax.config, "update", lambda k, v: updates.append((k, v))
+        )
+        monkeypatch.setenv(compile_cache.CACHE_ENV, "/somewhere/else")
+        assert compile_cache.enable_compile_cache() == "/somewhere/else"
+        assert updates == []
+        monkeypatch.delenv(compile_cache.CACHE_ENV)
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        want = os.path.join(repo, ".jax_cache")
+        assert compile_cache.enable_compile_cache() == want
+        assert compile_cache.enable_compile_cache() == want  # never moves
+        assert updates == [("jax_compilation_cache_dir", want)] * 2
+
+    def test_peak_flops_keyed_by_exact_device_kind(self):
+        """An unknown accelerator is an error, not a row without mfu."""
+        import types
+
         import bench
 
-        from mpit_tpu.utils import vmesh
-
-        monkeypatch.setattr(bench, "_PROBE_CACHE", {})
-        monkeypatch.setenv("MPIT_BENCH_PROBE_TIMEOUT", "7")
-        monkeypatch.delenv("MPIT_BENCH_PROBE_SECONDS", raising=False)
-        calls = []
-
-        def fake_run_bounded(code, timeout=None, quiet=False):
-            calls.append(timeout)
-            return 1  # probe fails
-
-        monkeypatch.setattr(vmesh, "run_bounded", fake_run_bounded)
-        assert bench._backend_alive() is False
-        assert calls == [7.0, 7.0]  # env knob honored, both attempts
-        assert bench._backend_alive() is False
-        assert calls == [7.0, 7.0]  # cached: no re-probe this process
-        tag = bench._probe_tag()
-        assert tag["probe_seconds"] >= 0.0
-
-    def test_probe_seconds_survives_reexec_env(self, monkeypatch):
-        import bench
-
-        monkeypatch.setattr(bench, "_PROBE_CACHE", {})
-        monkeypatch.setenv("MPIT_BENCH_PROBE_SECONDS", "361.2")
-        assert bench._probe_tag() == {"probe_seconds": 361.2}
-        monkeypatch.setenv("MPIT_BENCH_PROBE_SECONDS", "")
-        assert bench._probe_tag() == {}
+        dev = lambda platform, kind: types.SimpleNamespace(
+            platform=platform, device_kind=kind
+        )
+        assert bench._peak_flops_per_chip(dev("cpu", "cpu")) is None
+        assert bench._peak_flops_per_chip(dev("tpu", "TPU v5 lite")) == 197e12
+        with pytest.raises(KeyError, match="TPU v5 lite pod"):
+            bench._peak_flops_per_chip(dev("tpu", "TPU v5 lite pod"))

@@ -126,8 +126,8 @@ def test_batches_shapes_and_determinism(mnist):
 
 
 class TestBucketedExchange:
-    """ISSUE-11 bucketed / quantized gradient exchange
-    (docs/PERF.md "overlapped DP exchange"): the staged bucket pipeline
+    """ISSUE-11 bucketed / quantized gradient exchange: the staged
+    bucket pipeline
     must reproduce the fused step, int8+EF must track it closely, and
     the armed path must journal honest roofline/dynamics records."""
 
