@@ -1,0 +1,7 @@
+"""optimizer_ms_unit: device time in the optimizer update a unit."""
+
+from benchmark.lib import program_spans
+
+
+def read(run):
+    return program_spans.scope_ms_unit(run, "optimizer")

@@ -21,6 +21,7 @@ from typing import Iterator, Optional
 
 import numpy as np
 
+from mpit_tpu.utils.profiling import span
 from mpit_tpu.data.synthetic import (
     synthetic_image_classification,
     synthetic_lm_corpus,
@@ -367,7 +368,9 @@ class Batches:
         n_full = len(self.x) // self.global_batch
         for b in range(n_full):
             idx = order[b * self.global_batch : (b + 1) * self.global_batch]
-            yield self.x[idx], self.y[idx]
+            with span("mpit.input.batch"):
+                batch = self.x[idx], self.y[idx]
+            yield batch
 
     def steps_per_epoch(self) -> int:
         return len(self.x) // self.global_batch

@@ -21,11 +21,14 @@ from typing import Any, Callable, Iterable, Iterator, Optional
 
 import jax
 
+from mpit_tpu.utils.profiling import span
+
 
 def prefetch_to_device(
     it: Iterable[Any],
     sharding,
     depth: int = 2,
+    first_unit: int = 1,
 ) -> Iterator[Any]:
     """Yield items of ``it`` (pytrees of host arrays) staged on device.
 
@@ -33,17 +36,23 @@ def prefetch_to_device(
     degrades to synchronous per-item staging. The sharding is applied to
     every array leaf. Each staged item costs its full HBM footprint until
     consumed — peak input memory is ``depth + 1`` items.
+
+    Each staging is a ``mpit.fit.stage`` span whose ``unit`` counts from
+    ``first_unit``: the number of the fit-loop unit the item is for, which
+    runs ``depth`` units after the staging.
     """
     if depth < 0:  # validate eagerly, not at first next()
         raise ValueError(f"depth must be >= 0, got {depth}")
-    return _prefetch_gen(it, sharding, depth)
+    return _prefetch_gen(it, sharding, depth, first_unit)
 
 
-def _prefetch_gen(it, sharding, depth) -> Iterator[Any]:
+def _prefetch_gen(it, sharding, depth, first_unit) -> Iterator[Any]:
     buf: deque = deque()
-    for item in it:
+    for unit, item in enumerate(it, first_unit):
         # device_put maps one sharding over every leaf of a pytree itself
-        buf.append(jax.device_put(item, sharding))
+        with span("mpit.fit.stage", unit=unit):
+            staged = jax.device_put(item, sharding)
+        buf.append(staged)
         if len(buf) > depth:
             yield buf.popleft()
     while buf:

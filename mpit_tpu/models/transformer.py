@@ -55,47 +55,55 @@ class Block(nn.Module):
 
     @nn.compact
     def __call__(self, x):
+        # jax.named_scope names the device's time by layer part (metadata
+        # only; forward and backward both carry it): "attention" is the
+        # attention arithmetic alone, "attn_proj" the projections around
+        # it with their LayerNorm and residual, "mlp" the feed-forward
         dt = self.compute_dtype
         h, d = self.num_heads, self.d_model // self.num_heads
-        y = nn.LayerNorm(dtype=dt)(x)
-        qkv = nn.Dense(3 * self.d_model, use_bias=False, dtype=dt)(y)
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        split = lambda a: a.reshape(*a.shape[:2], h, d)
-        q, k, v = split(q), split(k), split(v)
         if self.seq_impl not in ("ring", "ulysses"):
             raise ValueError(
                 f"seq_impl={self.seq_impl!r} must be 'ring' or 'ulysses'"
             )
-        if self.decode:
-            if self.seq_axis is not None or self.moe_experts:
-                raise ValueError(
-                    "decode mode is single-device dense-FFN only "
-                    "(seq_axis=None, moe_experts=0)"
-                )
-            att = self._cached_attention(q, k, v)
-        elif self.seq_axis is not None and self.seq_impl == "ulysses":
-            att = ulysses_attention(q, k, v, self.seq_axis, causal=True)
-        elif self.seq_axis is not None:
-            att = ring_attention(q, k, v, self.seq_axis, causal=True)
-        elif self.attn_impl in ("flash", "flash_force"):
-            from mpit_tpu.ops.flash_attention import flash_attention
-
-            att = flash_attention(
-                q, k, v, causal=True,
-                use_pallas=True if self.attn_impl == "flash_force"
-                else None,
+        if self.decode and (self.seq_axis is not None or self.moe_experts):
+            raise ValueError(
+                "decode mode is single-device dense-FFN only "
+                "(seq_axis=None, moe_experts=0)"
             )
-        else:
-            att = dense_attention(q, k, v, causal=True)
-        att = att.reshape(*att.shape[:2], self.d_model)
-        x = x + nn.Dense(self.d_model, use_bias=False, dtype=dt)(att)
-        y = nn.LayerNorm(dtype=dt)(x)
-        if self.moe_experts:
-            x = x + self._moe(y)
-        else:
-            y = nn.Dense(self.d_ff, dtype=dt)(y)
-            y = nn.gelu(y)
-            x = x + nn.Dense(self.d_model, dtype=dt)(y)
+        with jax.named_scope("attn_proj"):
+            y = nn.LayerNorm(dtype=dt)(x)
+            qkv = nn.Dense(3 * self.d_model, use_bias=False, dtype=dt)(y)
+            q, k, v = jnp.split(qkv, 3, axis=-1)
+            split = lambda a: a.reshape(*a.shape[:2], h, d)
+            q, k, v = split(q), split(k), split(v)
+        with jax.named_scope("attention"):
+            if self.decode:
+                att = self._cached_attention(q, k, v)
+            elif self.seq_axis is not None and self.seq_impl == "ulysses":
+                att = ulysses_attention(q, k, v, self.seq_axis, causal=True)
+            elif self.seq_axis is not None:
+                att = ring_attention(q, k, v, self.seq_axis, causal=True)
+            elif self.attn_impl in ("flash", "flash_force"):
+                from mpit_tpu.ops.flash_attention import flash_attention
+
+                att = flash_attention(
+                    q, k, v, causal=True,
+                    use_pallas=True if self.attn_impl == "flash_force"
+                    else None,
+                )
+            else:
+                att = dense_attention(q, k, v, causal=True)
+        with jax.named_scope("attn_proj"):
+            att = att.reshape(*att.shape[:2], self.d_model)
+            x = x + nn.Dense(self.d_model, use_bias=False, dtype=dt)(att)
+        with jax.named_scope("mlp"):
+            y = nn.LayerNorm(dtype=dt)(x)
+            if self.moe_experts:
+                x = x + self._moe(y)
+            else:
+                y = nn.Dense(self.d_ff, dtype=dt)(y)
+                y = nn.gelu(y)
+                x = x + nn.Dense(self.d_model, dtype=dt)(y)
         return x
 
     def _cached_attention(self, q, k, v):
@@ -421,11 +429,12 @@ class TransformerLM(nn.Module):
         # models (the equivalence-test configuration) this is bit-
         # identical to the previous all-f32 head.
         hdt = self._head_operand_dtype
-        table = embed.embedding.astype(hdt)
-        return jnp.einsum(
-            "btd,vd->btv", x.astype(hdt), table,
-            preferred_element_type=jnp.float32,
-        )
+        with jax.named_scope("head"):
+            table = embed.embedding.astype(hdt)
+            return jnp.einsum(
+                "btd,vd->btv", x.astype(hdt), table,
+                preferred_element_type=jnp.float32,
+            )
 
     def head_logits(self, params, h):
         """The tied vocab head applied to (B, d_model) hidden rows —
@@ -434,8 +443,9 @@ class TransformerLM(nn.Module):
         and kept only the rows they need (chunked prefill). The embed
         table's param path is pinned by a test against a full forward."""
         hdt = self._head_operand_dtype
-        table = params["Embed_0"]["embedding"].astype(hdt)
-        return jnp.einsum(
-            "bd,vd->bv", h.astype(hdt), table,
-            preferred_element_type=jnp.float32,
-        )
+        with jax.named_scope("head"):
+            table = params["Embed_0"]["embedding"].astype(hdt)
+            return jnp.einsum(
+                "bd,vd->bv", h.astype(hdt), table,
+                preferred_element_type=jnp.float32,
+            )

@@ -11,6 +11,7 @@ import numpy as np
 import optax
 
 from mpit_tpu.data.prefetch import prefetch_to_device
+from mpit_tpu.utils.profiling import remember_unit, span
 
 
 @flax.struct.dataclass
@@ -37,9 +38,10 @@ class TrainState:
 
 
 def cross_entropy_loss(logits: jax.Array, labels: jax.Array) -> jax.Array:
-    return optax.softmax_cross_entropy_with_integer_labels(
-        logits, labels
-    ).mean()
+    with jax.named_scope("loss"):
+        return optax.softmax_cross_entropy_with_integer_labels(
+            logits, labels
+        ).mean()
 
 
 def default_loss_fn(apply_fn: Callable) -> Callable:
@@ -245,9 +247,16 @@ def synced_fit_loop(
     log tag). Deterministic resume via ``start_epoch``/``skip_steps``
     (epoch index seeds the permutation); ``on_step(steps, state, metrics)``
     after every step; batches staged ``prefetch`` ahead with the step's own
-    sharding. Returns (state, last_metrics)."""
+    sharding. Returns (state, last_metrics).
+
+    One iteration is tiled by four host spans (``utils/profiling.span``),
+    each carrying ``unit``, the number of the step it works for:
+    ``mpit.fit.group`` (the batch check), ``mpit.fit.stage`` (the
+    ``device_put``, ``prefetch`` units early), ``mpit.fit.dispatch`` and
+    ``mpit.fit.callback``."""
     metrics = None
     steps = 0
+    grouped = 0
     # one host fetch up front so log lines can number steps across resume
     # without a per-step device round-trip (the pipeline trainer's state
     # is a dict, not a TrainState)
@@ -255,23 +264,33 @@ def synced_fit_loop(
     base_step = int(step_leaf) if log_every else 0
 
     def step_batches(e, to_skip):
+        nonlocal grouped
         for x, y in batches.epoch(e):
             if to_skip > 0:
                 to_skip -= 1
                 continue
-            check(x)
+            grouped += 1
+            with span("mpit.fit.group", unit=grouped):
+                check(x)
             yield x, y
 
     for e in range(start_epoch, epochs):
         to_skip = skip_steps if e == start_epoch else 0
         for x, y in prefetch_to_device(
-            step_batches(e, to_skip), sharding, depth=prefetch
+            step_batches(e, to_skip), sharding, depth=prefetch,
+            first_unit=steps + 1,
         ):
-            state, metrics = step_fn(state, x, y)
-            bound_cpu_dispatch(topo, metrics)
+            with span("mpit.fit.dispatch", unit=steps + 1):
+                state, metrics = step_fn(state, x, y)
+                bound_cpu_dispatch(topo, metrics)
+            if steps == 0:
+                # after the dispatch, so the device is at work meanwhile; the
+                # state that came out is what the next call takes
+                remember_unit(step_fn, state, x, y)
             steps += 1
             if on_step is not None:
-                on_step(steps, state, metrics)
+                with span("mpit.fit.callback", unit=steps):
+                    on_step(steps, state, metrics)
             # gate on the HOST counter: `int(state.step)` every step would
             # force a device round-trip per step
             if log_every and steps % log_every == 0:
@@ -385,7 +404,13 @@ class RoundTrainer:
         step (``device_put`` is async, so transfer overlaps compute); 0 =
         stage synchronously (each staged group holds its full HBM footprint,
         so large-input configs may need 0). Skipped resume rounds are never
-        staged."""
+        staged.
+
+        One iteration is tiled by four host spans (``utils/profiling.span``),
+        each carrying ``unit``, the number of the round it works for:
+        ``mpit.fit.group`` (stacking τ batches into a round-group),
+        ``mpit.fit.stage`` (its ``device_put``, ``prefetch`` rounds early),
+        ``mpit.fit.dispatch`` and ``mpit.fit.callback``."""
         if self.rounds_per_epoch(batches) == 0:
             raise ValueError(
                 f"epoch of {batches.steps_per_epoch()} step(s) < "
@@ -393,10 +418,11 @@ class RoundTrainer:
             )
         metrics = None
         rounds = 0
+        grouped = 0
         dropped = 0
 
         def round_groups(e, to_skip):
-            nonlocal dropped
+            nonlocal dropped, grouped
             buf_x, buf_y = [], []
             for x, y in batches.epoch(e):
                 buf_x.append(x)
@@ -406,9 +432,12 @@ class RoundTrainer:
                 if to_skip > 0:
                     to_skip -= 1
                 else:
-                    yield self.round_batches(
-                        np.stack(buf_x), np.stack(buf_y)
-                    )
+                    grouped += 1
+                    with span("mpit.fit.group", unit=grouped):
+                        group = self.round_batches(
+                            np.stack(buf_x), np.stack(buf_y)
+                        )
+                    yield group
                 buf_x, buf_y = [], []
             dropped += len(buf_x)
 
@@ -416,13 +445,20 @@ class RoundTrainer:
         for e in range(start_epoch, epochs):
             to_skip = skip_rounds if e == start_epoch else 0
             for xr, yr in prefetch_to_device(
-                round_groups(e, to_skip), sharding, depth=prefetch
+                round_groups(e, to_skip), sharding, depth=prefetch,
+                first_unit=rounds + 1,
             ):
-                state, metrics = self._round(state, xr, yr)
-                bound_cpu_dispatch(self.topo, metrics)
+                with span("mpit.fit.dispatch", unit=rounds + 1):
+                    state, metrics = self._round(state, xr, yr)
+                    bound_cpu_dispatch(self.topo, metrics)
+                if rounds == 0:
+                    # after the dispatch, so the device is at work meanwhile;
+                    # the state that came out is what the next call takes
+                    remember_unit(self._round, state, xr, yr)
                 rounds += 1
                 if on_round is not None:
-                    on_round(rounds, state, metrics)
+                    with span("mpit.fit.callback", unit=rounds):
+                        on_round(rounds, state, metrics)
                 if log_every and rounds % log_every == 0:
                     print(
                         f"[{self._log_tag}] round={rounds} "

@@ -33,6 +33,7 @@ from mpit_tpu.comm.topology import topology as _current_topology
 from mpit_tpu import goptim
 from mpit_tpu.comm.topology import Topology
 from mpit_tpu.parallel import common
+from mpit_tpu.utils.profiling import span
 
 
 @flax.struct.dataclass
@@ -113,18 +114,20 @@ class EASGDTrainer(common.RoundTrainer):
                 p, o = carry
                 bx, by = batch
                 loss, g = jax.value_and_grad(self.loss_fn)(p, bx, by)
-                updates, o = self.optimizer.update(g, o, p)
-                p = optax.apply_updates(p, updates)
+                with jax.named_scope("optimizer"):
+                    updates, o = self.optimizer.update(g, o, p)
+                    p = optax.apply_updates(p, updates)
                 return (p, o), loss
 
             (params, opt), losses = jax.lax.scan(
                 local_step, (params, opt), (x[0], y[0])
             )
-            params, center = goptim.easgd_round(
-                params, state.center, self.alpha, axis,
-                use_pallas=self.use_pallas,
-                compress_dtype=self.exchange_dtype,
-            )
+            with jax.named_scope("elastic"):
+                params, center = goptim.easgd_round(
+                    params, state.center, self.alpha, axis,
+                    use_pallas=self.use_pallas,
+                    compress_dtype=self.exchange_dtype,
+                )
             return (
                 EASGDState(
                     worker_params=_put0(params),
@@ -161,28 +164,31 @@ class EASGDTrainer(common.RoundTrainer):
         """All workers and the center start from identical params (the
         reference broadcast the initial model the same way, via rank-0
         construction + bcast)."""
-        if params is None:
-            params = self.model.init(rng, jnp.asarray(sample_x))["params"]
-        w = self.topo.num_workers
-        state = EASGDState(
-            worker_params=_stack(params, w),
-            worker_opt=_stack(self.optimizer.init(params), w),
-            center=params,
-            round=jnp.zeros((), jnp.int32),
-        )
-        shardings = EASGDState(
-            worker_params=jax.tree.map(
-                lambda _: self.topo.worker_sharding(), state.worker_params
-            ),
-            worker_opt=jax.tree.map(
-                lambda _: self.topo.worker_sharding(), state.worker_opt
-            ),
-            center=jax.tree.map(
-                lambda _: self.topo.replicated_sharding(), state.center
-            ),
-            round=self.topo.replicated_sharding(),
-        )
-        return jax.device_put(state, shardings)
+        with span("mpit.setup.init_state"):
+            if params is None:
+                params = self.model.init(rng, jnp.asarray(sample_x))["params"]
+            w = self.topo.num_workers
+            state = EASGDState(
+                worker_params=_stack(params, w),
+                worker_opt=_stack(self.optimizer.init(params), w),
+                center=params,
+                round=jnp.zeros((), jnp.int32),
+            )
+            shardings = EASGDState(
+                worker_params=jax.tree.map(
+                    lambda _: self.topo.worker_sharding(),
+                    state.worker_params,
+                ),
+                worker_opt=jax.tree.map(
+                    lambda _: self.topo.worker_sharding(), state.worker_opt
+                ),
+                center=jax.tree.map(
+                    lambda _: self.topo.replicated_sharding(), state.center
+                ),
+                round=self.topo.replicated_sharding(),
+            )
+            # waited for, so that the span reads set-up done, not dispatched
+            return jax.block_until_ready(jax.device_put(state, shardings))
 
     def center_params(self, state: EASGDState):
         return state.center

@@ -58,6 +58,7 @@ from mpit_tpu.comm.topology import topology as _current_topology
 from mpit_tpu.comm.topology import Topology
 from mpit_tpu.obs import core as obs_core
 from mpit_tpu.parallel import common
+from mpit_tpu.utils.profiling import span
 
 # bucket size target when bucketing is engaged without an explicit size:
 # big enough that hop dispatch overhead amortizes, small enough that a
@@ -223,12 +224,14 @@ class DataParallelTrainer:
         def train_step(state: common.TrainState, x, y):
             loss, grads = local_vg(state.params, x, y)
             # the one collective of the step: grad average over workers
-            grads = jax.lax.pmean(grads, axis)
-            loss = jax.lax.pmean(loss, axis)
-            updates, opt_state = self.optimizer.update(
-                grads, state.opt_state, state.params
-            )
-            params = optax.apply_updates(state.params, updates)
+            with jax.named_scope("grad_exchange"):
+                grads = jax.lax.pmean(grads, axis)
+                loss = jax.lax.pmean(loss, axis)
+            with jax.named_scope("optimizer"):
+                updates, opt_state = self.optimizer.update(
+                    grads, state.opt_state, state.params
+                )
+                params = optax.apply_updates(state.params, updates)
             return (
                 common.TrainState(
                     params=params, opt_state=opt_state, step=state.step + 1
@@ -252,11 +255,15 @@ class DataParallelTrainer:
     def init_state(self, rng, sample_x) -> common.TrainState:
         """Initialize replicated state. ``sample_x`` is a *per-worker* shaped
         batch (leading dim = per-worker batch); only shapes matter."""
-        variables = self.model.init(rng, jnp.asarray(sample_x))
-        state = common.TrainState.create(variables["params"], self.optimizer)
-        return jax.device_put(
-            state, self.topo.replicated_sharding()
-        )
+        with span("mpit.setup.init_state"):
+            variables = self.model.init(rng, jnp.asarray(sample_x))
+            state = common.TrainState.create(
+                variables["params"], self.optimizer
+            )
+            # waited for, so that the span reads set-up done, not dispatched
+            return jax.block_until_ready(
+                jax.device_put(state, self.topo.replicated_sharding())
+            )
 
     def _check(self, x) -> None:
         common.check_accum_batch(

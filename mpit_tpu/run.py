@@ -487,6 +487,7 @@ def run(cfg: TrainConfig) -> dict:
         MetricsLogger,
         force_completion,
         latest_checkpoint,
+        profiling,
         restore_checkpoint,
         save_checkpoint,
         trace,
@@ -620,6 +621,12 @@ def run(cfg: TrainConfig) -> dict:
                    "mean_s": wall / trained if trained else None},
         last_checkpoint=(latest_checkpoint(cfg.ckpt_dir)
                          if cfg.ckpt_dir else None),
+        # the host spans of fit and set-up (docs/OBSERVABILITY.md), without
+        # the ring of single durations: main() prints this dict as one line
+        spans={
+            name: {k: v for k, v in rec.items() if k != "last_s"}
+            for name, rec in profiling.snapshot().items()
+        },
     )
     log.close()
     return results

@@ -13,10 +13,9 @@ from mpit_tpu.utils.checkpoint import (  # noqa: F401
     list_checkpoints,
 )
 from mpit_tpu.utils.config import TrainConfig, PRESETS  # noqa: F401
-from mpit_tpu.utils.metrics import MetricsLogger, Throughput  # noqa: F401
+from mpit_tpu.utils.metrics import MetricsLogger  # noqa: F401
 from mpit_tpu.utils.profiling import (  # noqa: F401
-    StepTimer,
-    annotate,
     force_completion,
+    span,
     trace,
 )

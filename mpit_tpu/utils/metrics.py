@@ -93,24 +93,3 @@ class MetricsLogger:
 
     def __exit__(self, *exc) -> None:
         self.close()
-
-
-class Throughput:
-    """Rolling samples/sec counter for the step loop (host-side, cheap)."""
-
-    def __init__(self):
-        self._t0: Optional[float] = None
-        self._samples = 0
-
-    def tick(self, samples: int) -> Optional[float]:
-        """Record ``samples`` processed; returns current samples/sec (None on
-        the first tick, which only starts the clock)."""
-        now = time.perf_counter()
-        if self._t0 is None:
-            self._t0 = now
-            return None
-        self._samples += samples
-        return self._samples / (now - self._t0)
-
-    def reset(self) -> None:
-        self._t0, self._samples = None, 0

@@ -5,15 +5,24 @@ and prints. TPU plan from the survey: ``jax.profiler`` trace hooks plus
 per-step wall-clock counters — a captured trace opens in
 Perfetto/TensorBoard and shows the XLA op timeline, ICI collectives
 included, which is the observability the MPI version never had.
+
+``span`` is the package's one way to name host work (the fit loops, the
+prefetcher, ``Batches`` and ``init_state`` use it; docs/OBSERVABILITY.md has
+the names); ``trace`` captures a profile; ``unit_program_text`` is where a
+trace's device events get their ``jax.named_scope`` from.
 """
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import time
 from typing import Iterator, Optional
 
 import jax
+
+#: durations kept a span name (the newest; count/total/max cover them all)
+RING = 4096
 
 
 @contextlib.contextmanager
@@ -27,9 +36,140 @@ def trace(log_dir: Optional[str]) -> Iterator[None]:
         yield
 
 
-def annotate(name: str):
-    """Named region on the host trace timeline (wrap a step or a phase)."""
-    return jax.profiler.TraceAnnotation(name)
+class _Record:
+    """What the registry keeps for one span name."""
+
+    __slots__ = ("count", "total_s", "max_s", "ring")
+
+    def __init__(self):
+        self.count = 0
+        self.total_s = 0.0
+        self.max_s = 0.0
+        self.ring = collections.deque(maxlen=RING)
+
+
+# name -> _Record. No lock: a name has one writer (the thread that runs
+# ``fit`` or set-up), and a reader takes ``snapshot()`` between units.
+_registry: dict[str, _Record] = {}
+
+
+class span:
+    """``with span("mpit.fit.dispatch", unit=k):`` names a piece of host work.
+
+    It enters a ``jax.profiler.TraceAnnotation(name, **ids)``, so that while
+    a profiler runs the span lies in its trace on the clock of the device
+    planes (with ``ids`` as the event's stats), and costs a flag test while
+    none does; and it adds its ``time.perf_counter()`` duration to the
+    module's registry, which ``snapshot()`` returns. Always on.
+
+    A span never encloses a ``yield``: in a generator it wraps the work and
+    closes before the value is handed out, or it would time the consumer."""
+
+    __slots__ = ("name", "_annotation", "_t0")
+
+    def __init__(self, name: str, **ids):
+        self.name = name
+        self._annotation = jax.profiler.TraceAnnotation(name, **ids)
+
+    def __enter__(self) -> "span":
+        self._annotation.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        seconds = time.perf_counter() - self._t0
+        self._annotation.__exit__(*exc)
+        record = _registry.get(self.name)
+        if record is None:
+            record = _registry[self.name] = _Record()
+        record.count += 1
+        record.total_s += seconds
+        if seconds > record.max_s:
+            record.max_s = seconds
+        record.ring.append(seconds)
+
+
+def snapshot() -> dict:
+    """The registry as plain data: per span name ``count``, ``total_s``,
+    ``max_s`` and ``last_s``, the newest ``RING`` durations, oldest first."""
+    return {
+        name: {
+            "count": r.count,
+            "total_s": r.total_s,
+            "max_s": r.max_s,
+            "last_s": list(r.ring),
+        }
+        for name, r in _registry.items()
+    }
+
+
+def reset() -> None:
+    global _unit
+    _registry.clear()
+    _unit = None
+
+
+# (jitted program, its arguments as shapes) of the unit the newest fit loop
+# dispatches: what ``unit_program_text`` compiles again
+_unit = None
+
+
+def remember_unit(program, *args) -> None:
+    """Called by a fit loop after its first dispatch (it takes a few ms for
+    a state of hundreds of leaves, which a device at work hides), with the
+    unit's program and the arguments of its next call. Only shapes, dtypes
+    and shardings are kept, so no buffer outlives its donation; the program
+    (and the trainer it closes over) stays referenced until the next fit
+    loop or ``reset()``."""
+    global _unit
+    _unit = (
+        program,
+        jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(
+                a.shape, a.dtype, sharding=a.sharding
+            )
+            if isinstance(a, jax.Array)
+            else a,
+            args,
+        ),
+    )
+
+
+def unit_program_text() -> Optional[str]:
+    """The compiled text of the unit program the newest fit loop ran, or
+    None where none ran or the unit is no single jitted program (the
+    bucketed sync step). Its ``metadata={op_name="..."}`` is where an
+    instruction's ``jax.named_scope`` path is found: the v5e's profiler
+    trace names a device event by its instruction and carries no
+    ``op_name`` (PERF.md).
+
+    Compiled afresh, past two caches. The persistent compile cache's key
+    leaves metadata out, so an executable cached by another revision of the
+    source (the same arithmetic under other scopes) carries that revision's
+    names, in its text and in a profile alike; and ``Lowered.compile``
+    hands back the executable the fit loop's own call made (which may have
+    come from that cache) unless it is given compiler options, so it is
+    given one at its default value. The instructions are the same either
+    way; only a fresh compile names them as this source does. That costs the
+    unit's whole compile time (27 s for a GPT-2-small round on a v5e): call
+    it after a measured window, never inside one."""
+    if _unit is None or not hasattr(_unit[0], "lower"):
+        return None
+    from jax.experimental.compilation_cache import compilation_cache
+
+    program, args = _unit
+    cached = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()  # the flag is read once a cache's life
+    try:
+        return (
+            program.lower(*args)
+            .compile(compiler_options={"xla_dump_hlo_as_text": False})
+            .as_text()
+        )
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cached)
+        compilation_cache.reset_cache()
 
 
 def force_completion(*results) -> float:  # mpit-analysis: host-sync-barrier
@@ -73,60 +213,3 @@ def force_completion(*results) -> float:  # mpit-analysis: host-sync-barrier
         term = jnp.sum(small).astype(jnp.float32)
         total = term if total is None else total + term
     return float(total) if total is not None else 0.0
-
-
-class StepTimer:
-    """Wall-clock timer for jitted step loops.
-
-    Measures *completed* work: call ``stop()`` with (or after) a
-    ``block_until_ready`` on the step output, otherwise async dispatch makes
-    steps look free. Keeps a skip-count so compile steps don't pollute the
-    stats."""
-
-    def __init__(self, skip_first: int = 1):
-        self.skip_first = skip_first
-        self._times: list[float] = []
-        self._seen = 0
-        self._t0: Optional[float] = None
-
-    def start(self) -> None:
-        self._t0 = time.perf_counter()
-
-    def stop(self, result=None) -> float:
-        """Waits for ``result`` (if given) via :func:`force_completion`
-        (on the v5e ``block_until_ready`` measures the same, see there),
-        then records the elapsed time. Returns the step's wall seconds. A
-        tuple result (e.g. a ``(state, metrics)`` step output) is spread
-        so each component gets its own proof leaf."""
-        if result is not None:
-            if isinstance(result, tuple):
-                force_completion(*result)
-            else:
-                force_completion(result)
-        if self._t0 is None:
-            raise RuntimeError("StepTimer.stop() without start()")
-        dt = time.perf_counter() - self._t0
-        self._t0 = None
-        self._seen += 1
-        if self._seen > self.skip_first:
-            self._times.append(dt)
-        return dt
-
-    @property
-    def mean(self) -> float:
-        return sum(self._times) / len(self._times) if self._times else float("nan")
-
-    @property
-    def count(self) -> int:
-        return len(self._times)
-
-    def summary(self) -> dict:
-        if not self._times:
-            return {"steps": 0}
-        ts = sorted(self._times)
-        return {
-            "steps": len(ts),
-            "mean_s": self.mean,
-            "p50_s": ts[len(ts) // 2],
-            "max_s": ts[-1],
-        }

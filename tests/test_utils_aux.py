@@ -22,8 +22,6 @@ from mpit_tpu.parallel import DataParallelTrainer, EASGDTrainer
 from mpit_tpu.utils import (
     PRESETS,
     MetricsLogger,
-    StepTimer,
-    Throughput,
     TrainConfig,
     latest_checkpoint,
     list_checkpoints,
@@ -149,11 +147,6 @@ class TestMetrics:
         assert rec["grad_norms"] == [0.0, 1.0, 2.0]
         assert rec["name"] == "run" and rec["counts"] == [1, 2]
 
-    def test_throughput(self):
-        tp = Throughput()
-        assert tp.tick(100) is None
-        assert tp.tick(100) > 0
-
 
 class TestConfig:
     def test_presets_cover_baseline_configs(self):
@@ -195,15 +188,6 @@ class TestConfig:
 
 
 class TestProfiling:
-    def test_step_timer_skips_compile(self):
-        t = StepTimer(skip_first=1)
-        for _ in range(3):
-            t.start()
-            t.stop(jnp.ones(4))
-        assert t.count == 2
-        s = t.summary()
-        assert s["steps"] == 2 and s["mean_s"] > 0
-
     def test_trace_noop_without_dir(self):
         from mpit_tpu.utils.profiling import trace
 
@@ -267,17 +251,3 @@ class TestForceCompletion:
         from mpit_tpu.utils import force_completion
 
         assert force_completion({"i": jnp.int32(1)}) == 0.0
-
-    def test_step_timer_spreads_tuple_results(self):
-        import jax.numpy as jnp
-
-        from mpit_tpu.utils import StepTimer
-
-        t = StepTimer(skip_first=0)
-        t.start()
-        dt = t.stop(({"w": jnp.ones(3)}, {"loss": jnp.float32(0.5)}))
-        assert dt >= 0
-        t.start()
-        assert t.stop(jnp.float32(2.0)) >= 0
-        t.start()
-        assert t.stop(None) >= 0
