@@ -17,7 +17,9 @@ one host):
                       requests through 8 slots, rows compared with solo
                       generate_fast
   E  pallas kernels   flash attention forward + grad and the fused elastic
-                      update, compiled (not interpreted), against XLA
+                      update, compiled (not interpreted), against XLA; at
+                      GPT-2-small's shape the kernel's and XLA's errors
+                      against float32 at the highest precision
 
 Weights are random from a seed and the data is the loaders' seeded synthetic
 sets (no download, no git, no network). Each leg checks its own output —
@@ -348,6 +350,43 @@ def leg_e(tiny, devices):
             atol=5e-2 * float(np.max(np.abs(f32(gd)))),
         )
 
+    # the shape gpt2s_easgd_1chip_flash runs (GPT-2-small, batch 8): both
+    # branches of attn_impl against dense attention on float32 inputs at
+    # the highest matmul precision. The kernel's speed is not bought with
+    # precision: its error may be at most 1.5 times the xla branch's.
+    cell = (1, 128, 2, 16) if tiny else (8, 1024, 12, 64)
+    q, k, v = (
+        jnp.asarray(rng.standard_normal(cell), jnp.bfloat16) for _ in range(3)
+    )
+    w = jnp.asarray(rng.standard_normal(cell), jnp.float32)
+    out_and_grads = lambda fn: jax.jit(lambda q, k, v: (
+        fn(q, k, v), *jax.grad(loss(fn), argnums=(0, 1, 2))(q, k, v)
+    ))
+    with jax.default_matmul_precision("highest"):
+        exact = [f32(a) for a in out_and_grads(dense)(
+            *(a.astype(jnp.float32) for a in (q, k, v))
+        )]
+    errors = {}
+    for branch, fn in (("flash", flash), ("xla", dense)):
+        got = [f32(a) for a in out_and_grads(fn)(q, k, v)]
+        errors[branch] = {
+            name: {
+                "max_abs": float(np.max(np.abs(g - e))),
+                "rel": float(np.linalg.norm(g - e) / np.linalg.norm(e)),
+            }
+            for name, g, e in zip(("out", "dq", "dk", "dv"), got, exact)
+        }
+    for name, kernel_err in errors["flash"].items():
+        for kind, err in kernel_err.items():
+            assert err <= 1.5 * errors["xla"][name][kind], (
+                f"flash {name} {kind} error {err:.3g} is over 1.5 times the "
+                f"xla branch's {errors['xla'][name][kind]:.3g}"
+            )
+    rounded = lambda e: {
+        name: {kind: float(f"{x:.3g}") for kind, x in by.items()}
+        for name, by in e.items()
+    }
+
     n = 3_000 if tiny else 1_000_003  # not a multiple of the block: pads
     x, c, dd = (
         jnp.asarray(rng.standard_normal(n), jnp.float32) for _ in range(3)
@@ -363,6 +402,9 @@ def leg_e(tiny, devices):
         "compiled": compiled, "flash_shape": [b, t, h, d],
         "flash_fwd_max_abs_err": round(fwd_err, 5),
         "flash_bwd_max_abs_err": round(bwd_err, 5), "elastic_n": n,
+        "cell_shape": list(cell),
+        "cell_flash_vs_f32_highest": rounded(errors["flash"]),
+        "cell_xla_vs_f32_highest": rounded(errors["xla"]),
     }
 
 

@@ -18,11 +18,20 @@ Kernel structure (the canonical pallas flash shape,
 - scratch initializes at ``j == 0``, the output block writes once at
   the last ``j`` (revisiting one output block across sequential grid
   steps is the standard TPU accumulation pattern);
-- causal masking uses GLOBAL positions from the block indices, and a
-  fully-masked (block entirely above the diagonal) k-block skips its
-  matmuls via ``pl.when``;
-- scores/statistics accumulate in f32 regardless of input dtype (bf16
-  inputs hit the MXU as bf16 — the recipe shared with ring attention).
+- the tiles come from the shape (``choose_blocks``), for each of the
+  three kernels: a grid step has a fixed cost and every fold of the
+  online softmax works on one-lane columns, so at T = 1,024 the
+  128 x 128 tiles this file began with took three times the time of the
+  chosen ones (PERF.md section 6, PR 26, has the sweep);
+- causal masking uses GLOBAL positions from the block indices. A
+  fully-masked tile (entirely above the diagonal) skips its arithmetic
+  via ``pl.when`` AND its fetch: the inner axis' index maps clamp to
+  the nearest live block, so a skipped step names the block already
+  resident and the pipeline issues no copy;
+- every product reaches the MXU in the inputs' dtype (``p``, ``dS`` and
+  ``dO`` are cast to it, as jax's own TPU kernel does) and leaves it as
+  f32; scores, running max, normalizer, log-sum-exp, ``D`` and every
+  accumulator stay f32. With f32 inputs nothing is cast.
   ``m``/``l`` live lane-broadcast in (block_q, 128) scratch (the TPU
   f32 tile's lane width).
 
@@ -35,14 +44,18 @@ transposed dK/dV kernel as ``(B·H, 8, T)`` sublane-broadcast rows. A
 ``(1, block_q)`` block of a 2-D ``(B·H, T)`` array — the first version
 of this file — is refused by the TPU lowering whenever ``B·H > 1``.
 
+In a trace the three kernels run under ``jax.named_scope``s
+``flash_fwd``, ``flash_dq`` and ``flash_dkv``, and the transposes into
+and out of the kernels' layout with the ``D`` reduction under
+``flash_layout``.
+
 `interpret=True` runs the same kernel on CPU (the correctness tests).
 The public wrapper picks plain XLA dense attention off-TPU only when no
 kernel was asked for (``use_pallas=None``); once the kernel is selected
 it runs — compiled on TPU, interpreted on CPU — or raises, and a ``T``
 that does not tile is an error, never a silent dense pass. Default OFF
-in the model (``attn_impl="xla"``): no timing against XLA's fused
-attention exists yet (PERF.md), and the elastic-update kernel taught us
-XLA's fusion can beat a pallas kernel (ops/elastic.py).
+in the model (``attn_impl="xla"``); PERF.md section 6 (PR 26) has its
+timing against XLA's dense attention at GPT-2-small's shape.
 """
 
 from __future__ import annotations
@@ -60,6 +73,79 @@ from mpit_tpu.ops.ring_attention import dense_attention
 _NEG_INF = float("-inf")
 _LANE = 128
 _SUBLANE = 8
+#: what a kernel may take of VMEM (the chip's compiler is told so), and
+#: the part of it the tile chooser's estimate has to stay under
+_VMEM_LIMIT = 48 * 1024 * 1024
+_VMEM_BUDGET = _VMEM_LIMIT // 2
+#: the largest tile side the chooser takes for each kernel, and how many
+#: f32 temporaries of the score tile's size the kernel holds (PERF.md
+#: section 6, PR 26: the sweep at T = 1,024, D = 64). The forward pays
+#: for every fold of the online softmax in per-row work on one-lane
+#: columns, as dear as a 128-lane tile's, so it wants one k-step where T
+#: allows; the backward kernels only accumulate, and there the causal
+#: skip of 512-tiles (3 of 4 live) beats the smaller step count.
+_KERNELS = {"fwd": (1024, 3), "dq": (512, 5), "dkv": (512, 5)}
+_COMPILER_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "arbitrary"),
+    vmem_limit_bytes=_VMEM_LIMIT,
+)
+
+
+def vmem_estimate(
+    kernel: str, block_q: int, block_k: int, d: int, itemsize: int
+) -> int:
+    """An upper estimate of the VMEM ``kernel`` ("fwd", "dq" or "dkv")
+    holds at these tiles: at most four double-buffered tiles a side, in
+    and out; the per-row residuals and statistics, lane-broadcast at
+    worst; two f32 accumulators; and the kernel's f32 temporaries of the
+    score tile's size (scores, probabilities and their low-precision
+    copy; dP and dS in the backward)."""
+    tiles = 2 * 4 * (block_q + block_k) * d * itemsize
+    rows = 4 * block_q * _LANE * 4
+    scratch = 2 * max(block_q, block_k) * d * 4
+    return tiles + rows + scratch + _KERNELS[kernel][1] * block_q * block_k * 4
+
+
+def choose_blocks(t: int, d: int, dtype) -> tuple[tuple[int, int], ...]:
+    """``(block_q, block_k)`` for the forward, dQ and dK/dV kernels, in
+    that order, at sequence length ``t``: for each the largest square
+    tile up to its cap that divides ``t``, is a multiple of 128 (or
+    spans ``t``, where ``t`` has no such divisor) and keeps
+    ``vmem_estimate`` under the budget."""
+    itemsize = jnp.dtype(dtype).itemsize
+
+    def side(kernel):
+        fits = [
+            b for b in range(_LANE, min(t, _KERNELS[kernel][0]) + 1, _LANE)
+            if t % b == 0
+            and vmem_estimate(kernel, b, b, d, itemsize) <= _VMEM_BUDGET
+        ]
+        return max(fits) if fits else t
+
+    return tuple((side(kernel),) * 2 for kernel in _KERNELS)
+
+
+def _last_live_k(i, block_q: int, block_k: int):
+    """Under the causal mask, the last k-block q-block ``i`` sees."""
+    return (i * block_q + block_q - 1) // block_k
+
+
+def _first_live_q(j, block_q: int, block_k: int):
+    """Under the causal mask, the first q-block that sees k-block ``j``."""
+    return (j * block_k) // block_q
+
+
+def _inner_k(i, j, causal: bool, block_q: int, block_k: int):
+    """The k-block to hold at step ``(i, j)`` of a k-innermost grid: ``j``
+    itself, or under the causal mask the last live one, so that a masked
+    step names the resident block and nothing is fetched for it."""
+    return jnp.minimum(j, _last_live_k(i, block_q, block_k)) if causal else j
+
+
+def _inner_q(j, i, causal: bool, block_q: int, block_k: int):
+    """The mirror for the q-innermost dK/dV grid, whose masked steps come
+    first: they name the first live q-block, which then is resident."""
+    return jnp.maximum(i, _first_live_q(j, block_q, block_k)) if causal else i
 
 
 def _apply_causal(s, q_off, k_off, q_axis: int):
@@ -67,11 +153,19 @@ def _apply_causal(s, q_off, k_off, q_axis: int):
     ``q_axis`` names the tile dimension the query positions vary along
     (0 in the q-major kernels, 1 in the transposed dK/dV kernel). The
     ONE copy of the mask for forward and both backward kernels."""
-    q_pos = q_off + jax.lax.broadcasted_iota(jnp.int32, s.shape, q_axis)
-    k_pos = k_off + jax.lax.broadcasted_iota(
-        jnp.int32, s.shape, 1 - q_axis
-    )
-    return jnp.where(k_pos <= q_pos, s, _NEG_INF)
+    ahead = jax.lax.broadcasted_iota(
+        jnp.int32, s.shape, q_axis
+    ) - jax.lax.broadcasted_iota(jnp.int32, s.shape, 1 - q_axis)
+    return jnp.where(ahead >= k_off - q_off, s, _NEG_INF)
+
+
+def _when_live(live, update):
+    """Run a tile's arithmetic: always, or under the causal mask only
+    where the tile has an unmasked entry."""
+    if live is None:
+        update()
+    else:
+        pl.when(live)(update)
 
 
 def _to2d(a):
@@ -97,13 +191,6 @@ def _kernel(
         l_scr[:] = jnp.zeros_like(l_scr[:])
         acc_scr[:] = jnp.zeros_like(acc_scr[:])
 
-    # causal: a k-block strictly above the q-block's last row contributes
-    # nothing — skip its matmuls entirely
-    needed = (
-        j * block_k <= i * block_q + block_q - 1 if causal else j >= 0
-    )
-
-    @pl.when(needed)
     def _update():
         q = q_ref[0]  # (block_q, D)
         k = k_ref[0]  # (block_k, D)
@@ -127,12 +214,18 @@ def _kernel(
         )
         l_new = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
         acc_new = acc_scr[:] * corr + jax.lax.dot_general(
-            p, v.astype(jnp.float32), (((1,), (0,)), ((), ())),
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
         m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
         l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
         acc_scr[:] = acc_new
+
+    # causal: a k-block strictly above the q-block's last row contributes
+    # nothing — skip its matmuls entirely
+    _when_live(
+        j <= _last_live_k(i, block_q, block_k) if causal else None, _update
+    )
 
     @pl.when(j == n_k - 1)
     def _finalize():
@@ -164,16 +257,11 @@ def _dq_kernel(
     def _init():
         acc_scr[:] = jnp.zeros_like(acc_scr[:])
 
-    needed = (
-        j * block_k <= i * block_q + block_q - 1 if causal else j >= 0
-    )
-
-    @pl.when(needed)
     def _update():
         q = q_ref[0]
         k = k_ref[0]
         v = v_ref[0]
-        do = do_ref[0].astype(jnp.float32)
+        do = do_ref[0]
         lse = lse_ref[0, 0][:, None]  # (1, bq) row block -> (bq, 1)
         dd = dd_ref[0, 0][:, None]
         s = jax.lax.dot_general(
@@ -184,18 +272,22 @@ def _dq_kernel(
             s = _apply_causal(s, i * block_q, j * block_k, 0)
         p = jnp.exp(s - lse)  # rows with lse=+inf go to 0
         dp = jax.lax.dot_general(
-            do, v.astype(jnp.float32), (((1,), (1,)), ((), ())),
+            do, v, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
         ds = p * (dp - dd)
         acc_scr[:] += jax.lax.dot_general(
-            ds, k.astype(jnp.float32), (((1,), (0,)), ((), ())),
+            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
-        ) * scale
+        )
+
+    _when_live(
+        j <= _last_live_k(i, block_q, block_k) if causal else None, _update
+    )
 
     @pl.when(j == n_k - 1)
     def _finalize():
-        dq_ref[0] = acc_scr[:].astype(dq_ref.dtype)
+        dq_ref[0] = (acc_scr[:] * scale).astype(dq_ref.dtype)
 
 
 def _dkv_kernel(
@@ -212,17 +304,11 @@ def _dkv_kernel(
         dk_scr[:] = jnp.zeros_like(dk_scr[:])
         dv_scr[:] = jnp.zeros_like(dv_scr[:])
 
-    # causal: a q-block entirely ABOVE this k-block contributes nothing
-    needed = (
-        i * block_q + block_q - 1 >= j * block_k if causal else i >= 0
-    )
-
-    @pl.when(needed)
     def _update():
         q = q_ref[0]
         k = k_ref[0]
         v = v_ref[0]
-        do = do_ref[0].astype(jnp.float32)
+        do = do_ref[0]
         lse = lse_ref[0][:1, :]  # (8, bq) sublane-broadcast -> (1, bq)
         dd = dd_ref[0][:1, :]
         st = jax.lax.dot_general(
@@ -233,105 +319,152 @@ def _dkv_kernel(
             st = _apply_causal(st, i * block_q, j * block_k, 1)
         pt = jnp.exp(st - lse)
         dv_scr[:] += jax.lax.dot_general(
-            pt, do, (((1,), (0,)), ((), ())),
+            pt.astype(do.dtype), do, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
         dpt = jax.lax.dot_general(
-            v.astype(jnp.float32), do, (((1,), (1,)), ((), ())),
+            v, do, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
         dst = pt * (dpt - dd)
         dk_scr[:] += jax.lax.dot_general(
-            dst, q.astype(jnp.float32), (((1,), (0,)), ((), ())),
+            dst.astype(q.dtype), q, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
-        ) * scale
+        )
+
+    # causal: a q-block entirely ABOVE this k-block contributes nothing
+    _when_live(
+        i >= _first_live_q(j, block_q, block_k) if causal else None, _update
+    )
 
     @pl.when(i == n_q - 1)
     def _finalize():
-        dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
+        dk_ref[0] = (dk_scr[:] * scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
 
 
+def _k_inner_specs(d, causal, block_q, block_k):
+    """Q and K/V block specs of a ``(B·H, q-block, k-block)`` grid."""
+    q_spec = pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0))
+    kv_spec = pl.BlockSpec(
+        (1, block_k, d),
+        lambda b, i, j: (b, _inner_k(i, j, causal, block_q, block_k), 0),
+    )
+    return q_spec, kv_spec
+
+
+def _fwd_call(q2, k2, v2, causal, block_q, block_k, interpret):
+    """Forward kernel on the (B·H, T, D) layout -> (out, lane-broadcast
+    log-sum-exp)."""
+    bh, t, d = q2.shape
+    q_spec, kv_spec = _k_inner_specs(d, causal, block_q, block_k)
+    with jax.named_scope("flash_fwd"):
+        return pl.pallas_call(
+            functools.partial(
+                _kernel, scale=1.0 / (d ** 0.5), causal=causal,
+                block_q=block_q, block_k=block_k, n_k=t // block_k,
+            ),
+            grid=(bh, t // block_q, t // block_k),
+            in_specs=[q_spec, kv_spec, kv_spec],
+            out_specs=[
+                q_spec,
+                pl.BlockSpec((1, block_q, _LANE), lambda b, i, j: (b, i, 0)),
+            ],
+            out_shape=[
+                jax.ShapeDtypeStruct((bh, t, d), q2.dtype),
+                jax.ShapeDtypeStruct((bh, t, _LANE), jnp.float32),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((block_q, _LANE), jnp.float32),  # running max m
+                pltpu.VMEM((block_q, _LANE), jnp.float32),  # normalizer l
+                pltpu.VMEM((block_q, d), jnp.float32),      # output acc
+            ],
+            compiler_params=_COMPILER_PARAMS,
+            interpret=interpret,
+        )(q2, k2, v2)
+
+
+def _rows(a, rows: int):
+    """(B·H, T) per-row residual -> (B·H, rows, T) lane-major rows."""
+    return jnp.broadcast_to(a[:, None, :], (a.shape[0], rows, a.shape[1]))
+
+
+def _dq_call(q2, k2, v2, do2, lse, dd, causal, block_q, block_k, interpret):
+    bh, t, d = q2.shape
+    q_spec, kv_spec = _k_inner_specs(d, causal, block_q, block_k)
+    # per-row residuals as one lane-major row for the q-major kernel
+    row_spec = pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i))
+    with jax.named_scope("flash_dq"):
+        return pl.pallas_call(
+            functools.partial(
+                _dq_kernel, scale=1.0 / (d ** 0.5), causal=causal,
+                block_q=block_q, block_k=block_k, n_k=t // block_k,
+            ),
+            grid=(bh, t // block_q, t // block_k),
+            in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
+            out_specs=q_spec,
+            out_shape=jax.ShapeDtypeStruct((bh, t, d), q2.dtype),
+            scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+            compiler_params=_COMPILER_PARAMS,
+            interpret=interpret,
+        )(q2, k2, v2, do2, _rows(lse, 1), _rows(dd, 1))
+
+
+def _dkv_call(q2, k2, v2, do2, lse, dd, causal, block_q, block_k, interpret):
+    bh, t, d = q2.shape
+    inner = lambda j, i: _inner_q(j, i, causal, block_q, block_k)
+    q_spec = pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, inner(j, i), 0))
+    kv_spec = pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0))
+    # sublane-broadcast rows for the transposed kernel
+    row_spec = pl.BlockSpec(
+        (1, _SUBLANE, block_q), lambda b, j, i: (b, 0, inner(j, i))
+    )
+    with jax.named_scope("flash_dkv"):
+        return pl.pallas_call(
+            functools.partial(
+                _dkv_kernel, scale=1.0 / (d ** 0.5), causal=causal,
+                block_q=block_q, block_k=block_k, n_q=t // block_q,
+            ),
+            grid=(bh, t // block_k, t // block_q),
+            in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
+            out_specs=[kv_spec, kv_spec],
+            out_shape=[
+                jax.ShapeDtypeStruct((bh, t, d), k2.dtype),
+                jax.ShapeDtypeStruct((bh, t, d), v2.dtype),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((block_k, d), jnp.float32),
+                pltpu.VMEM((block_k, d), jnp.float32),
+            ],
+            compiler_params=_COMPILER_PARAMS,
+            interpret=interpret,
+        )(q2, k2, v2, do2, _rows(lse, _SUBLANE), _rows(dd, _SUBLANE))
+
+
 @functools.partial(
-    jax.jit,
-    static_argnames=("causal", "block_q", "block_k", "interpret"),
+    jax.jit, static_argnames=("causal", "blocks", "interpret")
 )
-def _flash_pallas_bwd(q, k, v, out, lse, ct, causal, block_q, block_k,
-                      interpret):
+def _flash_pallas_bwd(q, k, v, out, lse, ct, causal, blocks, interpret):
     b, t, h, d = q.shape
-    scale = 1.0 / (d ** 0.5)
-    q2, k2, v2 = _to2d(q), _to2d(k), _to2d(v)
-    do2 = _to2d(ct)
-    o2 = _to2d(out)
-    # D_i = Σ_d dO_id · O_id — cheap elementwise+reduce, XLA's job
-    dd = jnp.sum(
-        do2.astype(jnp.float32) * o2.astype(jnp.float32), -1
-    )  # (BH, T)
-    n_q, n_k = t // block_q, t // block_k
-
-    q_spec = lambda ax: pl.BlockSpec(
-        (1, block_q, d), lambda bh, a, b_: (bh, a if ax == 1 else b_, 0)
+    with jax.named_scope("flash_layout"):
+        q2, k2, v2, do2, o2 = (_to2d(a) for a in (q, k, v, ct, out))
+        # D_i = Σ_d dO_id · O_id — cheap elementwise+reduce, XLA's job
+        dd = jnp.sum(
+            do2.astype(jnp.float32) * o2.astype(jnp.float32), -1
+        )  # (BH, T)
+    _, dq_blocks, dkv_blocks = blocks
+    dq = _dq_call(q2, k2, v2, do2, lse, dd, causal, *dq_blocks, interpret)
+    dk, dv = _dkv_call(
+        q2, k2, v2, do2, lse, dd, causal, *dkv_blocks, interpret
     )
-    # per-row residuals as lane-major rows: one row for the q-major dQ
-    # kernel, sublane-broadcast for the transposed dK/dV kernel
-    row_spec = lambda ax, rows: pl.BlockSpec(
-        (1, rows, block_q), lambda bh, a, b_: (bh, 0, a if ax == 1 else b_)
-    )
-    rows_of = lambda a, rows: jnp.broadcast_to(
-        a[:, None, :], (b * h, rows, t)
-    )
-    kv_spec = lambda ax: pl.BlockSpec(
-        (1, block_k, d), lambda bh, a, b_: (bh, a if ax == 1 else b_, 0)
-    )
-
-    dq = pl.pallas_call(
-        functools.partial(
-            _dq_kernel, scale=scale, causal=causal,
-            block_q=block_q, block_k=block_k, n_k=n_k,
-        ),
-        grid=(b * h, n_q, n_k),
-        in_specs=[
-            q_spec(1), kv_spec(2), kv_spec(2), q_spec(1),
-            row_spec(1, 1), row_spec(1, 1),
-        ],
-        out_specs=q_spec(1),
-        out_shape=jax.ShapeDtypeStruct((b * h, t, d), q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        interpret=interpret,
-    )(q2, k2, v2, do2, rows_of(lse, 1), rows_of(dd, 1))
-
-    dk, dv = pl.pallas_call(
-        functools.partial(
-            _dkv_kernel, scale=scale, causal=causal,
-            block_q=block_q, block_k=block_k, n_q=n_q,
-        ),
-        grid=(b * h, n_k, n_q),
-        in_specs=[
-            q_spec(2), kv_spec(1), kv_spec(1), q_spec(2),
-            row_spec(2, _SUBLANE), row_spec(2, _SUBLANE),
-        ],
-        out_specs=[kv_spec(1), kv_spec(1)],
-        out_shape=[
-            jax.ShapeDtypeStruct((b * h, t, d), k.dtype),
-            jax.ShapeDtypeStruct((b * h, t, d), v.dtype),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, d), jnp.float32),
-        ],
-        interpret=interpret,
-    )(q2, k2, v2, do2, rows_of(lse, _SUBLANE), rows_of(dd, _SUBLANE))
-
-    return (
-        _from2d(dq, b, h, t, d),
-        _from2d(dk, b, h, t, d),
-        _from2d(dv, b, h, t, d),
-    )
+    with jax.named_scope("flash_layout"):
+        return tuple(_from2d(a, b, h, t, d) for a in (dq, dk, dv))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash(q, k, v, causal, block_q, block_k, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _flash(q, k, v, causal, blocks, interpret):
     """Differentiable flash attention: pallas kernels both directions.
+    ``blocks`` holds the forward, dQ and dK/dV kernels' tiles.
 
     ``pallas_call`` has no automatic VJP; the backward here is the
     standard FlashAttention recipe — recompute P from the saved
@@ -340,59 +473,32 @@ def _flash(q, k, v, causal, block_q, block_k, interpret):
     dK/dV kernel (k-blocks outer, q-blocks inner), with the D = rowsum
     (dO ∘ O) vector computed by XLA outside.
     """
-    return _flash_pallas(q, k, v, causal, block_q, block_k, interpret)[0]
+    return _flash_pallas(q, k, v, causal, blocks, interpret)[0]
 
 
-def _flash_fwd(q, k, v, causal, block_q, block_k, interpret):
-    out, lse = _flash_pallas(q, k, v, causal, block_q, block_k, interpret)
+def _flash_fwd(q, k, v, causal, blocks, interpret):
+    out, lse = _flash_pallas(q, k, v, causal, blocks, interpret)
     return out, (q, k, v, out, lse)
 
 
-def _flash_bwd(causal, block_q, block_k, interpret, res, ct):
+def _flash_bwd(causal, blocks, interpret, res, ct):
     q, k, v, out, lse = res
-    return _flash_pallas_bwd(
-        q, k, v, out, lse, ct, causal, block_q, block_k, interpret
-    )
+    return _flash_pallas_bwd(q, k, v, out, lse, ct, causal, blocks, interpret)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
 @functools.partial(
-    jax.jit,
-    static_argnames=("causal", "block_q", "block_k", "interpret"),
+    jax.jit, static_argnames=("causal", "blocks", "interpret")
 )
-def _flash_pallas(q, k, v, causal, block_q, block_k, interpret):
+def _flash_pallas(q, k, v, causal, blocks, interpret):
     b, t, h, d = q.shape
-    scale = 1.0 / (d ** 0.5)
-    q2, k2, v2 = _to2d(q), _to2d(k), _to2d(v)
-    n_q, n_k = t // block_q, t // block_k
-
-    q_spec = pl.BlockSpec((1, block_q, d), lambda bh, i, j: (bh, i, 0))
-    kv_spec = pl.BlockSpec((1, block_k, d), lambda bh, i, j: (bh, j, 0))
-    out, lse = pl.pallas_call(
-        functools.partial(
-            _kernel, scale=scale, causal=causal,
-            block_q=block_q, block_k=block_k, n_k=n_k,
-        ),
-        grid=(b * h, n_q, n_k),
-        in_specs=[q_spec, kv_spec, kv_spec],
-        out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda bh, i, j: (bh, i, 0)),
-            pl.BlockSpec((1, block_q, _LANE), lambda bh, i, j: (bh, i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b * h, t, d), q.dtype),
-            jax.ShapeDtypeStruct((b * h, t, _LANE), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, _LANE), jnp.float32),  # running max m
-            pltpu.VMEM((block_q, _LANE), jnp.float32),  # normalizer l
-            pltpu.VMEM((block_q, d), jnp.float32),      # output acc
-        ],
-        interpret=interpret,
-    )(q2, k2, v2)
-    return _from2d(out, b, h, t, d), lse[..., 0]
+    with jax.named_scope("flash_layout"):
+        q2, k2, v2 = _to2d(q), _to2d(k), _to2d(v)
+    out, lse = _fwd_call(q2, k2, v2, causal, *blocks[0], interpret)
+    with jax.named_scope("flash_layout"):
+        return _from2d(out, b, h, t, d), lse[..., 0]
 
 
 def flash_attention(
@@ -400,8 +506,8 @@ def flash_attention(
     k: jax.Array,
     v: jax.Array,
     causal: bool = False,
-    block_q: int = 128,
-    block_k: int = 128,
+    block_q: int | None = None,
+    block_k: int | None = None,
     use_pallas=None,
 ) -> jax.Array:
     """Tiled exact attention, ``(B, T, H, D) -> (B, T, H, D)``.
@@ -415,22 +521,29 @@ def flash_attention(
     log-sum-exp; dQ and fused dK/dV passes), so no (T, T) score matrix
     materializes in either direction.
 
-    Blocks clamp to ``T`` for short sequences. Once the kernel is
-    selected, a ``T`` the blocks do not tile raises ``ValueError`` — it
-    never becomes a dense pass: a block must divide ``T`` and be
-    sublane-aligned (a multiple of 8), and compiled for the chip it must
-    also be lane-aligned (a multiple of 128) or span ``T``, because the
-    backward reads per-row residuals as ``(…, block_q)`` lane-major rows.
+    ``block_q``/``block_k``: ``None`` = chosen from ``(T, D, dtype)`` by
+    ``choose_blocks``, for each of the three kernels; a given one wins
+    for all three and clamps to ``T``. Once the kernel is selected, a
+    ``T`` the blocks do not tile raises ``ValueError`` — it never becomes
+    a dense pass: a block must divide ``T`` and be sublane-aligned (a
+    multiple of 8), and compiled for the chip it must also be
+    lane-aligned (a multiple of 128) or span ``T``, because the backward
+    reads per-row residuals as ``(…, block_q)`` lane-major rows.
     """
     if use_pallas is None:
         use_pallas = pallas_supported()
     if not use_pallas:
         return dense_attention(q, k, v, causal=causal)
     interpret = pallas_interpret()
-    t = q.shape[1]
-    block_q = min(block_q, t)
-    block_k = min(block_k, t)
-    for name, blk in (("block_q", block_q), ("block_k", block_k)):
+    t, d = q.shape[1], q.shape[3]
+    blocks = tuple(
+        (bq if block_q is None else min(block_q, t),
+         bk if block_k is None else min(block_k, t))
+        for bq, bk in choose_blocks(t, d, q.dtype)
+    )
+    for name, blk in (
+        pair for tiles in blocks for pair in zip(("block_q", "block_k"), tiles)
+    ):
         if t % blk or blk % 8:
             raise ValueError(
                 f"flash_attention: T={t} does not tile with {name}={blk} "
@@ -443,4 +556,4 @@ def flash_attention(
                 f"{q.shape}) cannot compile for TPU: a block must be a "
                 f"multiple of {_LANE} or span T"
             )
-    return _flash(q, k, v, causal, block_q, block_k, interpret)
+    return _flash(q, k, v, causal, blocks, interpret)

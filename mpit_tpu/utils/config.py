@@ -109,8 +109,10 @@ class TrainConfig:
     grad_accum: int = 1
     # transformer dense-attention implementation: "xla" (fused dense) or
     # "flash" (pallas tiled kernel on TPU, where a seq_len that does not
-    # tile raises; dense elsewhere) — opt-in: it compiles and matches on
-    # the chip (PERF.md) but has no timing against XLA yet
+    # tile raises; dense elsewhere) — still opt-in, though at GPT-2-small's
+    # shape (T=1,024) it trains 1.76 times as fast as "xla" on the v5e
+    # (PERF.md section 6, PR 26); the choice from the sequence length that
+    # retires this field is ROADMAP.md Queue 3 item 3
     attn_impl: str = "xla"
     # moe-sync only: expert count (sharded over the worker axis; must be
     # divisible by it) and the GShard capacity factor

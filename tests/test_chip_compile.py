@@ -1,0 +1,84 @@
+"""The Pallas kernels of the main path, compiled for a described TPU v5e.
+
+The chip's compiler is installed where there is no chip: it compiles for a
+topology that is described and not attached, and raises what the chip would
+raise — a tile not aligned to Mosaic's (8, 128) tiling, more VMEM than a
+kernel may take — which interpret mode never sees. Nothing runs, so these
+say nothing about results or times.
+
+Only one process may load the TPU's library, so the topology is described
+inside a fixture (never at import) and every such compile lives in this one
+file; where no topology can be described the tests skip.
+"""
+
+import importlib
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+fa = importlib.import_module("mpit_tpu.ops.flash_attention")
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def no_compile_cache():
+    """A compile for a described device is written to jax's persistent
+    cache but cannot be read back without the chip; keep it out."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.mark.parametrize(
+    "shape,dtype",
+    [
+        # gpt2s_easgd_1chip_flash: GPT-2-small, batch 8
+        ((8, 1024, 12, 64), jnp.bfloat16),
+        # ptb-transformer-large; chip_smoke leg E
+        ((2, 512, 12, 64), jnp.bfloat16),
+        # a T with no 128-multiple divisor but itself
+        ((2, 384, 12, 64), jnp.bfloat16),
+        # twice the length, D = 128
+        ((1, 2048, 8, 128), jnp.bfloat16),
+        # the chooser's largest estimate: float32 tiles at D = 128
+        ((1, 1024, 4, 128), jnp.float32),
+    ],
+)
+def test_flash_kernels_compile_with_the_chosen_tiles(
+    shape, dtype, one_chip, no_compile_cache
+):
+    """Forward, dQ and dK/dV at the tiles ``choose_blocks`` gives the
+    shape, causal: three Mosaic kernels in the compiled step."""
+    _, t, _, d = shape
+    blocks = fa.choose_blocks(t, d, dtype)
+    x = jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def loss(q, k, v):
+        out = fa._flash(q, k, v, True, blocks, False)
+        return out.astype(jnp.float32).sum()
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        x, x, x
+    ).compile()
+    assert compiled.as_text().count("custom_call_target=\"tpu_custom_call\"") == 3

@@ -7,6 +7,8 @@ the online-softmax edge cases (multi-block running max updates, fully
 masked leading blocks).
 """
 
+import importlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -14,6 +16,9 @@ import pytest
 
 from mpit_tpu.ops.flash_attention import flash_attention
 from mpit_tpu.ops.ring_attention import dense_attention
+
+# the module: ``mpit_tpu.ops`` re-exports the function under its name
+fa = importlib.import_module("mpit_tpu.ops.flash_attention")
 
 
 def _qkv(b=2, t=256, h=2, d=16, dtype=jnp.float32, seed=0):
@@ -31,7 +36,10 @@ class TestFlashAttention:
         the cross-block running-max correction and (causal) the
         skipped above-diagonal block."""
         q, k, v = _qkv()
-        got = flash_attention(q, k, v, causal=causal, use_pallas=True)
+        got = flash_attention(
+            q, k, v, causal=causal, block_q=128, block_k=128,
+            use_pallas=True,
+        )
         want = dense_attention(q, k, v, causal=causal)
         np.testing.assert_allclose(
             np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-5
@@ -166,4 +174,156 @@ class TestFlashAttention:
         got = flash.apply({"params": params}, x)
         np.testing.assert_allclose(
             np.asarray(got), np.asarray(ref), rtol=2e-5, atol=2e-5
+        )
+
+
+def _grads(attend, q, k, v):
+    def loss(q_, k_, v_):
+        return (attend(q_, k_, v_).astype(jnp.float32) ** 2).mean()
+
+    return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+
+class TestTilesFromTheShape:
+    @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+    @pytest.mark.parametrize("d", [64, 128])
+    @pytest.mark.parametrize("t", [1024, 512, 384, 128, 96])
+    def test_chosen_tiles_fit_the_shape(self, t, d, dtype):
+        """What the chip's compiler asks of a tile, and the VMEM the
+        chooser promises to stay inside, for each of the three kernels."""
+        chosen = fa.choose_blocks(t, d, dtype)
+        assert len(chosen) == len(fa._KERNELS) == 3
+        for kernel, blocks in zip(fa._KERNELS, chosen):
+            for blk in blocks:
+                assert t % blk == 0 and blk % 8 == 0
+                assert blk % 128 == 0 or blk == t
+            assert fa.vmem_estimate(
+                kernel, *blocks, d, jnp.dtype(dtype).itemsize
+            ) <= fa._VMEM_BUDGET < fa._VMEM_LIMIT
+            # the largest such tile, not the smallest: fewer grid steps
+            assert min(blocks) >= min(t, 256)
+
+    def test_a_length_no_tile_fits_is_left_to_the_check(self):
+        # 100 has no divisor that is a multiple of 128: the chooser hands
+        # back tiles that span T and flash_attention refuses them (8 | T)
+        assert fa.choose_blocks(100, 64, jnp.float32) == ((100, 100),) * 3
+
+    def test_the_sweeps_choice_at_the_cells_shape(self):
+        # PERF.md section 6, PR 26: one k-step forward, 512-tiles backward
+        assert fa.choose_blocks(1024, 64, jnp.bfloat16) == (
+            (1024, 1024), (512, 512), (512, 512))
+
+    @pytest.mark.parametrize("causal", [False, True])
+    @pytest.mark.parametrize(
+        "dtype,tol", [(jnp.float32, 2e-5), (jnp.bfloat16, 3e-2)]
+    )
+    def test_chosen_tiles_match_dense(self, dtype, tol, causal):
+        """T=512 with the tiles the chooser gives it, no block passed:
+        forward and the three gradients. With bfloat16 inputs ``p``,
+        ``dS`` and ``dO`` reach the products as bfloat16."""
+        q, k, v = _qkv(b=1, t=512, dtype=dtype, seed=7)
+        flash = lambda a, b, c: flash_attention(
+            a, b, c, causal=causal, use_pallas=True
+        )
+        dense = lambda a, b, c: dense_attention(a, b, c, causal=causal)
+        np.testing.assert_allclose(
+            np.asarray(flash(q, k, v), np.float32),
+            np.asarray(dense(q, k, v), np.float32), rtol=tol, atol=tol,
+        )
+        for gf, gd in zip(_grads(flash, q, k, v), _grads(dense, q, k, v)):
+            np.testing.assert_allclose(
+                np.asarray(gf, np.float32), np.asarray(gd, np.float32),
+                rtol=tol, atol=tol,
+            )
+
+    def test_the_cells_length_with_its_mixed_tiles(self):
+        """T=1,024: the forward in one k-step, the backward kernels on
+        512-tiles with one of four masked, as the cell runs them."""
+        q, k, v = _qkv(b=1, t=1024, h=1, seed=10)
+        flash = lambda a, b, c: flash_attention(
+            a, b, c, causal=True, use_pallas=True
+        )
+        dense = lambda a, b, c: dense_attention(a, b, c, causal=True)
+        np.testing.assert_allclose(
+            np.asarray(flash(q, k, v)), np.asarray(dense(q, k, v)),
+            rtol=2e-5, atol=2e-5,
+        )
+        for gf, gd in zip(_grads(flash, q, k, v), _grads(dense, q, k, v)):
+            np.testing.assert_allclose(
+                np.asarray(gf), np.asarray(gd), rtol=2e-5, atol=2e-5
+            )
+
+    def test_bf16_products_keep_f32_accumulators(self):
+        """Many k-blocks of bfloat16 inputs: were the running sums kept
+        in bfloat16, 16 folds would lose what one fold keeps."""
+        q, k, v = _qkv(b=1, t=512, dtype=jnp.bfloat16, seed=8)
+        one = flash_attention(
+            q, k, v, causal=True, block_q=512, block_k=512, use_pallas=True
+        )
+        many = flash_attention(
+            q, k, v, causal=True, block_q=32, block_k=32, use_pallas=True
+        )
+        np.testing.assert_allclose(
+            np.asarray(one, np.float32), np.asarray(many, np.float32),
+            rtol=2e-2, atol=2e-2,
+        )
+
+
+class TestNoFetchForAMaskedTile:
+    @pytest.mark.parametrize(
+        "t,block_q,block_k",
+        [(512, 128, 128), (512, 256, 128), (512, 128, 256), (768, 384, 128)],
+    )
+    def test_masked_steps_name_the_resident_block(self, t, block_q, block_k):
+        """A live step names its own block; a masked step names the
+        block of the live step next to it, so the pipeline sees no
+        change of index and copies nothing."""
+        n_q, n_k = t // block_q, t // block_k
+        live = lambda i, j: j * block_k <= i * block_q + block_q - 1
+        masked = 0
+        for i in range(n_q):
+            for j in range(n_k):
+                got = int(fa._inner_k(i, j, True, block_q, block_k))
+                assert int(fa._inner_k(i, j, False, block_q, block_k)) == j
+                if live(i, j):
+                    assert got == j
+                else:  # masked steps trail the live ones of a q-block
+                    masked += 1
+                    assert got == int(
+                        fa._inner_k(i, j - 1, True, block_q, block_k)
+                    )
+                    assert live(i, got)
+        for j in range(n_k):
+            for i in reversed(range(n_q)):
+                got = int(fa._inner_q(j, i, True, block_q, block_k))
+                assert int(fa._inner_q(j, i, False, block_q, block_k)) == i
+                if live(i, j):
+                    assert got == i
+                else:  # masked steps lead the live ones of a k-block
+                    masked += 1
+                    assert got == int(
+                        fa._inner_q(j, i + 1, True, block_q, block_k)
+                    )
+                    assert live(got, j)
+        assert masked > 0
+
+    def test_clamped_maps_give_the_unclamped_outputs(self, monkeypatch):
+        """Six of sixteen tiles masked: outputs and gradients with the
+        clamped index maps equal those with every step naming its own
+        block, bit for bit (a masked step does no arithmetic)."""
+        q, k, v = _qkv(b=1, t=512, seed=9)
+        flash = lambda a, b, c: flash_attention(
+            a, b, c, causal=True, block_q=128, block_k=128, use_pallas=True
+        )
+        clamped = (flash(q, k, v), *_grads(flash, q, k, v))
+        monkeypatch.setattr(fa, "_inner_k", lambda i, j, *rest: j)
+        monkeypatch.setattr(fa, "_inner_q", lambda j, i, *rest: i)
+        jax.clear_caches()  # the jitted kernels were traced with the clamp
+        plain = (flash(q, k, v), *_grads(flash, q, k, v))
+        jax.clear_caches()
+        for a, b in zip(clamped, plain):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        want = dense_attention(q, k, v, causal=True)
+        np.testing.assert_allclose(
+            np.asarray(clamped[0]), np.asarray(want), rtol=2e-5, atol=2e-5
         )
