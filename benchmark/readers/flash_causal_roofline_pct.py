@@ -1,0 +1,7 @@
+"""flash_causal_roofline_pct: the causal flash kernels' share of their roofline (forward, dQ, dK/dV together)."""
+
+from benchmark.lib import lm_spans
+
+
+def read(run):
+    return lm_spans.roofline_pct(run, "flash_causal")

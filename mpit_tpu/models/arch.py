@@ -1,0 +1,250 @@
+"""From a published ``config.json`` to what each layer of the model is.
+
+``TrainConfig.arch`` carries an architecture in its source's own keys (the
+Hugging Face ``config.json`` of the model, with the keys a cut replaces set
+to what is run); :func:`layer_specs` turns it into one hashable
+:class:`LayerSpec` a layer, which ``models/transformer.Block`` takes in place
+of the GPT-2 block's fixed shape: its norm, positions, head counts, output
+gate, window and feed-forward kind.
+
+Keys read (others are ignored): ``hidden_size``, ``num_hidden_layers``,
+``head_dim``, ``num_key_value_heads``, ``num_attention_heads`` or
+``num_attention_heads_per_layer``, ``layer_types`` (``full_attention`` /
+``sliding_attention``), ``sliding_window``, ``rope_parameters`` (by layer
+type: ``rope_theta``, ``rope_type`` ``default`` or ``yarn`` with its
+``factor``, ``original_max_position_embeddings``, ``beta_fast``,
+``beta_slow``, ``attention_factor``; ``partial_rotary_factor``), ``gating``
+(``per-head`` or absent), ``rms_norm_eps``, ``intermediate_size``,
+``mlp_layer_types`` (``dense`` / ``sparse``), ``moe_intermediate_size``,
+``shared_expert_intermediate_size``, ``num_experts_per_tok``,
+``moe_routed_scaling_factor``, ``tie_word_embeddings``; lists longer than
+``num_hidden_layers`` are read from their start. Two keys are this repo's,
+for one chip's share of an expert-parallel deployment (the model-configs
+guide's section 4): ``num_experts`` is the number of routed experts HELD,
+``num_routed_experts`` the number the router scores (default: all held) and
+``expert_offset`` the first held expert's index; ``moe_row_bound`` is the
+dispatch buffer's static row count (default: four times the expected rows);
+``moe_routing_no_grad`` true makes the routing weights constants of the
+backward pass: no gradient into the router's weights, none through the
+scores into the layer's input. A share takes it: that gradient is a sum over
+every chosen expert's output, and with the other chips' experts absent the
+part computed here says only "the experts held add noise, the absent ones
+add nothing", which empties the experts held within 35 steps of AdamW, by
+the router's weights or, where those are frozen, by the layer's input alone
+(both seen on the v5e, PERF.md section 6, PR 27); the deployment's gradient
+has no such term. ``router_aux_loss_coef`` (the family's key; default 0)
+weights the mean over sparse layers of the load-balancing term ``E · sum_e
+f_e P_e`` (``f_e`` the share of tokens that chose ``e``, ``P_e`` its mean
+score: transformers' ``load_balancing_loss_func`` a layer) that
+``TransformerLM.loss_with_counters`` adds to the loss.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import math
+from typing import Optional
+
+import numpy as np
+
+
+@dataclasses.dataclass(frozen=True)
+class RopeSpec:
+    theta: float
+    rotary_dim: int  # leading dims of each head that rotate
+    # YaRN (None = plain rope): frequencies blended between the published
+    # and the ``factor``-times-slower ones, cos and sin scaled
+    yarn: Optional[tuple] = None  # (factor, original_max, beta_fast, beta_slow)
+    attention_factor: float = 1.0
+
+
+@dataclasses.dataclass(frozen=True)
+class MoESpec:
+    routed: int  # experts the router scores
+    held: int  # experts whose weights live here
+    offset: int  # index of the first held expert
+    top_k: int
+    width: int
+    shared_width: int  # 0 = no shared expert
+    scale: float
+    row_bound: int  # 0 = from the token count (four times the expectation)
+    routing_grad: bool = True  # False: routing weights are constants backward
+
+    def rows(self, tokens: int) -> int:
+        """The dispatch buffer's rows for ``tokens`` tokens: the given
+        bound, or four times the rows uniform routing sends to the
+        experts held, in multiples of 256 (at most every pair)."""
+        if self.row_bound:
+            return self.row_bound
+        expected = tokens * self.top_k * self.held / self.routed
+        return min(int(math.ceil(4 * expected / 256.0)) * 256,
+                   tokens * min(self.top_k, self.held))
+
+
+@dataclasses.dataclass(frozen=True)
+class LayerSpec:
+    d_model: int
+    num_heads: int
+    num_kv_heads: int
+    head_dim: int
+    window: Optional[int]  # None = full causal attention
+    rope: RopeSpec
+    gate: bool  # per-head sigmoid gate on the attention output
+    norm_eps: float
+    d_ff: int  # dense SwiGLU width (0 where the layer is sparse)
+    moe: Optional[MoESpec]
+
+
+def _rope_spec(params: dict, head_dim: int) -> RopeSpec:
+    rotary = int(head_dim * params.get("partial_rotary_factor", 1.0))
+    kind = params.get("rope_type", "default")
+    if kind == "default":
+        return RopeSpec(float(params["rope_theta"]), rotary)
+    if kind != "yarn":
+        raise ValueError(f"rope_type {kind!r}: have default, yarn")
+    factor = float(params["factor"])
+    return RopeSpec(
+        float(params["rope_theta"]), rotary,
+        yarn=(factor, int(params["original_max_position_embeddings"]),
+              float(params.get("beta_fast", 32)),
+              float(params.get("beta_slow", 1))),
+        attention_factor=float(
+            params.get("attention_factor") or 0.1 * math.log(factor) + 1.0),
+    )
+
+
+def layer_specs(arch: dict) -> tuple[LayerSpec, ...]:
+    n = int(arch["num_hidden_layers"])
+    d, head_dim = int(arch["hidden_size"]), int(arch["head_dim"])
+    kinds = list(arch.get("layer_types") or ["full_attention"] * n)[:n]
+    ffns = list(arch.get("mlp_layer_types") or ["dense"] * n)[:n]
+    heads = list(arch.get("num_attention_heads_per_layer")
+                 or [arch["num_attention_heads"]] * n)[:n]
+    if not len(kinds) == len(ffns) == len(heads) == n:
+        raise ValueError(
+            f"arch: {n} layers but layer_types, mlp_layer_types and "
+            f"num_attention_heads_per_layer give {len(kinds)}, {len(ffns)} "
+            f"and {len(heads)}"
+        )
+    gating = arch.get("gating")
+    if gating not in (None, "per-head"):
+        raise ValueError(f"arch: gating {gating!r}: have per-head or none")
+    ropes = arch["rope_parameters"]
+    if "rope_theta" in ropes:  # one rope for every layer type
+        ropes = {"full_attention": ropes, "sliding_attention": ropes}
+    moe = None
+    if "sparse" in ffns:
+        held = int(arch["num_experts"])
+        moe = MoESpec(
+            routed=int(arch.get("num_routed_experts", held)), held=held,
+            offset=int(arch.get("expert_offset", 0)),
+            top_k=int(arch["num_experts_per_tok"]),
+            width=int(arch["moe_intermediate_size"]),
+            shared_width=int(arch.get("shared_expert_intermediate_size", 0)),
+            scale=float(arch.get("moe_routed_scaling_factor", 1.0)),
+            row_bound=int(arch.get("moe_row_bound", 0)),
+            routing_grad=not arch.get("moe_routing_no_grad", False),
+        )
+        if not arch.get("norm_topk_prob", True):
+            raise ValueError("arch: norm_topk_prob false is not built")
+        if arch.get("moe_router_logit_softcapping"):
+            raise ValueError("arch: router logit soft-capping is not built")
+        if not 0 <= moe.offset <= moe.routed - moe.held:
+            raise ValueError(
+                f"arch: experts {moe.offset}..{moe.offset + moe.held} held "
+                f"of {moe.routed} routed"
+            )
+    specs = []
+    for kind, ffn, h in zip(kinds, ffns, heads):
+        if kind not in ("full_attention", "sliding_attention"):
+            raise ValueError(f"arch: layer type {kind!r}")
+        if ffn not in ("dense", "sparse"):
+            raise ValueError(f"arch: mlp layer type {ffn!r}")
+        specs.append(LayerSpec(
+            d_model=d, num_heads=int(h),
+            num_kv_heads=int(arch["num_key_value_heads"]), head_dim=head_dim,
+            window=(int(arch["sliding_window"])
+                    if kind == "sliding_attention" else None),
+            rope=_rope_spec(ropes[kind], head_dim),
+            gate=gating == "per-head",
+            norm_eps=float(arch.get("rms_norm_eps", 1e-6)),
+            d_ff=int(arch["intermediate_size"]) if ffn == "dense" else 0,
+            moe=moe if ffn == "sparse" else None,
+        ))
+    return tuple(specs)
+
+
+def rope_inv_freq(rope: RopeSpec) -> np.ndarray:
+    """``(rotary_dim / 2,)`` float64 inverse frequencies. YaRN follows
+    transformers' ``_compute_yarn_parameters``: dimensions that turn more
+    than ``beta_fast`` times over the original context keep the published
+    frequency, those under ``beta_slow`` turns are slowed ``factor``
+    times, a linear ramp between. They are static, so they hold at any
+    sequence length."""
+    dim = rope.rotary_dim
+    pos_freqs = rope.theta ** (np.arange(0, dim, 2, dtype=np.float64) / dim)
+    if rope.yarn is None:
+        return 1.0 / pos_freqs
+    factor, original, beta_fast, beta_slow = rope.yarn
+    turns_dim = lambda turns: (
+        dim * math.log(original / (turns * 2 * math.pi))
+        / (2 * math.log(rope.theta)))
+    low = max(math.floor(turns_dim(beta_fast)), 0)
+    high = min(math.ceil(turns_dim(beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float64) - low)
+                   / (high - low), 0.0, 1.0)
+    return (1.0 / (factor * pos_freqs)) * ramp + (1.0 / pos_freqs) * (1 - ramp)
+
+
+#: positions a block of the rotary tables' fine part
+_ROPE_BLOCK = 128
+
+
+def rope_tables(rope: RopeSpec, length: int, head_dim: int):
+    """float32 ``cos`` and ``sin`` of shape ``(length, head_dim)``: over the
+    rotary dims the half-width angles repeated (rotate-half layout) times
+    the attention factor, over the dims that pass through 1 and 0. Made in
+    the program from two small float64-exact tables, position
+    ``p = 128 a + b`` by ``cos(x + y) = cos x cos y - sin x sin y``: a
+    table of all positions would sit in the step program as a constant of
+    4 MB a layer, forward and backward, and angles multiplied out in
+    float32 are off by 5e-4 at 8,192 tokens."""
+    import jax.numpy as jnp
+
+    inv_freq = rope_inv_freq(rope)
+
+    def table(positions):
+        angles = np.outer(positions, inv_freq)
+        return (jnp.asarray(np.cos(angles), jnp.float32),
+                jnp.asarray(np.sin(angles), jnp.float32))
+
+    blocks = -(-length // _ROPE_BLOCK)
+    (cos_a, sin_a) = table(np.arange(blocks) * float(_ROPE_BLOCK))
+    (cos_b, sin_b) = table(np.arange(_ROPE_BLOCK, dtype=np.float64))
+    position = jnp.arange(length)
+    a, b = position // _ROPE_BLOCK, position % _ROPE_BLOCK
+    cos = cos_a[a] * cos_b[b] - sin_a[a] * sin_b[b]
+    sin = sin_a[a] * cos_b[b] + cos_a[a] * sin_b[b]
+    factor = jnp.float32(rope.attention_factor)
+    rest = (length, head_dim - rope.rotary_dim)
+    return (jnp.concatenate([cos * factor, cos * factor,
+                             jnp.ones(rest, jnp.float32)], axis=-1),
+            jnp.concatenate([sin * factor, sin * factor,
+                             jnp.zeros(rest, jnp.float32)], axis=-1))
+
+
+def rotate_half_matrix(rope: RopeSpec, head_dim: int) -> np.ndarray:
+    """``(head_dim, head_dim)`` of 0, 1 and -1 with ``x @ M`` the
+    rotate-half partner of ``x`` over the rotary dims (``-x[i + r/2]`` for
+    ``i < r/2``, ``x[i - r/2]`` above) and 0 over the others: the
+    half-swap as one exact product on the MXU instead of a lane shuffle
+    (on the v5e split-and-concatenate took 3.2 ms a pass over a 72-head
+    query, a tenth of the whole step; PERF.md section 6, PR 27)."""
+    half = rope.rotary_dim // 2
+    m = np.zeros((head_dim, head_dim), np.float32)
+    for i in range(half):
+        m[i + half, i] = -1.0
+        m[i, i + half] = 1.0
+    return m

@@ -25,8 +25,34 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from mpit_tpu.models.arch import (
+    LayerSpec, layer_specs, rope_tables, rotate_half_matrix,
+)
 from mpit_tpu.ops.ring_attention import dense_attention, ring_attention
 from mpit_tpu.ops.ulysses import ulysses_attention
+
+
+def rms_norm(x, scale, eps: float):
+    """``x / rms(x) * scale`` over the last axis, in float32."""
+    x = x.astype(jnp.float32)
+    return x * jax.lax.rsqrt(
+        jnp.mean(jnp.square(x), axis=-1, keepdims=True) + eps
+    ) * scale
+
+
+def apply_rope(x, cos, sin, swap):
+    """``x cos + (x @ swap) sin`` on ``(B, T, H, D)``: rotary positions in
+    the rotate-half layout (dim ``i`` pairs with ``i + rotary/2``), with
+    ``cos``/``sin`` from ``rope_tables`` and ``swap`` from
+    ``rotate_half_matrix``. The product only moves and negates elements,
+    so it is exact in ``x``'s dtype; the rest is float32."""
+    partner = jnp.dot(
+        x, jnp.asarray(swap, x.dtype), preferred_element_type=jnp.float32,
+        precision=(jax.lax.Precision.HIGHEST
+                   if x.dtype == jnp.float32 else None),
+    )
+    return (x.astype(jnp.float32) * cos[None, :, None, :]
+            + partner * sin[None, :, None, :]).astype(x.dtype)
 
 
 class Block(nn.Module):
@@ -52,9 +78,17 @@ class Block(nn.Module):
     # decode_len sizes the cache (the LM passes its max_len)
     decode: bool = False
     decode_len: int = 0
+    # what this layer is, where the model was given an architecture
+    # (TransformerLM.arch): RMSNorm, rotary positions, grouped KV heads,
+    # a window, a per-head output gate, a SwiGLU or a sparse expert
+    # feed-forward. None = the GPT-2 block below, whose parameter tree
+    # (LayerNorm_0..1, Dense_0..3) other modules key on
+    spec: Optional[LayerSpec] = None
 
     @nn.compact
     def __call__(self, x):
+        if self.spec is not None:
+            return self._described(x)
         # jax.named_scope names the device's time by layer part (metadata
         # only; forward and backward both carry it): "attention" is the
         # attention arithmetic alone, "attn_proj" the projections around
@@ -105,6 +139,122 @@ class Block(nn.Module):
                 y = nn.gelu(y)
                 x = x + nn.Dense(self.d_model, dtype=dt)(y)
         return x
+
+    def _described(self, x):
+        """The block ``spec`` describes: ``h = x + Attn(RMSNorm(x))``,
+        ``x' = h + FFN(RMSNorm(h))``, no biases. Scopes as in the GPT-2
+        block, with ``rope`` and ``attn_gate`` inside ``attn_proj``,
+        ``attn_window`` / ``attn_full`` inside ``attention`` and the
+        expert layer's ``moe_*`` scopes inside ``mlp``."""
+        from mpit_tpu.ops.flash_attention import flash_attention
+        from mpit_tpu.ops.moe import swiglu
+
+        spec, dt = self.spec, self.compute_dtype
+        if self.decode or self.seq_axis is not None or self.moe_experts:
+            raise ValueError(
+                "a block built from an architecture (TrainConfig.arch) "
+                "trains on one device's whole sequence: decode=True, "
+                "seq_axis and the GShard moe_experts path are not built "
+                "for it"
+            )
+        if self.attn_impl not in ("xla", "flash", "flash_force"):
+            raise ValueError(f"attn_impl={self.attn_impl!r}")
+        b, t, d = x.shape
+        h, h_kv, hd = spec.num_heads, spec.num_kv_heads, spec.head_dim
+        lecun = nn.initializers.lecun_normal()
+        weight = lambda name, *shape: self.param(
+            name, lecun, shape, jnp.float32)
+        scale = lambda name: self.param(
+            name, nn.initializers.ones_init(), (d,), jnp.float32)
+        proj = lambda a, w: jnp.dot(
+            a, w.astype(dt), preferred_element_type=jnp.float32)
+        with jax.named_scope("attn_proj"):
+            y = rms_norm(x, scale("attn_norm"), spec.norm_eps).astype(dt)
+            q = proj(y, weight("wq", d, h * hd)).astype(dt)
+            k = proj(y, weight("wk", d, h_kv * hd)).astype(dt)
+            v = proj(y, weight("wv", d, h_kv * hd)).astype(dt)
+            q = q.reshape(b, t, h, hd)
+            k, v = (a.reshape(b, t, h_kv, hd) for a in (k, v))
+            with jax.named_scope("rope"):
+                cos, sin = rope_tables(spec.rope, t, hd)
+                swap = rotate_half_matrix(spec.rope, hd)
+                q, k = apply_rope(q, cos, sin, swap), apply_rope(
+                    k, cos, sin, swap)
+        with jax.named_scope("attention"):
+            with jax.named_scope(
+                "attn_full" if spec.window is None else "attn_window"
+            ):
+                att = flash_attention(
+                    q, k, v, causal=True, window=spec.window,
+                    use_pallas={"xla": False, "flash": None,
+                                "flash_force": True}[self.attn_impl],
+                )
+        with jax.named_scope("attn_proj"):
+            if spec.gate:
+                # head-wise sigmoid gate from the normed input, on the
+                # attention output before the output projection
+                with jax.named_scope("attn_gate"):
+                    gate = jax.nn.sigmoid(proj(y, weight("wg", d, h)))
+                    att = (att * gate[..., None]).astype(dt)
+            x = x + proj(
+                att.reshape(b, t, h * hd), weight("wo", h * hd, d)
+            ).astype(dt)
+        with jax.named_scope("mlp"):
+            y = rms_norm(x, scale("ffn_norm"), spec.norm_eps).astype(dt)
+            if spec.moe is None:
+                x = x + swiglu(
+                    y, weight("w_gate", d, spec.d_ff),
+                    weight("w_up", d, spec.d_ff),
+                    weight("w_down", spec.d_ff, d),
+                )
+            else:
+                x = x + self._held_experts(y.reshape(b * t, d)).reshape(
+                    b, t, d)
+        return x
+
+    def _held_experts(self, y2):
+        """The sparse feed-forward's part that lives here
+        (``ops/moe.moe_ffn_held``) plus the shared expert, which every
+        chip of the deployment computes alike. Routing counters are sown
+        into the ``counters`` collection (``aggregate_counters``) and the
+        chosen expert ids into ``routing``."""
+        from mpit_tpu.ops.moe import moe_ffn_held, swiglu
+
+        moe, d = self.spec.moe, self.spec.d_model
+        lecun = nn.initializers.lecun_normal()
+        expert_init = nn.initializers.variance_scaling(
+            1.0, "fan_in", "truncated_normal", in_axis=-2, out_axis=-1,
+            batch_axis=(0,),
+        )
+        stacked = lambda name, *shape: self.param(
+            name, expert_init, (moe.held, *shape), jnp.float32)
+        params = {
+            "router": self.param(
+                "moe_router", lecun, (d, moe.routed), jnp.float32),
+            "w_gate": stacked("moe_w_gate", d, moe.width),
+            "w_up": stacked("moe_w_up", d, moe.width),
+            "w_down": stacked("moe_w_down", moe.width, d),
+        }
+        out, counters, (_, experts) = moe_ffn_held(
+            params, y2, top_k=moe.top_k, expert_offset=moe.offset,
+            row_bound=moe.rows(y2.shape[0]), scale=moe.scale,
+            routing_grad=moe.routing_grad,
+        )
+        for name, val in counters.items():
+            self.sow("counters", name, val)
+        # the top-k expert ids, for a comparison that has to be made on
+        # the same discrete choices (mutable=["routing"])
+        self.sow("routing", "experts", experts)
+        if moe.shared_width:
+            with jax.named_scope("moe_shared"):
+                shared = lambda name, *shape: self.param(
+                    name, lecun, shape, jnp.float32)
+                out = out + swiglu(
+                    y2, shared("shared_w_gate", d, moe.shared_width),
+                    shared("shared_w_up", d, moe.shared_width),
+                    shared("shared_w_down", moe.shared_width, d),
+                )
+        return out
 
     def _cached_attention(self, q, k, v):
         """Causal attention of a T-token CHUNK over the persistent K/V
@@ -259,6 +409,16 @@ class Block(nn.Module):
         return out
 
 
+def _sown_by_name(collection: dict) -> dict:
+    """``{"Block_i": {name: (value, ...)}}`` -> ``{name: [values]}`` over
+    the blocks that sowed it."""
+    by_name: dict = {}
+    for block_vals in collection.values():
+        for name, vals in block_vals.items():
+            by_name.setdefault(name, []).extend(vals)
+    return by_name
+
+
 def aggregate_moe_losses(collection: dict) -> dict:
     """Mean each sown MoE stat over the blocks that sowed it.
 
@@ -266,12 +426,28 @@ def aggregate_moe_losses(collection: dict) -> dict:
     ``model.apply(..., mutable=["moe_losses"])``:
     ``{"Block_i": {name: (scalar,), ...}, ...}`` → ``{name: scalar}``.
     """
-    per_name: dict = {}
-    for block_vals in collection.values():
-        for name, vals in block_vals.items():
-            per_name.setdefault(name, []).extend(vals)
     return {
-        name: sum(vals) / len(vals) for name, vals in per_name.items()
+        name: sum(vals) / len(vals)
+        for name, vals in _sown_by_name(collection).items()
+    }
+
+
+def aggregate_counters(collection: dict) -> dict:
+    """One value a step from the routing counters the expert layers sowed
+    (``model.apply(..., mutable=["counters"])``): ``moe_rows_held`` the
+    mean over layers of the pairs routed to the experts held,
+    ``moe_load_max_over_mean`` the worst layer's fullest expert over its
+    mean, ``moe_rows_dropped`` the sum of the rows past the bound,
+    ``moe_balance`` the mean of the load-balancing terms (top-k = uniform)."""
+    by_name = _sown_by_name(collection)
+    if not by_name:
+        return {}
+    return {
+        "moe_rows_held": jnp.mean(jnp.stack(by_name["rows_held"])),
+        "moe_load_max_over_mean": jnp.max(
+            jnp.stack(by_name["load_max_over_mean"])),
+        "moe_rows_dropped": jnp.sum(jnp.stack(by_name["rows_dropped"])),
+        "moe_balance": jnp.mean(jnp.stack(by_name["balance"])),
     }
 
 
@@ -330,6 +506,37 @@ class TransformerLM(nn.Module):
     # isolation; head_dtype=f32 also serves a bf16 model with a
     # full-precision head when quality comparisons call for it.
     head_dtype: Any = None
+    # an architecture in its source's own keys (models/arch.py reads
+    # them): RMSNorm, rotary positions, a per-layer pattern of head
+    # counts, windows and dense or sparse SwiGLU feed-forwards, no
+    # position table, an untied head. It replaces num_layers, d_model,
+    # num_heads, d_ff and max_len. None = the GPT-2 model those describe
+    arch: Any = None
+
+    @property
+    def loss_with_counters(self):
+        """``(params, x, y) -> (loss, counters)`` where the layers sow
+        counters (an ``arch``'s expert layers: ``aggregate_counters``),
+        else None. A trainer that finds it puts the counters into its
+        step's metrics."""
+        if self.arch is None:
+            return None
+        from mpit_tpu.parallel.common import cross_entropy_loss
+
+        # the family's auxiliary load-balancing loss (0 = none)
+        coef = float(self.arch.get("router_aux_loss_coef", 0.0))
+
+        def loss_fn(params, x, y):
+            logits, sown = self.apply(
+                {"params": params}, x, mutable=["counters"]
+            )
+            counters = aggregate_counters(sown.get("counters", {}))
+            loss = cross_entropy_loss(logits, y)
+            if coef and counters:
+                loss = loss + coef * counters["moe_balance"]
+            return loss, counters
+
+        return loss_fn
 
     @property
     def _head_operand_dtype(self):
@@ -341,8 +548,53 @@ class TransformerLM(nn.Module):
             else self.head_dtype
         )
 
+    def _described(self, tokens):
+        """The model ``arch`` describes; parameters ``Embed_0``,
+        ``Block_i``, ``final_norm`` and (untied) ``head``."""
+        if self.decode or self.seq_axis is not None or self.moe_experts:
+            raise ValueError(
+                "a model built from an architecture (TrainConfig.arch) "
+                "trains on one device's whole sequence: decode=True "
+                "(serving), seq_axis (seq-sync) and moe_experts (moe-sync) "
+                "are not built for it"
+            )
+        arch, dt = self.arch, self.compute_dtype
+        specs = layer_specs(arch)
+        d = specs[0].d_model
+        embed = nn.Embed(self.vocab_size, d, dtype=dt, name="Embed_0")
+        x = embed(tokens)
+        block_cls = nn.remat(Block) if self.remat else Block
+        for i, spec in enumerate(specs):
+            x = block_cls(
+                d_model=d, num_heads=spec.num_heads, d_ff=spec.d_ff,
+                compute_dtype=dt, seq_axis=None, attn_impl=self.attn_impl,
+                spec=spec, name=f"Block_{i}",
+            )(x)
+        x = rms_norm(
+            x, self.param("final_norm", nn.initializers.ones_init(), (d,),
+                          jnp.float32),
+            specs[0].norm_eps,
+        ).astype(dt)
+        if not self.head:
+            return x
+        hdt = self._head_operand_dtype
+        with jax.named_scope("head"):
+            if arch.get("tie_word_embeddings", False):
+                table = embed.embedding
+            else:
+                table = self.param(
+                    "head", nn.initializers.lecun_normal(in_axis=-1,
+                                                         out_axis=-2),
+                    (self.vocab_size, d), jnp.float32)
+            return jnp.einsum(
+                "btd,vd->btv", x.astype(hdt), table.astype(hdt),
+                preferred_element_type=jnp.float32,
+            )
+
     @nn.compact
     def __call__(self, tokens):
+        if self.arch is not None:
+            return self._described(tokens)
         if self.d_model % self.num_heads:
             raise ValueError(
                 f"d_model {self.d_model} not divisible by "

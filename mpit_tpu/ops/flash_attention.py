@@ -76,7 +76,7 @@ _SUBLANE = 8
 #: what a kernel may take of VMEM (the chip's compiler is told so), and
 #: the part of it the tile chooser's estimate has to stay under
 _VMEM_LIMIT = 48 * 1024 * 1024
-_VMEM_BUDGET = _VMEM_LIMIT // 2
+_VMEM_BUDGET = _VMEM_LIMIT * 2 // 3
 #: the largest tile side the chooser takes for each kernel, and how many
 #: f32 temporaries of the score tile's size the kernel holds (PERF.md
 #: section 6, PR 26: the sweep at T = 1,024, D = 64). The forward pays
@@ -85,6 +85,11 @@ _VMEM_BUDGET = _VMEM_LIMIT // 2
 #: allows; the backward kernels only accumulate, and there the causal
 #: skip of 512-tiles (3 of 4 live) beats the smaller step count.
 _KERNELS = {"fwd": (1024, 3), "dq": (512, 5), "dkv": (512, 5)}
+#: from this length on the backward kernels take the forward's cap too:
+#: with eight tiles a side or more a 1,024-tile skips nearly as much of the
+#: causal mask as a 512-tile (9 of 16 tiles live against 17 of 32) in a
+#: quarter of the steps (PERF.md section 6, PR 27: T = 8,192, D = 128)
+_LONG_T = 8192
 _COMPILER_PARAMS = pltpu.CompilerParams(
     dimension_semantics=("parallel", "parallel", "arbitrary"),
     vmem_limit_bytes=_VMEM_LIMIT,
@@ -106,17 +111,26 @@ def vmem_estimate(
     return tiles + rows + scratch + _KERNELS[kernel][1] * block_q * block_k * 4
 
 
-def choose_blocks(t: int, d: int, dtype) -> tuple[tuple[int, int], ...]:
+def choose_blocks(
+    t: int, d: int, dtype, window: int | None = None
+) -> tuple[tuple[int, int], ...]:
     """``(block_q, block_k)`` for the forward, dQ and dK/dV kernels, in
     that order, at sequence length ``t``: for each the largest square
     tile up to its cap that divides ``t``, is a multiple of 128 (or
     spans ``t``, where ``t`` has no such divisor) and keeps
-    ``vmem_estimate`` under the budget."""
+    ``vmem_estimate`` under the budget. Under a ``window`` no tile is
+    larger than the window (a larger one is mostly masked; at window 512
+    a 512 x 512 tile won the sweep for all three kernels, PERF.md section
+    6, PR 27)."""
     itemsize = jnp.dtype(dtype).itemsize
 
     def side(kernel):
+        cap = _KERNELS["fwd" if t >= _LONG_T else kernel][0]
+        cap = min(t, cap)
+        if window is not None:
+            cap = min(cap, max(_LANE, window))
         fits = [
-            b for b in range(_LANE, min(t, _KERNELS[kernel][0]) + 1, _LANE)
+            b for b in range(_LANE, cap + 1, _LANE)
             if t % b == 0
             and vmem_estimate(kernel, b, b, d, itemsize) <= _VMEM_BUDGET
         ]
@@ -135,28 +149,95 @@ def _first_live_q(j, block_q: int, block_k: int):
     return (j * block_k) // block_q
 
 
-def _inner_k(i, j, causal: bool, block_q: int, block_k: int):
-    """The k-block to hold at step ``(i, j)`` of a k-innermost grid: ``j``
-    itself, or under the causal mask the last live one, so that a masked
-    step names the resident block and nothing is fetched for it."""
-    return jnp.minimum(j, _last_live_k(i, block_q, block_k)) if causal else j
+def _first_live_k(i, block_q: int, block_k: int, window: int):
+    """Under a window, the k-block of the earliest key that q-block
+    ``i``'s first row sees (key ``q - window + 1``)."""
+    return jnp.maximum(i * block_q - window + 1, 0) // block_k
 
 
-def _inner_q(j, i, causal: bool, block_q: int, block_k: int):
-    """The mirror for the q-innermost dK/dV grid, whose masked steps come
-    first: they name the first live q-block, which then is resident."""
-    return jnp.maximum(i, _first_live_q(j, block_q, block_k)) if causal else i
+def _last_live_q(j, block_q: int, block_k: int, window: int, n_q: int):
+    """Under a window, the q-block of the latest query that sees k-block
+    ``j``'s last key (query ``k + window - 1``)."""
+    return jnp.minimum(
+        (j * block_k + block_k + window - 2) // block_q, n_q - 1
+    )
 
 
-def _apply_causal(s, q_off, k_off, q_axis: int):
-    """Mask score tile entries where k_pos > q_pos (global positions);
-    ``q_axis`` names the tile dimension the query positions vary along
-    (0 in the q-major kernels, 1 in the transposed dK/dV kernel). The
-    ONE copy of the mask for forward and both backward kernels."""
+def window_steps(t: int, block_q: int, block_k: int, window: int):
+    """Inner grid extents under a window: the most k-blocks any q-block
+    sees and the most q-blocks that see any k-block. The windowed grids
+    walk only these, from each outer block's first live inner block, so
+    a tile outside the window costs neither arithmetic, fetch nor grid
+    step."""
+    n_q, n_k = t // block_q, t // block_k
+    k_steps = max(
+        (i * block_q + block_q - 1) // block_k
+        - max(i * block_q - window + 1, 0) // block_k + 1
+        for i in range(n_q)
+    )
+    q_steps = max(
+        min((j * block_k + block_k + window - 2) // block_q, n_q - 1)
+        - (j * block_k) // block_q + 1
+        for j in range(n_k)
+    )
+    return k_steps, q_steps
+
+
+def _k_block(i, j, block_q: int, block_k: int, window):
+    """The k-block step ``j`` of q-block ``i`` stands for: ``j`` itself,
+    or under a window the ``j``-th from the first live one."""
+    if window is None:
+        return j
+    return _first_live_k(i, block_q, block_k, window) + j
+
+
+def _q_block(j, i, block_q: int, block_k: int, window):
+    """The mirror for the q-innermost dK/dV grid."""
+    if window is None:
+        return i
+    return _first_live_q(j, block_q, block_k) + i
+
+
+def _inner_k(i, j, causal: bool, block_q: int, block_k: int, window=None):
+    """The k-block to hold at step ``(i, j)`` of a k-innermost grid: the
+    step's own, or under the causal mask the last live one, so that a
+    masked step names the resident block and nothing is fetched for it."""
+    if not causal:
+        return j
+    return jnp.minimum(
+        _k_block(i, j, block_q, block_k, window),
+        _last_live_k(i, block_q, block_k),
+    )
+
+
+def _inner_q(j, i, causal: bool, block_q: int, block_k: int, window=None,
+             n_q: int = 0):
+    """The mirror for the q-innermost dK/dV grid. Without a window its
+    masked steps come first and name the first live q-block, which then
+    is resident; under a window they come last and name the last."""
+    if not causal:
+        return i
+    if window is None:
+        return jnp.maximum(i, _first_live_q(j, block_q, block_k))
+    return jnp.minimum(
+        _q_block(j, i, block_q, block_k, window),
+        _last_live_q(j, block_q, block_k, window, n_q),
+    )
+
+
+def _apply_causal(s, q_off, k_off, q_axis: int, window=None):
+    """Mask score tile entries where k_pos > q_pos (global positions)
+    and, under a window, where k_pos <= q_pos - window; ``q_axis`` names
+    the tile dimension the query positions vary along (0 in the q-major
+    kernels, 1 in the transposed dK/dV kernel). The ONE copy of the mask
+    for forward and both backward kernels."""
     ahead = jax.lax.broadcasted_iota(
         jnp.int32, s.shape, q_axis
     ) - jax.lax.broadcasted_iota(jnp.int32, s.shape, 1 - q_axis)
-    return jnp.where(ahead >= k_off - q_off, s, _NEG_INF)
+    seen = ahead >= k_off - q_off
+    if window is not None:
+        seen &= ahead < k_off - q_off + window
+    return jnp.where(seen, s, _NEG_INF)
 
 
 def _when_live(live, update):
@@ -180,10 +261,11 @@ def _from2d(a, b: int, h: int, t: int, d: int):
 
 def _kernel(
     q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
-    *, scale, causal, block_q, block_k, n_k,
+    *, scale, causal, block_q, block_k, n_k, window=None,
 ):
     i = pl.program_id(1)
     j = pl.program_id(2)
+    kb = _k_block(i, j, block_q, block_k, window)
 
     @pl.when(j == 0)
     def _init():
@@ -200,7 +282,7 @@ def _kernel(
             preferred_element_type=jnp.float32,
         ) * scale  # (block_q, block_k)
         if causal:
-            s = _apply_causal(s, i * block_q, j * block_k, 0)
+            s = _apply_causal(s, i * block_q, kb * block_k, 0, window)
         m_prev = m_scr[:][:, :1]  # (block_q, 1) of the broadcast store
         l_prev = l_scr[:][:, :1]
         block_max = jnp.max(s, axis=-1, keepdims=True)
@@ -224,7 +306,7 @@ def _kernel(
     # causal: a k-block strictly above the q-block's last row contributes
     # nothing — skip its matmuls entirely
     _when_live(
-        j <= _last_live_k(i, block_q, block_k) if causal else None, _update
+        kb <= _last_live_k(i, block_q, block_k) if causal else None, _update
     )
 
     @pl.when(j == n_k - 1)
@@ -246,12 +328,13 @@ def _kernel(
 
 def _dq_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref, dq_ref, acc_scr,
-    *, scale, causal, block_q, block_k, n_k,
+    *, scale, causal, block_q, block_k, n_k, window=None,
 ):
     """dQ_i = scale · Σ_j dS_ij K_j with dS = P ∘ (dP − D); grid
     (B·H, q-block, k-block-innermost), accumulating in VMEM scratch."""
     i = pl.program_id(1)
     j = pl.program_id(2)
+    kb = _k_block(i, j, block_q, block_k, window)
 
     @pl.when(j == 0)
     def _init():
@@ -269,7 +352,7 @@ def _dq_kernel(
             preferred_element_type=jnp.float32,
         ) * scale
         if causal:
-            s = _apply_causal(s, i * block_q, j * block_k, 0)
+            s = _apply_causal(s, i * block_q, kb * block_k, 0, window)
         p = jnp.exp(s - lse)  # rows with lse=+inf go to 0
         dp = jax.lax.dot_general(
             do, v, (((1,), (1,)), ((), ())),
@@ -282,7 +365,7 @@ def _dq_kernel(
         )
 
     _when_live(
-        j <= _last_live_k(i, block_q, block_k) if causal else None, _update
+        kb <= _last_live_k(i, block_q, block_k) if causal else None, _update
     )
 
     @pl.when(j == n_k - 1)
@@ -292,14 +375,20 @@ def _dq_kernel(
 
 def _dkv_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref, dk_ref, dv_ref,
-    dk_scr, dv_scr, *, scale, causal, block_q, block_k, n_q,
+    dk_scr, dv_scr, *, scale, causal, block_q, block_k, n_q, window=None,
+    group=1, t_blocks=0,
 ):
     """dK_j = scale · Σ_i dSᵀ_ji Q_i and dV_j = Σ_i Pᵀ_ji dO_i; grid
-    (B·H, k-block, q-block-innermost)."""
+    (B·H_kv, k-block, q-block-innermost). ``n_q`` is the q-steps one
+    query head takes; with grouped KV heads the innermost axis walks
+    them once for each of the ``group`` query heads that read this KV
+    head, and the sums run over all of them in the same scratch."""
     j = pl.program_id(1)
-    i = pl.program_id(2)
+    step = pl.program_id(2)
+    i = step if group == 1 else step % n_q
+    qb = _q_block(j, i, block_q, block_k, window)
 
-    @pl.when(i == 0)
+    @pl.when(step == 0)
     def _init():
         dk_scr[:] = jnp.zeros_like(dk_scr[:])
         dv_scr[:] = jnp.zeros_like(dv_scr[:])
@@ -316,7 +405,7 @@ def _dkv_kernel(
             preferred_element_type=jnp.float32,
         ) * scale  # (bk, bq) = sᵀ
         if causal:
-            st = _apply_causal(st, i * block_q, j * block_k, 1)
+            st = _apply_causal(st, qb * block_q, j * block_k, 1, window)
         pt = jnp.exp(st - lse)
         dv_scr[:] += jax.lax.dot_general(
             pt.astype(do.dtype), do, (((1,), (0,)), ((), ())),
@@ -332,39 +421,62 @@ def _dkv_kernel(
             preferred_element_type=jnp.float32,
         )
 
-    # causal: a q-block entirely ABOVE this k-block contributes nothing
-    _when_live(
-        i >= _first_live_q(j, block_q, block_k) if causal else None, _update
-    )
+    # causal: a q-block entirely ABOVE this k-block contributes nothing;
+    # under a window neither does one wholly past it
+    if not causal:
+        live = None
+    elif window is None:
+        live = i >= _first_live_q(j, block_q, block_k)
+    else:
+        live = qb <= _last_live_q(j, block_q, block_k, window, t_blocks)
+    _when_live(live, _update)
 
-    @pl.when(i == n_q - 1)
+    @pl.when(step == group * n_q - 1)
     def _finalize():
         dk_ref[0] = (dk_scr[:] * scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
 
 
-def _k_inner_specs(d, causal, block_q, block_k):
+def _kv_head(b, group: int):
+    """Row of the ``(B·H_kv, T, D)`` arrays that query row ``b`` of
+    ``(B·H, T, D)`` reads: query head ``h`` reads KV head
+    ``h // group``, and ``H = group · H_kv`` makes that ``b // group``."""
+    return b if group == 1 else b // group
+
+
+def _k_inner_specs(d, causal, block_q, block_k, window=None, group=1):
     """Q and K/V block specs of a ``(B·H, q-block, k-block)`` grid."""
     q_spec = pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0))
     kv_spec = pl.BlockSpec(
         (1, block_k, d),
-        lambda b, i, j: (b, _inner_k(i, j, causal, block_q, block_k), 0),
+        lambda b, i, j: (
+            _kv_head(b, group),
+            _inner_k(i, j, causal, block_q, block_k, window), 0,
+        ),
     )
     return q_spec, kv_spec
 
 
-def _fwd_call(q2, k2, v2, causal, block_q, block_k, interpret):
+def _k_steps(t, block_q, block_k, window):
+    return t // block_k if window is None else window_steps(
+        t, block_q, block_k, window)[0]
+
+
+def _fwd_call(q2, k2, v2, causal, block_q, block_k, interpret, window=None):
     """Forward kernel on the (B·H, T, D) layout -> (out, lane-broadcast
-    log-sum-exp)."""
+    log-sum-exp). ``k2``/``v2`` may hold fewer (grouped) heads."""
     bh, t, d = q2.shape
-    q_spec, kv_spec = _k_inner_specs(d, causal, block_q, block_k)
-    with jax.named_scope("flash_fwd"):
+    group = bh // k2.shape[0]
+    q_spec, kv_spec = _k_inner_specs(d, causal, block_q, block_k, window, group)
+    n_k = _k_steps(t, block_q, block_k, window)
+    scope = "flash_fwd" if window is None else "flash_window_fwd"
+    with jax.named_scope(scope):
         return pl.pallas_call(
             functools.partial(
                 _kernel, scale=1.0 / (d ** 0.5), causal=causal,
-                block_q=block_q, block_k=block_k, n_k=t // block_k,
+                block_q=block_q, block_k=block_k, n_k=n_k, window=window,
             ),
-            grid=(bh, t // block_q, t // block_k),
+            grid=(bh, t // block_q, n_k),
             in_specs=[q_spec, kv_spec, kv_spec],
             out_specs=[
                 q_spec,
@@ -389,18 +501,22 @@ def _rows(a, rows: int):
     return jnp.broadcast_to(a[:, None, :], (a.shape[0], rows, a.shape[1]))
 
 
-def _dq_call(q2, k2, v2, do2, lse, dd, causal, block_q, block_k, interpret):
+def _dq_call(q2, k2, v2, do2, lse, dd, causal, block_q, block_k, interpret,
+             window=None):
     bh, t, d = q2.shape
-    q_spec, kv_spec = _k_inner_specs(d, causal, block_q, block_k)
+    group = bh // k2.shape[0]
+    q_spec, kv_spec = _k_inner_specs(d, causal, block_q, block_k, window, group)
+    n_k = _k_steps(t, block_q, block_k, window)
     # per-row residuals as one lane-major row for the q-major kernel
     row_spec = pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i))
-    with jax.named_scope("flash_dq"):
+    scope = "flash_dq" if window is None else "flash_window_dq"
+    with jax.named_scope(scope):
         return pl.pallas_call(
             functools.partial(
                 _dq_kernel, scale=1.0 / (d ** 0.5), causal=causal,
-                block_q=block_q, block_k=block_k, n_k=t // block_k,
+                block_q=block_q, block_k=block_k, n_k=n_k, window=window,
             ),
-            grid=(bh, t // block_q, t // block_k),
+            grid=(bh, t // block_q, n_k),
             in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
             out_specs=q_spec,
             out_shape=jax.ShapeDtypeStruct((bh, t, d), q2.dtype),
@@ -410,27 +526,38 @@ def _dq_call(q2, k2, v2, do2, lse, dd, causal, block_q, block_k, interpret):
         )(q2, k2, v2, do2, _rows(lse, 1), _rows(dd, 1))
 
 
-def _dkv_call(q2, k2, v2, do2, lse, dd, causal, block_q, block_k, interpret):
+def _dkv_call(q2, k2, v2, do2, lse, dd, causal, block_q, block_k, interpret,
+              window=None):
     bh, t, d = q2.shape
-    inner = lambda j, i: _inner_q(j, i, causal, block_q, block_k)
-    q_spec = pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, inner(j, i), 0))
-    kv_spec = pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0))
+    group = bh // k2.shape[0]
+    n_q = t // block_q if window is None else window_steps(
+        t, block_q, block_k, window)[1]
+    # step -> (query head of the group, q-step of that head)
+    head = lambda b, s: b if group == 1 else b * group + s // n_q
+    inner = lambda j, s: _inner_q(
+        j, s if group == 1 else s % n_q, causal, block_q, block_k, window,
+        t // block_q)
+    q_spec = pl.BlockSpec(
+        (1, block_q, d), lambda b, j, s: (head(b, s), inner(j, s), 0))
+    kv_spec = pl.BlockSpec((1, block_k, d), lambda b, j, s: (b, j, 0))
     # sublane-broadcast rows for the transposed kernel
     row_spec = pl.BlockSpec(
-        (1, _SUBLANE, block_q), lambda b, j, i: (b, 0, inner(j, i))
+        (1, _SUBLANE, block_q), lambda b, j, s: (head(b, s), 0, inner(j, s))
     )
-    with jax.named_scope("flash_dkv"):
+    scope = "flash_dkv" if window is None else "flash_window_dkv"
+    with jax.named_scope(scope):
         return pl.pallas_call(
             functools.partial(
                 _dkv_kernel, scale=1.0 / (d ** 0.5), causal=causal,
-                block_q=block_q, block_k=block_k, n_q=t // block_q,
+                block_q=block_q, block_k=block_k, n_q=n_q, window=window,
+                group=group, t_blocks=t // block_q,
             ),
-            grid=(bh, t // block_k, t // block_q),
+            grid=(k2.shape[0], t // block_k, group * n_q),
             in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
             out_specs=[kv_spec, kv_spec],
             out_shape=[
-                jax.ShapeDtypeStruct((bh, t, d), k2.dtype),
-                jax.ShapeDtypeStruct((bh, t, d), v2.dtype),
+                jax.ShapeDtypeStruct(k2.shape, k2.dtype),
+                jax.ShapeDtypeStruct(v2.shape, v2.dtype),
             ],
             scratch_shapes=[
                 pltpu.VMEM((block_k, d), jnp.float32),
@@ -442,9 +569,10 @@ def _dkv_call(q2, k2, v2, do2, lse, dd, causal, block_q, block_k, interpret):
 
 
 @functools.partial(
-    jax.jit, static_argnames=("causal", "blocks", "interpret")
+    jax.jit, static_argnames=("causal", "blocks", "interpret", "window")
 )
-def _flash_pallas_bwd(q, k, v, out, lse, ct, causal, blocks, interpret):
+def _flash_pallas_bwd(q, k, v, out, lse, ct, causal, blocks, interpret,
+                      window=None):
     b, t, h, d = q.shape
     with jax.named_scope("flash_layout"):
         q2, k2, v2, do2, o2 = (_to2d(a) for a in (q, k, v, ct, out))
@@ -453,16 +581,18 @@ def _flash_pallas_bwd(q, k, v, out, lse, ct, causal, blocks, interpret):
             do2.astype(jnp.float32) * o2.astype(jnp.float32), -1
         )  # (BH, T)
     _, dq_blocks, dkv_blocks = blocks
-    dq = _dq_call(q2, k2, v2, do2, lse, dd, causal, *dq_blocks, interpret)
+    dq = _dq_call(
+        q2, k2, v2, do2, lse, dd, causal, *dq_blocks, interpret, window)
     dk, dv = _dkv_call(
-        q2, k2, v2, do2, lse, dd, causal, *dkv_blocks, interpret
+        q2, k2, v2, do2, lse, dd, causal, *dkv_blocks, interpret, window
     )
     with jax.named_scope("flash_layout"):
-        return tuple(_from2d(a, b, h, t, d) for a in (dq, dk, dv))
+        return (_from2d(dq, b, h, t, d),
+                *(_from2d(a, b, k.shape[2], t, d) for a in (dk, dv)))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def _flash(q, k, v, causal, blocks, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash(q, k, v, causal, blocks, interpret, window=None):
     """Differentiable flash attention: pallas kernels both directions.
     ``blocks`` holds the forward, dQ and dK/dV kernels' tiles.
 
@@ -473,32 +603,55 @@ def _flash(q, k, v, causal, blocks, interpret):
     dK/dV kernel (k-blocks outer, q-blocks inner), with the D = rowsum
     (dO ∘ O) vector computed by XLA outside.
     """
-    return _flash_pallas(q, k, v, causal, blocks, interpret)[0]
+    return _flash_pallas(q, k, v, causal, blocks, interpret, window)[0]
 
 
-def _flash_fwd(q, k, v, causal, blocks, interpret):
-    out, lse = _flash_pallas(q, k, v, causal, blocks, interpret)
+def _flash_fwd(q, k, v, causal, blocks, interpret, window=None):
+    out, lse = _flash_pallas(q, k, v, causal, blocks, interpret, window)
     return out, (q, k, v, out, lse)
 
 
-def _flash_bwd(causal, blocks, interpret, res, ct):
+def _flash_bwd(causal, blocks, interpret, window, res, ct):
     q, k, v, out, lse = res
-    return _flash_pallas_bwd(q, k, v, out, lse, ct, causal, blocks, interpret)
+    return _flash_pallas_bwd(
+        q, k, v, out, lse, ct, causal, blocks, interpret, window)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
 @functools.partial(
-    jax.jit, static_argnames=("causal", "blocks", "interpret")
+    jax.jit, static_argnames=("causal", "blocks", "interpret", "window")
 )
-def _flash_pallas(q, k, v, causal, blocks, interpret):
+def _flash_pallas(q, k, v, causal, blocks, interpret, window=None):
     b, t, h, d = q.shape
     with jax.named_scope("flash_layout"):
         q2, k2, v2 = _to2d(q), _to2d(k), _to2d(v)
-    out, lse = _fwd_call(q2, k2, v2, causal, *blocks[0], interpret)
+    out, lse = _fwd_call(q2, k2, v2, causal, *blocks[0], interpret, window)
     with jax.named_scope("flash_layout"):
         return _from2d(out, b, h, t, d), lse[..., 0]
+
+
+def masked_dense_attention(q, k, v, window=None):
+    """Causal dense attention with grouped KV heads and an optional
+    window, f32 scores: the XLA branch beside the kernels for shapes
+    :func:`dense_attention` does not take (it materializes the
+    ``(B, H, T, T)`` scores, so it is for small ``T``)."""
+    b, t, h, d = q.shape
+    group = h // k.shape[2]
+    k, v = (jnp.repeat(a, group, axis=2) for a in (k, v))
+    s = jnp.einsum(
+        "bqhd,bkhd->bhqk", q, k, preferred_element_type=jnp.float32
+    ) / (d ** 0.5)
+    ahead = jnp.arange(t)[:, None] - jnp.arange(t)[None, :]
+    seen = ahead >= 0
+    if window is not None:
+        seen &= ahead < window
+    p = jax.nn.softmax(jnp.where(seen, s, _NEG_INF), axis=-1)
+    return jnp.einsum(
+        "bhqk,bkhd->bqhd", p.astype(v.dtype), v,
+        preferred_element_type=jnp.float32,
+    ).astype(q.dtype)
 
 
 def flash_attention(
@@ -509,6 +662,7 @@ def flash_attention(
     block_q: int | None = None,
     block_k: int | None = None,
     use_pallas=None,
+    window: int | None = None,
 ) -> jax.Array:
     """Tiled exact attention, ``(B, T, H, D) -> (B, T, H, D)``.
 
@@ -516,30 +670,48 @@ def flash_attention(
     mode on CPU), False = XLA dense attention, None = kernel on TPU, XLA
     elsewhere.
 
+    ``k`` and ``v`` may hold fewer heads than ``q`` (grouped KV heads,
+    ``H`` a multiple of ``H_kv``): query head ``h`` reads KV head
+    ``h // (H / H_kv)`` through the kernels' index maps, so nothing is
+    repeated in HBM, and dK/dV are summed over the group in the dK/dV
+    kernel's scratch. ``window`` (causal only): key ``j`` is visible to
+    query ``i`` iff ``i - window < j <= i``; tiles outside the window
+    are not in the grid at all. ``window=None`` and equal head counts
+    give the kernels as they were.
+
     Fully trainable: the custom VJP runs the standard FlashAttention
     backward as pallas kernels too (P recomputed from the saved
     log-sum-exp; dQ and fused dK/dV passes), so no (T, T) score matrix
     materializes in either direction.
 
-    ``block_q``/``block_k``: ``None`` = chosen from ``(T, D, dtype)`` by
-    ``choose_blocks``, for each of the three kernels; a given one wins
-    for all three and clamps to ``T``. Once the kernel is selected, a
-    ``T`` the blocks do not tile raises ``ValueError`` — it never becomes
-    a dense pass: a block must divide ``T`` and be sublane-aligned (a
-    multiple of 8), and compiled for the chip it must also be
-    lane-aligned (a multiple of 128) or span ``T``, because the backward
-    reads per-row residuals as ``(…, block_q)`` lane-major rows.
+    ``block_q``/``block_k``: ``None`` = chosen from ``(T, D, dtype,
+    window)`` by ``choose_blocks``, for each of the three kernels; a
+    given one wins for all three and clamps to ``T``. Once the kernel is
+    selected, a ``T`` the blocks do not tile raises ``ValueError`` — it
+    never becomes a dense pass: a block must divide ``T`` and be
+    sublane-aligned (a multiple of 8), and compiled for the chip it must
+    also be lane-aligned (a multiple of 128) or span ``T``, because the
+    backward reads per-row residuals as ``(…, block_q)`` lane-major rows.
     """
+    if window is not None and not causal:
+        raise ValueError("flash_attention: a window needs causal=True")
+    if q.shape[2] % k.shape[2] or k.shape != v.shape:
+        raise ValueError(
+            f"flash_attention: {q.shape[2]} query heads over KV of shape "
+            f"{k.shape} / {v.shape}"
+        )
     if use_pallas is None:
         use_pallas = pallas_supported()
     if not use_pallas:
-        return dense_attention(q, k, v, causal=causal)
+        if window is None and q.shape[2] == k.shape[2]:
+            return dense_attention(q, k, v, causal=causal)
+        return masked_dense_attention(q, k, v, window)
     interpret = pallas_interpret()
     t, d = q.shape[1], q.shape[3]
     blocks = tuple(
         (bq if block_q is None else min(block_q, t),
          bk if block_k is None else min(block_k, t))
-        for bq, bk in choose_blocks(t, d, q.dtype)
+        for bq, bk in choose_blocks(t, d, q.dtype, window)
     )
     for name, blk in (
         pair for tiles in blocks for pair in zip(("block_q", "block_k"), tiles)
@@ -556,4 +728,4 @@ def flash_attention(
                 f"{q.shape}) cannot compile for TPU: a block must be a "
                 f"multiple of {_LANE} or span T"
             )
-    return _flash(q, k, v, causal, blocks, interpret)
+    return _flash(q, k, v, causal, blocks, interpret, window)

@@ -231,3 +231,120 @@ def moe_ffn_dense_reference(
         stats["f"], stats["p"], stats["z"], stats["dropped"], num_experts
     )
     return res, aux
+
+
+# -- one chip's share of a dropless, sort-dispatched expert layer -----------
+
+def route_top_k(y2, router, top_k: int, scale: float = 1.0):
+    """Softmax scores over ALL experts in f32, the ``top_k`` largest,
+    their weights divided by their sum and multiplied by ``scale``.
+    ``(tokens, D) -> (tokens, k)`` weights and int32 expert ids, and the
+    ``(tokens, E)`` scores."""
+    logits = jnp.dot(
+        y2.astype(jnp.float32), router.astype(jnp.float32),
+        precision=lax.Precision.HIGHEST,
+    )
+    scores = jax.nn.softmax(logits, axis=-1)
+    weights, experts = lax.top_k(scores, top_k)
+    weights = weights / weights.sum(-1, keepdims=True) * scale
+    return weights, experts.astype(jnp.int32), scores
+
+
+def swiglu(x, w_gate, w_up, w_down):
+    """``W_down(silu(W_gate x) * (W_up x))`` without biases, in ``x``'s
+    dtype with f32 accumulation."""
+    dt = x.dtype
+    mm = lambda a, w: jnp.dot(
+        a, w.astype(dt), preferred_element_type=jnp.float32).astype(dt)
+    return mm(jax.nn.silu(mm(x, w_gate)) * mm(x, w_up), w_down)
+
+
+def moe_ffn_held(
+    params: dict,
+    y: jax.Array,
+    *,
+    top_k: int,
+    expert_offset: int = 0,
+    row_bound: int,
+    scale: float = 1.0,
+    routing_grad: bool = True,
+):
+    """The part of a sparse expert layer that the experts HELD here give
+    (the model-configs guide's section 4): routing scores all
+    ``params["router"].shape[1]`` experts, this chip holds experts
+    ``expert_offset .. expert_offset + E_held`` (the leading dimension of
+    ``params["w_gate"]``/``w_up``/``w_down``) and computes, for every
+    token, ``sum over its chosen experts held here of weight · expert``.
+    What the other experts would add is left out; no code stands in for
+    their chips or the exchange.
+
+    Dropless by sorting, not by capacity: the (token, choice) pairs that
+    name an expert held here are sorted by expert into one buffer of
+    ``row_bound`` rows, three grouped matrix products
+    (``jax.lax.ragged_dot``, the TPU compiler's own grouped kernel) run
+    the SwiGLU experts over it, and the rows are scattered back times
+    their weights. ``row_bound`` is static; pairs past it are dropped
+    and COUNTED (``rows_dropped``; a correct run reads 0).
+
+    ``routing_grad=False`` makes the routing weights constants of the
+    backward pass (``models/arch.py``, ``moe_routing_no_grad``, says when).
+
+    ``y``: ``(tokens, D)``. Returns ``(out, counters, (weights,
+    experts))``; the counters are f32 scalars: ``rows_held`` (pairs
+    routed to the experts held), ``load_max_over_mean`` (the fullest
+    expert's rows over the mean), ``rows_dropped`` and ``balance`` (the
+    load-balancing term, the one counter a gradient passes through).
+    """
+    tokens, d = y.shape
+    held = params["w_gate"].shape[0]
+    row_bound = min(row_bound, tokens * top_k)  # there are no more pairs
+    with jax.named_scope("moe_router"):
+        weights, experts, scores = route_top_k(
+            y, params["router"], top_k, scale)
+        if not routing_grad:
+            weights = lax.stop_gradient(weights)
+        # the load-balancing term E · sum_e f_e P_e over ALL experts scored,
+        # f_e the share of tokens that chose e (top_k under uniform
+        # routing); its gradient passes through P alone
+        routed = scores.shape[1]
+        share = jnp.bincount(experts.reshape(-1), length=routed) / tokens
+        balance = routed * jnp.sum(share * scores.mean(0))
+    with jax.named_scope("moe_dispatch"):
+        local = experts.reshape(-1) - expert_offset  # pair p = token·k + c
+        here = (local >= 0) & (local < held)
+        key = jnp.where(here, local, held)  # the others sort to the end
+        order = jnp.argsort(key, stable=True)[:row_bound]
+        counts = jnp.bincount(key, length=held + 1)[:held]
+        # rows of each expert that fit under the bound, in sorted order
+        ends = jnp.minimum(jnp.cumsum(counts), row_bound)
+        sizes = jnp.diff(ends, prepend=0).astype(jnp.int32)
+        valid = jnp.arange(row_bound) < ends[-1]
+        token = order // top_k
+        rows = jnp.take(y, token, axis=0)
+        row_weight = jnp.where(
+            valid, jnp.take(weights.reshape(-1), order), 0.0)
+    with jax.named_scope("moe_experts"):
+        dt = y.dtype
+        # a grouped product leaves the rows past its groups as they were
+        # in memory (seen on the v5e, PR 27: the gradient into such rows
+        # came back as garbage 1e5 times the true one), so every operand
+        # and result is cut to the valid rows, forward and backward
+        live = lambda a: jnp.where(valid[:, None], a, 0)
+        grouped = lambda a, w: live(lax.ragged_dot(
+            live(a), w.astype(dt), sizes, preferred_element_type=jnp.float32
+        ).astype(dt))
+        hidden = jax.nn.silu(grouped(rows, params["w_gate"])) * grouped(
+            rows, params["w_up"])
+        out_rows = grouped(hidden, params["w_down"])
+    with jax.named_scope("moe_dispatch"):
+        out = jnp.zeros((tokens, d), jnp.float32).at[token].add(
+            out_rows.astype(jnp.float32) * row_weight[:, None]
+        ).astype(dt)
+        total = counts.sum().astype(jnp.float32)
+        counters = {
+            "rows_held": total,
+            "load_max_over_mean": counts.max() * held / jnp.maximum(total, 1.0),
+            "rows_dropped": jnp.maximum(total - row_bound, 0.0),
+            "balance": balance,
+        }
+    return out, counters, (weights, experts)

@@ -165,8 +165,12 @@ def check_accum_steps(accum) -> int:
     return int(accum)
 
 
-def accumulated_value_and_grad(loss_fn: Callable, accum: int) -> Callable:
-    """(params, x, y) -> (loss, grads), processing the batch as ``accum``
+def accumulated_value_and_grad(
+    loss_fn: Callable, accum: int, has_aux: bool = False
+) -> Callable:
+    """(params, x, y) -> (loss, grads) — or ((loss, aux), grads) for a
+    ``loss_fn`` that returns ``(loss, aux)``, which only ``accum=1``
+    takes — processing the batch as ``accum``
     sequential ``lax.scan`` slices whose losses/gradients average —
     exactly the full-batch mean for equal slices (no model here carries
     batch statistics), at 1/accum of the peak activation memory. Used by
@@ -176,7 +180,12 @@ def accumulated_value_and_grad(loss_fn: Callable, accum: int) -> Callable:
     ``value_and_grad``. Validates via :func:`check_accum_steps`."""
     accum = check_accum_steps(accum)
     if accum == 1:
-        return jax.value_and_grad(loss_fn)
+        return jax.value_and_grad(loss_fn, has_aux=has_aux)
+    if has_aux:
+        raise ValueError(
+            f"accum_steps={accum}: a loss that returns counters beside "
+            "itself is stepped without accumulation"
+        )
 
     def value_and_grad(params, x, y):
         xs = x.reshape(accum, x.shape[0] // accum, *x.shape[1:])

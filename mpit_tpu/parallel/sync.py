@@ -173,8 +173,13 @@ class DataParallelTrainer:
         quant: Optional[str] = None,
         bucket_bytes: Optional[int] = None,
         obs: Optional[obs_core.ObsConfig] = None,
+        jit_init: bool = False,
     ):
-        """``accum_steps``: gradient accumulation — each step's local
+        """``jit_init``: make the state in one jitted program, born
+        replicated, instead of op by op and then placed: for a model
+        whose eager ``init`` is a forward pass too large to run that way.
+
+        ``accum_steps``: gradient accumulation — each step's local
         batch is processed as that many sequential slices (``lax.scan``)
         whose gradients average before the one optimizer update. The
         math is EXACTLY the full-batch step (equal slice sizes, mean
@@ -192,6 +197,7 @@ class DataParallelTrainer:
         )
         self.accum_steps = accum = int(accum_steps)
         self.donate_state = donate_state
+        self.jit_init = jit_init
         self.quant = dp_quant_from_env() if quant is None else quant
         if self.quant not in _quant.QUANT_MODES:
             raise ValueError(
@@ -218,15 +224,28 @@ class DataParallelTrainer:
 
         axis = self.topo.worker_axis
         mesh = self.topo.mesh
-        local_vg = common.accumulated_value_and_grad(self.loss_fn, accum)
+        # a model may offer (params, x, y) -> (loss, counters) beside its
+        # apply (expert layers' routing counts): the step's metrics then
+        # carry the counters
+        counting = (
+            getattr(model, "loss_with_counters", None)
+            if loss_fn is None and not self.bucketed else None
+        )
+        local_vg = common.accumulated_value_and_grad(
+            counting or self.loss_fn, accum, has_aux=counting is not None
+        )
         self._local_vg = local_vg
 
         def train_step(state: common.TrainState, x, y):
             loss, grads = local_vg(state.params, x, y)
+            counters = {}
+            if counting is not None:
+                loss, counters = loss
             # the one collective of the step: grad average over workers
             with jax.named_scope("grad_exchange"):
                 grads = jax.lax.pmean(grads, axis)
                 loss = jax.lax.pmean(loss, axis)
+                counters = jax.lax.pmean(counters, axis)
             with jax.named_scope("optimizer"):
                 updates, opt_state = self.optimizer.update(
                     grads, state.opt_state, state.params
@@ -236,7 +255,7 @@ class DataParallelTrainer:
                 common.TrainState(
                     params=params, opt_state=opt_state, step=state.step + 1
                 ),
-                {"loss": loss},
+                {"loss": loss, **counters},
             )
 
         self._step = jax.jit(
@@ -256,10 +275,14 @@ class DataParallelTrainer:
         """Initialize replicated state. ``sample_x`` is a *per-worker* shaped
         batch (leading dim = per-worker batch); only shapes matter."""
         with span("mpit.setup.init_state"):
-            variables = self.model.init(rng, jnp.asarray(sample_x))
-            state = common.TrainState.create(
-                variables["params"], self.optimizer
+            create = lambda key, x: common.TrainState.create(
+                self.model.init(key, x)["params"], self.optimizer
             )
+            if self.jit_init:
+                return jax.block_until_ready(jax.jit(
+                    create, out_shardings=self.topo.replicated_sharding()
+                )(rng, jnp.asarray(sample_x)))
+            state = create(rng, jnp.asarray(sample_x))
             # waited for, so that the span reads set-up done, not dispatched
             return jax.block_until_ready(
                 jax.device_put(state, self.topo.replicated_sharding())

@@ -121,6 +121,12 @@ def tp_state_specs(state):
 
 def check_tp_divisibility(model, tp: int) -> None:
     """d_model / num_heads / d_ff must all split across the tp axis."""
+    if getattr(model, "arch", None) is not None:
+        raise ValueError(
+            "tensor-parallel rules are written for the GPT-2 block "
+            "(Dense_0..Dense_3); a model described by arch (wq, wk, wv, "
+            "wo, SwiGLU and expert weights) has none yet"
+        )
     d_model = getattr(model, "d_model", tp)
     for field, need in (
         ("d_model", d_model),

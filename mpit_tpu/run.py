@@ -90,13 +90,32 @@ def _build_model(cfg: TrainConfig, meta: dict, worker_axis: str = None):
             f"{cfg.model!r} runs without it",
             stacklevel=2,
         )
-    if cfg.moe_experts and not (name == "transformer" and algo == "moe-sync"):
+    if cfg.arch is not None:
+        if name != "transformer":
+            raise ValueError(
+                f"arch describes a transformer; model={cfg.model!r} cannot "
+                "take it"
+            )
+        if algo != "sync":
+            raise ValueError(
+                f"algo={cfg.algo!r} is not built for a model described by "
+                "arch: only sync runs it (its step carries the routing "
+                "counters and its state is made in one program); seq-sync, "
+                "moe-sync and pp-sync shard the GPT-2 block by its own "
+                "parameter names"
+            )
+    if cfg.moe_experts and (
+        cfg.arch is not None
+        or not (name == "transformer" and algo == "moe-sync")
+    ):
         import warnings
 
         warnings.warn(
-            f"moe_experts={cfg.moe_experts} only applies with "
-            f"model='transformer' and algo='moe-sync'; model={cfg.model!r} "
-            f"algo={cfg.algo!r} runs without experts",
+            f"moe_experts={cfg.moe_experts} is the GShard expert layer of "
+            "the GPT-2 block and only applies with model='transformer', "
+            f"algo='moe-sync' and no arch; model={cfg.model!r} "
+            f"algo={cfg.algo!r} runs without it (an arch brings its own "
+            "experts)",
             stacklevel=2,
         )
     if cfg.seq_impl != "ring" and algo != "seq-sync":
@@ -107,6 +126,14 @@ def _build_model(cfg: TrainConfig, meta: dict, worker_axis: str = None):
             f"(no sequence axis exists under algo={cfg.algo!r}); running "
             "plain dense attention",
             stacklevel=2,
+        )
+    if name == "transformer" and cfg.arch is not None:
+        return get_model(
+            cfg.model,
+            vocab_size=meta.get("vocab_size", 10_000),
+            arch=cfg.arch,
+            remat=cfg.remat,
+            attn_impl=cfg.attn_impl,
         )
     if name == "transformer":
         return get_model(
@@ -256,7 +283,8 @@ def build_trainer(cfg: TrainConfig, model, opt, topo):
                                staleness=cfg.staleness)
     if algo == "sync":
         return DataParallelTrainer(model, opt, topo,
-                                   accum_steps=cfg.grad_accum)
+                                   accum_steps=cfg.grad_accum,
+                                   jit_init=cfg.arch is not None)
     if algo == "zero-sync":
         from mpit_tpu.parallel import ZeroDataParallelTrainer
 

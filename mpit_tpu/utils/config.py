@@ -124,6 +124,15 @@ class TrainConfig:
     moe_top_k: int = 1
     moe_balance_weight: float = 0.0
     moe_zloss_weight: float = 0.0
+    # transformer only: an architecture in its source's own keys (a model's
+    # published config.json as a dict, with the keys a cut replaces set to
+    # what is run; models/arch.py lists the keys read): RMSNorm, rotary
+    # positions, a per-layer pattern of head counts, windows and dense or
+    # sparse SwiGLU feed-forwards, an untied head. It replaces layers,
+    # d_model, heads and d_ff; its own experts need no moe_* field. Runs
+    # under algo=sync. None = the GPT-2 block those fields describe.
+    # On the command line: a JSON object
+    arch: Optional[dict] = None
     # image models (ImageNet-shaped configs; smaller for CPU-mesh smoke runs)
     image_size: int = 224
     # plumbing
@@ -186,7 +195,7 @@ class TrainConfig:
                 typ = {
                     "int": int, "float": float, "str": str,
                     "Optional[int]": int, "Optional[float]": float,
-                    "Optional[str]": str,
+                    "Optional[str]": str, "Optional[dict]": json.loads,
                 }.get(str(f.type), str)
                 p.add_argument(flag, type=typ, default=argparse.SUPPRESS)
         return p

@@ -104,14 +104,16 @@ def snapshot() -> dict:
 
 
 def reset() -> None:
-    global _unit
+    global _unit, _unit_text
     _registry.clear()
-    _unit = None
+    _unit = _unit_text = None
 
 
 # (jitted program, its arguments as shapes) of the unit the newest fit loop
 # dispatches: what ``unit_program_text`` compiles again
 _unit = None
+# its compiled text, once asked for: a second reader does not compile again
+_unit_text = None
 
 
 def remember_unit(program, *args) -> None:
@@ -121,7 +123,8 @@ def remember_unit(program, *args) -> None:
     and shardings are kept, so no buffer outlives its donation; the program
     (and the trainer it closes over) stays referenced until the next fit
     loop or ``reset()``."""
-    global _unit
+    global _unit, _unit_text
+    _unit_text = None
     _unit = (
         program,
         jax.tree.map(
@@ -153,8 +156,11 @@ def unit_program_text() -> Optional[str]:
     way; only a fresh compile names them as this source does. That costs the
     unit's whole compile time (27 s for a GPT-2-small round on a v5e): call
     it after a measured window, never inside one."""
+    global _unit_text
     if _unit is None or not hasattr(_unit[0], "lower"):
         return None
+    if _unit_text is not None:
+        return _unit_text
     from jax.experimental.compilation_cache import compilation_cache
 
     program, args = _unit
@@ -162,11 +168,12 @@ def unit_program_text() -> Optional[str]:
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()  # the flag is read once a cache's life
     try:
-        return (
+        _unit_text = (
             program.lower(*args)
             .compile(compiler_options={"xla_dump_hlo_as_text": False})
             .as_text()
         )
+        return _unit_text
     finally:
         jax.config.update("jax_enable_compilation_cache", cached)
         compilation_cache.reset_cache()

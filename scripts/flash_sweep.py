@@ -2,6 +2,7 @@
 """Time the flash-attention kernels over tile sizes on the chip.
 
     python3 scripts/flash_sweep.py [--b 8 --t 1024 --h 12 --d 64] [--blocks 128,256,512,1024]
+    python3 scripts/flash_sweep.py --b 1 --t 8192 --h 72 --kv-heads 8 --d 128 --window 512
 
 For every ``block_q x block_k`` it times the forward, dQ and dK/dV kernels
 alone (on the kernels' own ``(B·H, T, D)`` layout) and the whole
@@ -10,8 +11,11 @@ beside ``dense_attention``'s. A kernel takes well under a millisecond, less
 than a dispatch can cost, so ``--iters`` calls run inside ONE jitted
 ``fori_loop``, each fed the one before's output, and the host clock around
 ``block_until_ready`` is divided by ``--iters``. A tile the chip's compiler
-refuses is reported as such. Refuses to run without a TPU: a CPU time is not
-a device time. PERF.md section 6 (PR 26) holds the table this printed.
+refuses is reported as such. ``--kv-heads`` gives K and V fewer (grouped)
+heads, ``--window`` a sliding window; dense attention is left out where its
+float32 scores would pass 4 GB. Refuses to run without a TPU: a CPU time is
+not a device time. PERF.md section 6 (PR 26, PR 27) holds the tables this
+printed.
 """
 
 import argparse
@@ -53,7 +57,7 @@ def grad_of(attend, k, v, ct):
 
     def step(q):
         dq, dk, dv = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
-        return dq + 0 * (dk + dv)
+        return dq + (0 * (dk + dv).sum()).astype(dq.dtype)
     return step
 
 
@@ -63,6 +67,8 @@ def main() -> int:
     p.add_argument("--t", type=int, default=1024)
     p.add_argument("--h", type=int, default=12)
     p.add_argument("--d", type=int, default=64)
+    p.add_argument("--kv-heads", type=int, default=0, help="0 = --h")
+    p.add_argument("--window", type=int, default=0, help="0 = none")
     p.add_argument("--blocks", default="128,256,512,1024")
     p.add_argument("--iters", type=int, default=20)
     p.add_argument("--out", default="")
@@ -72,9 +78,10 @@ def main() -> int:
         return 3
 
     b, t, h, d = args.b, args.t, args.h, args.d
+    h_kv, window = args.kv_heads or h, args.window or None
     keys = jax.random.split(jax.random.key(0), 4)
-    q, k, v, ct = (jax.random.normal(kk, (b, t, h, d), jnp.bfloat16)
-                   for kk in keys)
+    q, k, v, ct = (jax.random.normal(kk, (b, t, heads, d), jnp.bfloat16)
+                   for kk, heads in zip(keys, (h, h_kv, h_kv, h)))
     q2, k2, v2, do2 = (fa._to2d(a) for a in (q, k, v, ct))
     rows = []
 
@@ -83,11 +90,19 @@ def main() -> int:
         print(json.dumps(row), flush=True)
 
     emit({"device": jax.devices()[0].device_kind, "shape": [b, t, h, d],
+          "kv_heads": h_kv, "window": window,
           "dtype": "bfloat16", "causal": True, "iters": args.iters,
-          "chosen": fa.choose_blocks(t, d, jnp.bfloat16)})
-    dense = lambda a, b_, c: dense_attention(a, b_, c, causal=True)
-    chosen = lambda a, b_, c: fa.flash_attention(a, b_, c, causal=True)
-    for impl, attend in (("dense", dense), ("flash, chosen tiles", chosen)):
+          "chosen": fa.choose_blocks(t, d, jnp.bfloat16, window)})
+    plain = window is None and h_kv == h
+    dense = lambda a, b_, c: (
+        dense_attention(a, b_, c, causal=True) if plain
+        else fa.masked_dense_attention(a, b_, c, window))
+    chosen = lambda a, b_, c: fa.flash_attention(
+        a, b_, c, causal=True, window=window)
+    impls = [("flash, chosen tiles", chosen)]
+    if b * h * t * t * 4 <= 4e9:
+        impls.insert(0, ("dense", dense))
+    for impl, attend in impls:
         emit({"impl": impl,
               "fwd_ms": ms(lambda a: attend(a, k, v), q, args.iters),
               "grad_ms": ms(grad_of(attend, k, v, ct), q, args.iters)})
@@ -96,7 +111,8 @@ def main() -> int:
     for bq in sides:
         for bk in sides:
             row = {"block_q": bq, "block_k": bk}
-            tiles = (True, bq, bk, False)  # causal, the tile, compiled
+            # causal, the tile, compiled, the window
+            tiles = (True, bq, bk, False, window)
             try:
                 fwd = lambda a: fa._fwd_call(a, k2, v2, *tiles)
                 row["fwd_ms"] = ms(lambda a: fwd(a)[0], q2, args.iters)
@@ -106,13 +122,15 @@ def main() -> int:
                              * out2.astype(jnp.float32), -1)
                 row["dq_ms"] = ms(lambda a: fa._dq_call(
                     q2, k2, v2, a, lse, dd, *tiles), do2, args.iters)
+                # fed through K: dK has K's shape under grouped heads too
                 row["dkv_ms"] = ms(lambda a: fa._dkv_call(
-                    q2, k2, v2, a, lse, dd, *tiles)[0], do2, args.iters)
+                    q2, a, v2, do2, lse, dd, *tiles)[0], k2, args.iters)
                 row["kernels_ms"] = (row["fwd_ms"] + row["dq_ms"]
                                      + row["dkv_ms"])
                 row["grad_ms"] = ms(grad_of(
                     lambda a, b_, c: fa.flash_attention(
-                        a, b_, c, causal=True, block_q=bq, block_k=bk),
+                        a, b_, c, causal=True, block_q=bq, block_k=bk,
+                        window=window),
                     k, v, ct), q, args.iters)
             except Exception as e:  # the compiler's refusal is a result
                 row["refused"] = str(e).splitlines()[0][:200]

@@ -63,6 +63,8 @@ def no_compile_cache():
         ((1, 2048, 8, 128), jnp.bfloat16),
         # the chooser's largest estimate: float32 tiles at D = 128
         ((1, 1024, 4, 128), jnp.float32),
+        # and from 8,192 tokens on, where all three kernels take 1,024
+        ((1, 8192, 2, 128), jnp.float32),
     ],
 )
 def test_flash_kernels_compile_with_the_chosen_tiles(
@@ -82,3 +84,39 @@ def test_flash_kernels_compile_with_the_chosen_tiles(
         x, x, x
     ).compile()
     assert compiled.as_text().count("custom_call_target=\"tpu_custom_call\"") == 3
+
+
+@pytest.mark.parametrize(
+    "heads,window,calls",
+    [
+        # laguna_s21_sync_1chip_8k's sliding layers: 72 query heads over 8
+        # KV heads, window 512
+        (72, 512, 3),
+        # and its full layers: 48 query heads, causal
+        (48, None, 3),
+    ],
+)
+def test_grouped_and_windowed_kernels_compile_at_8k(
+    heads, window, calls, one_chip, no_compile_cache
+):
+    """T = 8,192, D = 128, 8 KV heads, bfloat16, at the chosen tiles:
+    forward, dQ and the dK/dV kernel that sums over each KV head's group,
+    with the index maps that walk only the window's tiles."""
+    t, d = 8192, 128
+    blocks = fa.choose_blocks(t, d, jnp.bfloat16, window)
+    q = jax.ShapeDtypeStruct((1, t, heads, d), jnp.bfloat16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((1, t, 8, d), jnp.bfloat16, sharding=one_chip)
+
+    def loss(q, k, v):
+        out = fa._flash(q, k, v, True, blocks, False, window)
+        return out.astype(jnp.float32).sum()
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, kv, kv
+    ).compile()
+    text = compiled.as_text()
+    assert text.count("custom_call_target=\"tpu_custom_call\"") == calls
+    names = ("flash_window_fwd", "flash_window_dq", "flash_window_dkv") \
+        if window else ("flash_fwd", "flash_dq", "flash_dkv")
+    for name in names:  # the trace's per-kernel metrics read these names
+        assert f"%{name}." in text or f"%{name} " in text, name
