@@ -409,6 +409,23 @@ class Block(nn.Module):
         return out
 
 
+#: what a rematerialized block keeps for its backward pass, by
+#: ``checkpoint_name``; everything else in the block is computed again.
+#: ``ops/flash_attention.py`` sets all three where the kernels are taken:
+#: the forward kernel's output and log-sum-exp, which its backward kernels
+#: read (recomputing them is the dearest kernel run twice), and q, k and v
+#: as they enter it (after the rotary), which spares the recomputed
+#: projections and rotary too. A block that reaches no kernel (dense, ring,
+#: Ulysses) names nothing and keeps nothing.
+_REMAT_KEEPS = ("flash_out", "flash_lse", "flash_qkv")
+
+# explicit names at the call sites: nn.remat renames the wrapped class
+# (CheckpointBlock), which would fork the param tree between remat modes
+_RematBlock = nn.remat(
+    Block, policy=jax.checkpoint_policies.save_only_these_names(*_REMAT_KEEPS)
+)
+
+
 def _sown_by_name(collection: dict) -> dict:
     """``{"Block_i": {name: (value, ...)}}`` -> ``{name: [values]}`` over
     the blocks that sowed it."""
@@ -469,9 +486,11 @@ class TransformerLM(nn.Module):
     max_len: int = 1024
     compute_dtype: Any = jnp.bfloat16
     seq_axis: Optional[str] = None
-    # rematerialize each block on the backward pass: activation memory
-    # drops from O(layers) to O(1) blocks for ~1/3 more FLOPs — the
-    # standard jax.checkpoint trade to fit longer T or bigger B in HBM
+    # rematerialize each block on the backward pass, all but the
+    # attention kernel's named inputs and outputs (_REMAT_KEEPS):
+    # activation memory drops from O(layers) to O(1) blocks plus those
+    # few tensors a layer, for ~1/3 more FLOPs — the standard
+    # jax.checkpoint trade to fit longer T or bigger B in HBM
     remat: bool = False
     # mixture-of-experts FFN: moe_experts > 0 replaces every block's MLP
     # with a top-k-routed MoE (ops/moe.py); moe_axis names the mesh axis
@@ -563,7 +582,7 @@ class TransformerLM(nn.Module):
         d = specs[0].d_model
         embed = nn.Embed(self.vocab_size, d, dtype=dt, name="Embed_0")
         x = embed(tokens)
-        block_cls = nn.remat(Block) if self.remat else Block
+        block_cls = _RematBlock if self.remat else Block
         for i, spec in enumerate(specs):
             x = block_cls(
                 d_model=d, num_heads=spec.num_heads, d_ff=spec.d_ff,
@@ -646,9 +665,7 @@ class TransformerLM(nn.Module):
         # (B, t) positions — the table gather broadcasts either way
         pos = jnp.asarray(offset)[..., None] + jnp.arange(t_local)
         x = embed(tokens) + pos_table[pos].astype(dt)
-        # explicit names: nn.remat renames the wrapped class (Checkpoint
-        # Block), which would fork the param tree between remat modes
-        block_cls = nn.remat(Block) if self.remat else Block
+        block_cls = _RematBlock if self.remat else Block
         for i in range(self.num_layers):
             x = block_cls(
                 d_model=self.d_model,

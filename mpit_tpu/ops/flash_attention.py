@@ -44,6 +44,19 @@ transposed dK/dV kernel as ``(B·H, 8, T)`` sublane-broadcast rows. A
 ``(1, block_q)`` block of a 2-D ``(B·H, T)`` array — the first version
 of this file — is refused by the TPU lowering whenever ``B·H > 1``.
 
+Residuals are named. The backward kernels read ``(q, k, v, out, lse)``;
+under a ``jax.checkpoint`` with no policy all five are recomputed on the
+way back, and recomputing ``out`` and ``lse`` is the forward kernel, the
+dearest of the three for what it does, run a second time (34.8 ms of the
+Laguna cell's 467 ms step at 8,192 tokens, PERF.md section 6, PR 28). So
+the forward rule passes ``out`` and ``lse`` through ``checkpoint_name``
+(``flash_out``, ``flash_lse``) and the public wrapper does the same for
+``q``, ``k`` and ``v`` as they enter the kernel (``flash_qkv``), as jax's
+own TPU kernel does under ``residual_checkpoint_name``: a caller's
+``save_only_these_names`` policy (``models/transformer._REMAT_KEEPS``)
+then keeps them, the recomputed forward call is dead code and so is what
+only fed it. Outside a ``jax.checkpoint`` a name lowers to nothing.
+
 In a trace the three kernels run under ``jax.named_scope``s
 ``flash_fwd``, ``flash_dq`` and ``flash_dkv``, and the transposes into
 and out of the kernels' layout with the ``D`` reduction under
@@ -64,6 +77,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -608,6 +622,10 @@ def _flash(q, k, v, causal, blocks, interpret, window=None):
 
 def _flash_fwd(q, k, v, causal, blocks, interpret, window=None):
     out, lse = _flash_pallas(q, k, v, causal, blocks, interpret, window)
+    # named outside the jitted call, where a jax.checkpoint round the
+    # caller sees them (module text, "Residuals are named")
+    out = checkpoint_name(out, "flash_out")
+    lse = checkpoint_name(lse, "flash_lse")
     return out, (q, k, v, out, lse)
 
 
@@ -728,4 +746,5 @@ def flash_attention(
                 f"{q.shape}) cannot compile for TPU: a block must be a "
                 f"multiple of {_LANE} or span T"
             )
+    q, k, v = (checkpoint_name(a, "flash_qkv") for a in (q, k, v))
     return _flash(q, k, v, causal, blocks, interpret, window)

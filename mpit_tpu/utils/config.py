@@ -74,9 +74,11 @@ class TrainConfig:
     # alexnet): "conv" (textbook) or "space_to_depth" (same function,
     # MXU-friendlier input layout — mpit_tpu/ops/stem.py)
     stem: str = "conv"
-    # rematerialize blocks on backward (resnet50, transformer): trades
-    # ~1/3 extra FLOPs for O(1)-block activation memory — bigger batches
-    # or longer sequences per chip (jax.checkpoint via flax nn.remat)
+    # rematerialize blocks on backward (resnet50, transformer; in a
+    # transformer block all but the attention kernel's named inputs and
+    # outputs): trades ~1/3 extra FLOPs for O(1)-block activation memory
+    # — bigger batches or longer sequences per chip (jax.checkpoint via
+    # flax nn.remat)
     remat: bool = False
     # sequence models
     seq_len: int = 32

@@ -13,6 +13,7 @@ file; where no topology can be described the tests skip.
 
 import importlib
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -120,3 +121,67 @@ def test_grouped_and_windowed_kernels_compile_at_8k(
         if window else ("flash_fwd", "flash_dq", "flash_dkv")
     for name in names:  # the trace's per-kernel metrics read these names
         assert f"%{name}." in text or f"%{name} " in text, name
+
+
+def test_remat_block_keeps_the_kernels_residuals_at_8k(
+    one_chip, no_compile_cache, monkeypatch
+):
+    """The gradient of a two-layer described model under ``remat`` at
+    8,192 tokens, one window layer (72 heads) and one full layer (48), as
+    ``laguna_s21_sync_1chip_8k`` has them (hidden 1,024 and a thin FFN, to
+    keep the compile short: what the block keeps depends on the heads
+    alone). One forward kernel a layer, where a remat with no policy runs
+    it twice, and no more scratch than that remat's plus the bytes kept:
+    bf16 ``out`` and the roped ``q`` 151.0 + 151.0 MB (window) and 100.7 +
+    100.7 MB (full), ``k`` and ``v`` 33.6 MB a layer, f32 ``lse`` 2.4 + 1.6
+    MB: 574,357,504 bytes. (Read here: 63 MB more; the recomputation it
+    spares had its own scratch.)"""
+    import flax.linen as nn
+
+    from mpit_tpu.models import transformer
+
+    # on the CPU platform flash_force means interpret mode; the kernels
+    # have to compile for the described chip
+    monkeypatch.setattr(fa, "pallas_interpret", lambda: False)
+    t = 8192
+    arch = {
+        "hidden_size": 1024, "intermediate_size": 512, "num_hidden_layers": 2,
+        "num_key_value_heads": 8, "head_dim": 128, "sliding_window": 512,
+        "layer_types": ["sliding_attention", "full_attention"],
+        "num_attention_heads_per_layer": [72, 48], "gating": "per-head",
+        "rope_parameters": {"rope_theta": 10000},
+    }
+    kept = sum(t * (2 * h + 2 * 8) * 128 * 2 + h * t * 4 for h in (72, 48))
+    assert kept == 574_357_504
+    model = transformer.TransformerLM(
+        vocab_size=256, arch=arch, attn_impl="flash_force", remat=True)
+    on_chip = lambda s: jax.ShapeDtypeStruct(
+        s.shape, s.dtype, sharding=one_chip)
+    params = jax.tree.map(on_chip, jax.eval_shape(
+        lambda: model.clone(attn_impl="xla").init(
+            jax.random.key(0), jnp.zeros((1, 16), jnp.int32))["params"]))
+    tokens = on_chip(jax.ShapeDtypeStruct((1, t), jnp.int32))
+
+    def compiled():
+        return jax.jit(jax.grad(lambda p, x: jnp.square(
+            model.apply({"params": p}, x)).mean())).lower(
+                params, tokens).compile()
+
+    def kernels(text):
+        return {name: len(re.findall(
+            rf"%{name}[. ][^\n]*custom_call_target=\"tpu_custom_call\"", text))
+            for name in ("flash_window_fwd", "flash_window_dq",
+                         "flash_window_dkv", "flash_fwd", "flash_dq",
+                         "flash_dkv")}
+
+    step = compiled()
+    assert set(kernels(step.as_text()).values()) == {1}
+    monkeypatch.setattr(
+        transformer, "_RematBlock", nn.remat(transformer.Block))
+    plain = compiled()
+    assert kernels(plain.as_text()) == {
+        "flash_window_fwd": 2, "flash_window_dq": 1, "flash_window_dkv": 1,
+        "flash_fwd": 2, "flash_dq": 1, "flash_dkv": 1}
+    grown = (step.memory_analysis().temp_size_in_bytes
+             - plain.memory_analysis().temp_size_in_bytes)
+    assert grown <= kept, grown

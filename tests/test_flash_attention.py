@@ -7,6 +7,7 @@ the online-softmax edge cases (multi-block running max updates, fully
 masked leading blocks).
 """
 
+import collections
 import importlib
 
 import jax
@@ -327,3 +328,104 @@ class TestNoFetchForAMaskedTile:
         np.testing.assert_allclose(
             np.asarray(clamped[0]), np.asarray(want), rtol=2e-5, atol=2e-5
         )
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs inside it."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for param in eqn.params.values():
+            for sub in param if isinstance(param, (list, tuple)) else [param]:
+                if hasattr(sub, "eqns") or hasattr(sub, "jaxpr"):
+                    yield from _eqns(getattr(sub, "jaxpr", sub))
+
+
+def _kernel_calls(jaxpr):
+    """``{scope: count}`` of the ``pallas_call``s in a jaxpr, each under
+    the innermost named scope of its call site."""
+    return collections.Counter(
+        str(eqn.source_info.name_stack).split("/")[-1]
+        for eqn in _eqns(jaxpr) if eqn.primitive.name == "pallas_call")
+
+
+def _primitives(jaxpr):
+    """``{primitive: count}`` over a jaxpr and every jaxpr inside it."""
+    return collections.Counter(eqn.primitive.name for eqn in _eqns(jaxpr))
+
+
+# a window layer and a full layer of the described block: grouped KV
+# heads, rotary positions, the per-head gate, dense SwiGLU
+_TWO_LAYER_ARCH = {
+    "hidden_size": 32, "intermediate_size": 64, "num_hidden_layers": 2,
+    "num_key_value_heads": 2, "head_dim": 16, "sliding_window": 8,
+    "layer_types": ["sliding_attention", "full_attention"],
+    "num_attention_heads_per_layer": [6, 4], "gating": "per-head",
+    "rope_parameters": {"rope_theta": 10000, "partial_rotary_factor": 0.5},
+}
+
+
+def _two_layer_lm(block, **kw):
+    from mpit_tpu.models.transformer import TransformerLM
+
+    if block == "described":
+        return TransformerLM(vocab_size=31, arch=_TWO_LAYER_ARCH,
+                             compute_dtype=jnp.float32, **kw)
+    return TransformerLM(vocab_size=31, max_len=32, num_layers=2, d_model=32,
+                         num_heads=2, compute_dtype=jnp.float32, **kw)
+
+
+class TestRematKeepsTheKernelsResiduals:
+    """``TransformerLM(remat=True)`` recomputes a block on the way back,
+    all but what the kernel branch names (``transformer._REMAT_KEEPS``):
+    the forward kernel runs once a layer, not twice."""
+
+    @staticmethod
+    def _problem(block, **kw):
+        """The model, its gradient function and parameters to take it at."""
+        tokens = jax.random.randint(jax.random.key(1), (2, 32), 0, 31)
+        model = _two_layer_lm(block, remat=True, **kw)
+        params = jax.jit(model.clone(remat=False, attn_impl="xla").init)(
+            jax.random.key(0), tokens)["params"]
+        grad = lambda m: jax.grad(lambda p: jnp.square(
+            m.apply({"params": p}, tokens)).mean())
+        return model, grad, params
+
+    @pytest.mark.parametrize("block,forward_scopes", [
+        ("gpt2", {"flash_fwd": 2}),
+        ("described", {"flash_window_fwd": 1, "flash_fwd": 1}),
+    ])
+    def test_forward_kernel_once_a_layer_and_the_same_gradient(
+        self, block, forward_scopes
+    ):
+        model, grad, params = self._problem(block, attn_impl="flash_force")
+        calls = _kernel_calls(jax.make_jaxpr(grad(model))(params).jaxpr)
+        # two layers, three kernels each (the parent: the forward twice)
+        assert sum(calls.values()) == 6, calls
+        assert {s: n for s, n in calls.items()
+                if s.endswith("fwd")} == forward_scopes
+        want = jax.jit(grad(model.clone(remat=False)))(params)
+        got = jax.jit(grad(model))(params)
+        for (path, a), b in zip(
+            jax.tree_util.tree_flatten_with_path(want)[0],
+            jax.tree.leaves(got),
+        ):
+            np.testing.assert_allclose(
+                np.asarray(b), np.asarray(a), rtol=1e-6, atol=1e-6,
+                err_msg=jax.tree_util.keystr(path))
+
+    @pytest.mark.parametrize("block", ["gpt2", "described"])
+    def test_dense_attention_names_nothing_and_keeps_nothing(
+        self, block, monkeypatch
+    ):
+        """``attn_impl="xla"``: the program of a remat with no policy."""
+        import flax.linen as nn
+
+        from mpit_tpu.models import transformer
+
+        model, grad, params = self._problem(block, attn_impl="xla")
+        with_policy = _primitives(jax.make_jaxpr(grad(model))(params).jaxpr)
+        assert "name" not in with_policy
+        monkeypatch.setattr(
+            transformer, "_RematBlock", nn.remat(transformer.Block))
+        assert _primitives(
+            jax.make_jaxpr(grad(model))(params).jaxpr) == with_policy
