@@ -670,128 +670,6 @@ def bench_wire(cpu_smoke: bool = False) -> dict:
     }
 
 
-def bench_dp(
-    cpu_smoke: bool = False, quant: str = "int8", bucket_bytes: int = None,
-    steps: int = None,
-) -> dict:
-    """Sync-DP gradient-exchange A/B (the ``--dp`` preset): the same
-    staged bucketed exchange at raw f32 width vs quantized codes, same
-    seed, same bucket plan, same platform — the collective-path half of
-    the fast-wire item (the socket half is ``--wire``).
-
-    Both legs warm uninstrumented, then arm obs for the timed window
-    (the attribute-swap pattern bench_ps_literal established): each wire
-    hop is a separate collective-only XLA program journaled as a
-    ``send``, quant math blocks inside ``compute`` spans, so the
-    roofline split measures the wire *shrinking* under quantization
-    instead of hiding quantize cost in the wire figure
-    (``phase_source: "obs"``). The same journals yield the dynamics
-    roll-up — EF-residual elastic distance riding next to samples/s, so
-    a quantized-speedup claim carries its convergence-cost evidence.
-
-    On the CPU mesh the staged hops run serially (one collective
-    program in flight — the rendezvous bound); the byte drop and the
-    wire-fraction drop are real there, the overlap itself materializes
-    on hardware. The JSON line says which regime produced the number.
-    """
-    import tempfile
-
-    import jax
-    import jax.numpy as jnp
-    import optax
-
-    import mpit_tpu
-    from mpit_tpu.data import load_mnist
-    from mpit_tpu.models import LeNet
-    from mpit_tpu.obs import ObsConfig, roofline
-    from mpit_tpu.obs.dynamics import aggregate_dynamics
-    from mpit_tpu.parallel import DataParallelTrainer
-
-    if quant not in ("bf16", "int8"):
-        raise ValueError(f"--dp quant must be bf16|int8, got {quant!r}")
-    mpit_tpu.finalize()
-    topo = mpit_tpu.init()
-    w = topo.num_workers
-    pwb = 8 if cpu_smoke else 128
-    steps = steps or (8 if cpu_smoke else 60)
-    if bucket_bytes is None:
-        # small enough that LeNet still splits into several buckets —
-        # the plan must exercise the pipeline, not collapse to one hop
-        bucket_bytes = 64 << 10
-    gb = pwb * w
-    x_tr, y_tr, *_ = load_mnist(synthetic_train=max(2048, gb))
-    rng = np.random.default_rng(0)
-    idx = rng.integers(0, len(x_tr), gb)
-    x, y = x_tr[idx], y_tr[idx]
-
-    def leg(mode):
-        tr = DataParallelTrainer(
-            LeNet(compute_dtype=jnp.float32),
-            optax.sgd(0.05, momentum=0.9),
-            topo,
-            quant=mode,
-            bucket_bytes=bucket_bytes,
-        )
-        st = tr.init_state(jax.random.key(0), x[:2])
-        for _ in range(3):  # warmup: compile, EF state — obs unarmed
-            st, m = tr.step(st, x, y)
-        with tempfile.TemporaryDirectory(prefix="mpit_dp_obs_") as d:
-            tr.obs = ObsConfig(dir=d)
-            t0 = time.perf_counter()
-            for _ in range(steps):
-                st, m = tr.step(st, x, y)
-            wall = time.perf_counter() - t0
-            tr.close_obs()
-            run = roofline([d])["run"]
-            dyn = aggregate_dynamics([d])["run"]
-        return {
-            "samples_per_sec": steps * gb / wall,
-            "buckets": len(tr._plan.buckets),
-            "wire_bytes_per_step": tr.wire_bytes_per_step(),
-            "phases": {k: round(v, 4) for k, v in run["phases"].items()},
-            "dynamics": {
-                "elastic_dist_final": (
-                    None if dyn["elastic_dist_final"] is None
-                    else round(dyn["elastic_dist_final"], 4)
-                ),
-                "norm_ratio": (
-                    None if dyn["norm_ratio"] is None
-                    else round(dyn["norm_ratio"], 5)
-                ),
-                "diverging": dyn["diverging"],
-            },
-        }
-
-    raw = leg("off")
-    q = leg(quant)
-    chips = topo.num_devices
-    return {
-        "samples_per_sec": q["samples_per_sec"],
-        "samples_per_sec_per_chip": q["samples_per_sec"] / chips,
-        "chips": chips,
-        "platform": topo.platform,
-        "dp_quant": quant,
-        "dp_bucket_bytes": bucket_bytes,
-        # the staged pipeline dispatches hops as separate programs —
-        # async (true overlap) on hardware, serialized on the CPU mesh
-        "dp_overlap": topo.platform != "cpu",
-        "buckets": q["buckets"],
-        "per_worker_batch": pwb,
-        "timed_steps": steps,
-        "raw_samples_per_sec": round(raw["samples_per_sec"], 1),
-        "vs_raw": round(q["samples_per_sec"] / raw["samples_per_sec"], 3),
-        "wire_bytes_per_step": q["wire_bytes_per_step"],
-        "raw_wire_bytes_per_step": raw["wire_bytes_per_step"],
-        "wire_bytes_ratio": round(
-            raw["wire_bytes_per_step"] / q["wire_bytes_per_step"], 2
-        ),
-        "phases": q["phases"],
-        "raw_phases": raw["phases"],
-        "phase_source": "obs",
-        "dynamics": q["dynamics"],
-    }
-
-
 def bench_preset(
     name: str, num_workers=None, cpu_smoke: bool = False,
     input_dtype: str = "float32", stem: str = None, remat: bool = False,
@@ -1679,40 +1557,6 @@ def main():
             "unit": "MB/sec",
             "vs_baseline": None,  # pickle_*_ms columns ARE the baseline
             **{k: v for k, v in res.items() if k != "framed_mb_per_sec"},
-            **device_tag,
-            **profiled,
-        }))
-        return
-
-    if "--dp" in sys.argv:
-        qmode = flag_arg("--quant") or "int8"
-        bb = flag_arg("--bucket-bytes")
-        try:
-            with trace(profile_dir):
-                res = bench_dp(
-                    cpu_smoke=cpu, quant=qmode,
-                    bucket_bytes=int(bb) if bb else None,
-                )
-        except ValueError as e:
-            print(str(e), file=sys.stderr)
-            return 2
-        print(json.dumps({
-            "metric": "sync_dp_exchange_throughput",
-            "value": round(res["samples_per_sec_per_chip"], 1),
-            "unit": "samples/sec/chip",
-            # the A/B IS the baseline: quantized vs raw staged exchange
-            "vs_baseline": res["vs_raw"],
-            "baseline": "raw f32 staged exchange, same bucket plan/seed",
-            **{
-                k: res[k]
-                for k in ("chips", "platform", "dp_quant",
-                          "dp_bucket_bytes", "dp_overlap", "buckets",
-                          "per_worker_batch", "timed_steps",
-                          "raw_samples_per_sec", "wire_bytes_per_step",
-                          "raw_wire_bytes_per_step", "wire_bytes_ratio",
-                          "phases", "raw_phases", "phase_source",
-                          "dynamics")
-            },
             **device_tag,
             **profiled,
         }))

@@ -32,7 +32,7 @@ of which short-circuit on the module-level ``_ACTIVE`` being None):
   ``MPIT_RT_NUMERICS=1``). The dynamic complement of static MPT020-022:
   the quant kernels' host faces (:mod:`mpit_tpu.quant` peeks for an armed
   checker, never the other way round), the PServer apply path, and the
-  sync/PS error-feedback state report into :func:`note_numeric_array` /
+  PS client's error-feedback state report into :func:`note_numeric_array` /
   ``on_quantize`` / :func:`note_residual_norm`. Checks: NaN/Inf reaching
   a quantize or the server center, int8 absmax overflow (non-finite or
   non-positive scale), the zero-absmax pin (scale 1, codes all zero —

@@ -92,7 +92,7 @@ def allreduce(
     rounding step per hop) but not fed back at this level. Callers that
     reduce the same stream repeatedly (gradient exchange) should hold an
     error-feedback residual and call :func:`quantized_allreduce`
-    directly, as ``parallel/sync.py`` does.
+    directly.
     """
     axis = _axis(axis_name)
     if quant not in (None, "off"):

@@ -31,6 +31,7 @@ per device. Flat buffers reuse ``utils/params.flatten_params``
 
 from __future__ import annotations
 
+import os
 from typing import Callable, Optional
 
 import jax
@@ -44,8 +45,19 @@ from mpit_tpu.comm.collectives import quantized_psum_scatter
 from mpit_tpu.comm.topology import topology as _current_topology
 from mpit_tpu.comm.topology import Topology
 from mpit_tpu.parallel import common
-from mpit_tpu.parallel.sync import dp_quant_from_env
 from mpit_tpu.utils.params import flatten_params
+
+
+def dp_quant_from_env(env=None) -> str:
+    """``MPIT_DP_QUANT`` (off|bf16|int8; default off) — the ZeRO
+    gradient reduce-scatter's quantization mode."""
+    env = os.environ if env is None else env
+    mode = env.get("MPIT_DP_QUANT") or "off"
+    if mode not in _quant.QUANT_MODES:
+        raise ValueError(
+            f"MPIT_DP_QUANT={mode!r}: expected one of {_quant.QUANT_MODES}"
+        )
+    return mode
 
 
 class ZeroDataParallelTrainer:

@@ -14,10 +14,25 @@ residual wrong by exactly the disagreement. The equivalence is pinned in
 Kernels (EQuARX-style, PAPERS.md arXiv:2506.17615):
 
 - ``bf16``: round-to-nearest-even high halves of the float32 bits —
-  pure bit arithmetic, scale-free, 2x byte drop;
+  pure bit arithmetic, scale-free, 2x byte drop. Relative error at most
+  2^-8 for magnitudes up to bfloat16's largest finite value
+  (``BF16_MAX``, 3.3895314e38) and on to half a step past it; from
+  there (``BF16_MAX + 2^119``, 3.3961775e38) a finite float32 is no
+  nearer to that value than to 2^128 and rounds to infinity, as IEEE 754
+  says and as ``astype(jnp.bfloat16)`` does. A NaN stays a NaN;
 - ``int8``: symmetric per-block absmax scaling, codes in [-127, 127],
   ``scale = absmax / 127`` computed in float32 on BOTH paths (a float64
   host division would double-round against XLA's f32), 4x byte drop.
+  The scale is held inside ``[_INT8_SCALE_MIN, _INT8_SCALE_MAX]``, which
+  only the two ends of the float32 range reach: where the quotient
+  rounds up so far that its 127-fold overflows (absmax within a few ulps
+  of float32's largest) it is one ulp less, so a finite input never
+  reconstructs to inf; where it is subnormal, or underflows to zero, it
+  is the smallest normal float32, so no block divides by zero and a
+  backend that flushes subnormals computes the same scale from a normal
+  absmax (a block of nothing but subnormals it reads as all zero: scale
+  1, codes 0). The half-step bound ``|x - deq(q(x))| <= scale / 2``
+  holds at both ends.
 
 This module imports numpy only at module scope; jax is imported lazily
 inside the jnp kernels so the host wire path (and the stdlib-only
@@ -37,6 +52,48 @@ QUANT_MODES = ("off", "bf16", "int8")
 
 # on-wire bytes per quantized element (raw float32 = 4)
 MODE_ITEMSIZE = {"off": 4, "bf16": 2, "int8": 1}
+
+# bfloat16's largest finite value: round-to-nearest-even carries a
+# magnitude half a step (2^119) or more above it to infinity
+BF16_MAX = np.float32(3.3895314e38)
+
+# the largest int8 scale whose 127-fold is finite in float32:
+# FLT_MAX / 127 rounds up and 127 times it overflows; one ulp less does not
+_INT8_SCALE_MAX = np.nextafter(
+    np.finfo(np.float32).max / np.float32(127.0), np.float32(0)
+)
+# the smallest: a subnormal absmax / 127 is subnormal or zero (XLA flushes
+# it to zero on every backend), and a zero scale divides by zero
+_INT8_SCALE_MIN = np.finfo(np.float32).tiny
+
+
+def _bf16_codes(xp, a, u):
+    """bfloat16 codes (still 32 bits wide) of float32 ``a`` with bits
+    ``u``, on either face (``xp`` is numpy or jax.numpy):
+    round-to-nearest-even on the dropped mantissa half; the + carries
+    into the exponent correctly for halfway cases. A NaN keeps its high
+    half with the quiet bit set: the carry would take its payload through
+    the sign bit to a zero, or leave a payload that sits in the low half
+    as an infinity."""
+    return xp.where(
+        xp.isnan(a),
+        (u >> 16) | 0x0040,
+        (u + 0x7FFF + ((u >> 16) & 1)) >> 16,
+    )
+
+
+def _int8_scale(xp, amax):
+    """The int8 scale of a block (or of each row) whose finite absmax is
+    ``amax``, on either face. An f32 division, not float64-then-cast:
+    both faces divide in f32 and must agree to the bit. All-zero block:
+    the scale is moot, pick 1."""
+    return xp.where(
+        amax > 0,
+        xp.clip(
+            amax / xp.float32(127.0), _INT8_SCALE_MIN, _INT8_SCALE_MAX
+        ),
+        xp.float32(1.0),
+    ).astype(xp.float32)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -87,10 +144,7 @@ def quantize(arr: np.ndarray, mode: str) -> QuantArray:
     quantized buffer is new; the input is never aliased)."""
     a = np.ascontiguousarray(arr, dtype=np.float32)
     if mode == "bf16":
-        u = a.view(np.uint32)
-        # round-to-nearest-even on the dropped mantissa half; the +
-        # carries into the exponent correctly for halfway cases
-        data = ((u + 0x7FFF + ((u >> 16) & 1)) >> 16).astype(np.uint16)
+        data = _bf16_codes(np, a, a.view(np.uint32)).astype(np.uint16)
         checker = _rt_numerics_checker()
         if checker is not None:
             checker.on_quantize("quantize", a, mode, None, data)
@@ -105,10 +159,7 @@ def quantize(arr: np.ndarray, mode: str) -> QuantArray:
             if a.size
             else np.float32(0)
         )
-        # f32 division, not float64-then-cast: the jnp path divides in
-        # f32 and the two must agree to the bit (all-zero chunk: scale
-        # is moot, pick 1)
-        scale = amax / np.float32(127.0) if amax > 0 else np.float32(1.0)
+        scale = _int8_scale(np, amax)
         codes = np.clip(np.rint(a / scale), -127, 127)
         # ±Inf saturates to ±127 via the clip; NaN pins to code 0, so a
         # poisoned element dequantizes to 0 instead of garbage
@@ -156,9 +207,7 @@ def quantize_rows(a: np.ndarray, mode: str):
         ).astype(np.float32) if a.size else np.zeros(
             (a.shape[0], 1), np.float32
         )
-        scales = np.where(
-            amax > 0, amax / np.float32(127.0), np.float32(1.0)
-        ).astype(np.float32)
+        scales = _int8_scale(np, amax)
         codes = np.clip(np.rint(a / scales), -127, 127)
         codes = np.where(np.isnan(a), np.float32(0), codes).astype(np.int8)
         checker = _rt_numerics_checker()
@@ -207,8 +256,7 @@ def quantize_jnp(x, mode: str):
     a = jnp.asarray(x, jnp.float32)
     if mode == "bf16":
         u = lax.bitcast_convert_type(a, jnp.uint32)
-        codes = ((u + 0x7FFF + ((u >> 16) & 1)) >> 16).astype(jnp.uint16)
-        return codes, jnp.float32(1.0)
+        return _bf16_codes(jnp, a, u).astype(jnp.uint16), jnp.float32(1.0)
     if mode == "int8":
         # same NaN/Inf guards as the host path (scale from finite
         # elements only; Inf saturates, NaN pins to code 0) — the two
@@ -218,8 +266,7 @@ def quantize_jnp(x, mode: str):
             if a.size
             else jnp.float32(0)
         )
-        scale = jnp.where(amax > 0, amax / jnp.float32(127.0), 1.0)
-        scale = scale.astype(jnp.float32)
+        scale = _int8_scale(jnp, amax)
         codes = jnp.clip(jnp.rint(a / scale), -127, 127)
         codes = jnp.where(jnp.isnan(a), 0.0, codes).astype(jnp.int8)
         return codes, scale
@@ -253,8 +300,7 @@ def quantize_rows_jnp(x, mode: str):
             axis=1,
             keepdims=True,
         )
-        scale = jnp.where(amax > 0, amax / jnp.float32(127.0), 1.0)
-        scale = scale.astype(jnp.float32)
+        scale = _int8_scale(jnp, amax)
         codes = jnp.clip(jnp.rint(a / scale), -127, 127)
         codes = jnp.where(jnp.isnan(a), 0.0, codes).astype(jnp.int8)
         return codes, scale

@@ -140,11 +140,10 @@ def remember_unit(program, *args) -> None:
 
 def unit_program_text() -> Optional[str]:
     """The compiled text of the unit program the newest fit loop ran, or
-    None where none ran or the unit is no single jitted program (the
-    bucketed sync step). Its ``metadata={op_name="..."}`` is where an
-    instruction's ``jax.named_scope`` path is found: the v5e's profiler
-    trace names a device event by its instruction and carries no
-    ``op_name`` (PERF.md).
+    None where none ran or the unit is a plain function, no jitted
+    program. Its ``metadata={op_name="..."}`` is where an instruction's
+    ``jax.named_scope`` path is found: the v5e's profiler trace names a
+    device event by its instruction and carries no ``op_name`` (PERF.md).
 
     Compiled afresh, past two caches. The persistent compile cache's key
     leaves metadata out, so an executable cached by another revision of the

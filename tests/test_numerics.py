@@ -403,18 +403,33 @@ def test_rt104_off_means_zero_hooks():
 # --------------------------------------------- quantization error bound
 #
 # The property the whole EF story leans on (docs/WIRE.md): for every
-# finite element, |dequantize(quantize(x)) - x| <= scale/2 for int8 and
-# relative error <= 2^-8 for bf16 — INCLUDING arrays poisoned with
-# NaN/Inf/-0.0, empty chunks, and all-zero blocks, where the hardened
-# kernels must stay finite rather than accurate. Runs under hypothesis
-# when available; otherwise a seeded-stdlib sweep covers the same space
-# so the property still executes in tier-1.
+# finite element, |dequantize(quantize(x)) - x| <= scale/2 for int8 (a
+# finite input never reconstructs to inf, FLT_MAX included) and relative
+# error <= 2^-8 for bf16 up to half a step past bfloat16's largest finite
+# value (from there round-to-nearest-even gives infinity, as IEEE 754
+# says) — INCLUDING arrays poisoned with NaN/Inf/-0.0, empty chunks, and
+# all-zero blocks, where the hardened kernels must stay finite rather
+# than accurate. Runs under hypothesis when available; otherwise a
+# seeded-stdlib sweep covers the same space so the property still
+# executes in tier-1.
 
 _EDGE_VALUES = np.array(
     [0.0, -0.0, np.nan, np.inf, -np.inf, 1.0, -1.0,
-     2.0 ** -120, 6.5e4, 3.0e38, -3.0e38],
+     2.0 ** -120, 6.5e4, 3.0e38, -3.0e38,
+     # what hypothesis found: 127 * fl(FLT_MAX / 127) overflowed; the
+     # second lies half a step above bfloat16's largest finite value,
+     # the third just under that; a subnormal's scale underflowed to zero
+     3.4028234663852886e38, 3.39617752923046e38, 3.3895315920756315e38,
+     quant.BF16_MAX, -quant.BF16_MAX, 1.401298464324817e-45],
     np.float32,
 )
+# and two NaNs by their bits: bf16's rounding carried the first's payload
+# through the sign bit to -0.0 and left the second, whose payload is all
+# in the low half, as an infinity
+_EDGE_VALUES = np.concatenate([
+    _EDGE_VALUES,
+    np.array([0x7FFFF8EC, 0x7F800001], np.uint32).view(np.float32),
+])
 
 
 def _assert_roundtrip_bound(a):
@@ -432,9 +447,13 @@ def _assert_roundtrip_bound(a):
         assert err.max() <= q.scale * 0.51, (a, q.scale, err.max())
     assert (out[np.isnan(a)] == 0).all()
     # bf16: lanes pass through the f32<->bf16 pair with <= 2^-8 relative
-    # error on normal finite values; NaN stays NaN (representable)
+    # error on normal finite values up to half a step past bfloat16's
+    # largest; from there the nearest bfloat16 is infinity (the tie goes
+    # to the even code, infinity's); NaN stays NaN (representable)
     out = quant.dequantize(quant.quantize(a, "bf16"))
-    normal = finite & (np.abs(a) >= 2.0 ** -100)
+    over = finite & (np.abs(a) >= quant.BF16_MAX + np.float32(2.0 ** 119))
+    assert (out[over] == np.copysign(np.inf, a[over])).all()
+    normal = finite & ~over & (np.abs(a) >= 2.0 ** -100)
     nz = normal & (a != 0)
     if nz.any():
         rel = np.abs(out[nz] - a[nz]) / np.abs(a[nz])

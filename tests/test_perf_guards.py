@@ -446,12 +446,9 @@ def test_pipeline_step_compiles_clean_and_donates():
 
 
 def test_sync_serial_fallback_bit_identical(topo8):
-    """With both exchange knobs off (no MPIT_DP_QUANT, no
-    MPIT_DP_BUCKET_BYTES) the trainer must run the pre-bucketing fused
-    program EXACTLY: params equal to the BIT after several fixed-seed
-    steps against a verbatim reimplementation of the original step.
-    Guards the ISSUE-11 contract that the serial fallback is not
-    "close", it is the same program."""
+    """The trainer's one step program is the fused step EXACTLY: params
+    equal to the BIT after several fixed-seed steps against a verbatim
+    reimplementation of it (value_and_grad, one pmean, the optimizer)."""
     from jax.sharding import PartitionSpec as P
 
     from mpit_tpu.models import MLP
@@ -461,14 +458,13 @@ def test_sync_serial_fallback_bit_identical(topo8):
     model = MLP(compute_dtype=jnp.float32)
     opt = optax.sgd(0.05, momentum=0.9)
     tr = DataParallelTrainer(model, opt, topo8, donate_state=False)
-    assert not tr.bucketed
     x, y = _trainer_data()
     state = tr.init_state(jax.random.key(0), x[:2])
 
     axis = topo8.worker_axis
     loss_fn = pcommon.default_loss_fn(model.apply)
 
-    # the pre-bucketing step, verbatim
+    # the step, verbatim
     def train_step(state, xb, yb):
         loss, grads = jax.value_and_grad(loss_fn)(state.params, xb, yb)
         grads = jax.lax.pmean(grads, axis)

@@ -323,6 +323,54 @@ class TestQuantHardening:
             np.asarray(scales, np.float32), h_scales.astype(np.float32)
         )
 
+    def test_top_of_the_float32_range_on_both_faces(self):
+        """int8: a finite input never reconstructs to inf (FLT_MAX / 127
+        rounds up, and 127 times that overflows: the scale is held one
+        ulp under it), the same scale to the bit on every face. bf16:
+        from half a step above bfloat16's largest finite value on,
+        round-to-nearest-even gives infinity, as ``astype(jnp.bfloat16)``
+        does; under that, that value."""
+        import jax.numpy as jnp
+
+        from mpit_tpu import quant as qk
+
+        top = np.finfo(np.float32).max
+        a = np.array(
+            [[top, -1.0, 0.0, 3.0e38],
+             [3.39617752923046e38, -top, 1.0e38, np.inf],
+             [qk.BF16_MAX, -qk.BF16_MAX, 3.3895315920756315e38, np.nan]],
+            np.float32,
+        )
+        h_codes, h_scales = qk.quantize_rows(a, "int8")
+        d_codes, d_scales = qk.quantize_rows_jnp(a, "int8")
+        np.testing.assert_array_equal(np.asarray(d_codes), h_codes)
+        assert np.asarray(d_scales, np.float32).tobytes() == (
+            h_scales.tobytes())
+        for j, row in enumerate(a):
+            host = quantize(row, "int8")
+            codes, scale = qk.quantize_jnp(row, "int8")
+            assert np.float32(host.scale).tobytes() == (
+                np.asarray(scale, np.float32).tobytes()
+                ) == h_scales[j].tobytes()
+            for out in (dequantize(host),
+                        np.asarray(qk.dequantize_jnp(codes, scale, "int8")),
+                        qk.dequantize_rows(h_codes, h_scales, "int8")[j]):
+                assert np.isfinite(out).all(), (row, out)
+                assert abs(out[0] - row[0]) <= 0.51 * host.scale
+        assert np.isfinite(np.float32(127) * qk._INT8_SCALE_MAX)
+
+        want = np.asarray(
+            jnp.asarray(a).astype(jnp.bfloat16).astype(jnp.float32))
+        host = dequantize(quantize(a, "bf16"))
+        np.testing.assert_array_equal(host, want)
+        np.testing.assert_array_equal(
+            np.asarray(qk.dequantize_jnp(
+                qk.quantize_jnp(a, "bf16")[0], None, "bf16")), want)
+        assert np.isposinf(host[0, 0]) and np.isposinf(host[1, 0])
+        assert np.isneginf(host[1, 1]) and np.isfinite(host[0, 3])
+        assert host[2, 0] == qk.BF16_MAX and host[2, 1] == -qk.BF16_MAX
+        assert host[2, 2] == qk.BF16_MAX
+
     def test_bf16_preserves_nan_and_rt104_reports_it(self):
         # bf16 REPRESENTS NaN, so the kernel passes it through bit-true
         # (no silent zeroing that would hide the bug) — detection is the
@@ -332,6 +380,18 @@ class TestQuantHardening:
         a = np.array([1.5, np.nan, -2.25], np.float32)
         out = dequantize(quantize(a, "bf16"))
         assert np.isnan(out[1])
+        # whatever its payload: the rounding's carry must not take a NaN
+        # through the sign bit to a zero, nor drop a low-half payload
+        # and leave an infinity, on either face
+        from mpit_tpu import quant as qk
+
+        nans = np.array(
+            [0x7FFFF8EC, 0xFFFFFFFF, 0x7F800001, 0xFF800001], np.uint32
+        ).view(np.float32)
+        host = quantize(nans, "bf16").data
+        assert np.isnan(dequantize(quantize(nans, "bf16"))).all()
+        np.testing.assert_array_equal(
+            np.asarray(qk.quantize_jnp(nans, "bf16")[0]), host)
         assert out[0] == pytest.approx(1.5) and out[2] == pytest.approx(-2.25)
         with rt.checking(numerics=True) as ck:
             quantize(a, "bf16")

@@ -141,6 +141,20 @@ class TestZero:
                 model, optax.sgd(0.1), topo8, quant="fp4"
             )
 
+    def test_quant_mode_from_the_environment(self, topo8, monkeypatch):
+        """``MPIT_DP_QUANT`` has one reader and this trainer is its one
+        user: default off, a known mode accepted, an unknown one named."""
+        from mpit_tpu.parallel.zero import dp_quant_from_env
+
+        assert dp_quant_from_env({}) == "off"
+        assert dp_quant_from_env({"MPIT_DP_QUANT": "int8"}) == "int8"
+        with pytest.raises(ValueError, match="MPIT_DP_QUANT"):
+            dp_quant_from_env({"MPIT_DP_QUANT": "fp4"})
+        monkeypatch.setenv("MPIT_DP_QUANT", "bf16")
+        model = LeNet(compute_dtype=jnp.float32)
+        assert ZeroDataParallelTrainer(
+            model, optax.sgd(0.1), topo8).quant == "bf16"
+
     def test_cross_leaf_optimizer_rejected(self, topo8):
         """Global-norm clipping over a CHUNK would differ per device —
         the behavioral probe refuses it up front."""
