@@ -453,6 +453,7 @@ def aggregate_counters(collection: dict) -> dict:
     """One value a step from the routing counters the expert layers sowed
     (``model.apply(..., mutable=["counters"])``): ``moe_rows_held`` the
     mean over layers of the pairs routed to the experts held,
+    ``moe_rows_walked`` the mean of the buffer rows computed for them,
     ``moe_load_max_over_mean`` the worst layer's fullest expert over its
     mean, ``moe_rows_dropped`` the sum of the rows past the bound,
     ``moe_balance`` the mean of the load-balancing terms (top-k = uniform)."""
@@ -461,6 +462,7 @@ def aggregate_counters(collection: dict) -> dict:
         return {}
     return {
         "moe_rows_held": jnp.mean(jnp.stack(by_name["rows_held"])),
+        "moe_rows_walked": jnp.mean(jnp.stack(by_name["rows_walked"])),
         "moe_load_max_over_mean": jnp.max(
             jnp.stack(by_name["load_max_over_mean"])),
         "moe_rows_dropped": jnp.sum(jnp.stack(by_name["rows_dropped"])),

@@ -33,6 +33,9 @@ per-token dense reference in tests/test_moe.py.
 
 from __future__ import annotations
 
+import functools
+import math
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -259,6 +262,125 @@ def swiglu(x, w_gate, w_up, w_down):
     return mm(jax.nn.silu(mm(x, w_gate)) * mm(x, w_up), w_down)
 
 
+def chunk_rows(tokens: int, top_k: int, held: int, routed: int) -> int:
+    """The chunk in which ``moe_ffn_held`` walks its buffer: twice the rows
+    uniform routing sends to ``held`` of ``routed`` experts, those up to a
+    multiple of 256. A chunk costs 3 to 4 ms a layer whatever its rows
+    (its scatter-adds and weight-gradient sums) and 0.55 ms a thousand rows
+    on the v5e (``scripts/moe_held_sweep.py``, PERF.md section 6, PR 31),
+    so the loads a step meets should fit one chunk."""
+    return 2 * math.ceil(tokens * top_k * held / routed / 256) * 256
+
+
+def _expert_rows(rows, w_gate, w_up, w_down, row_weight, ends, start):
+    """The SwiGLU experts over ``rows``, which are rows ``start ..
+    start + len(rows)`` of the buffer sorted by expert (``ends``: where
+    each expert's rows end in the whole buffer), times ``row_weight``, in
+    f32. The weights come in ``rows``' dtype."""
+    n, dt = rows.shape[0], rows.dtype
+    with jax.named_scope("moe_dispatch"):
+        span = jnp.clip(ends - start, 0, n)
+        sizes = jnp.diff(span, prepend=0).astype(jnp.int32)
+        valid = jnp.arange(n) < span[-1]
+    with jax.named_scope("moe_experts"):
+        # a grouped product leaves the rows past its groups as they were
+        # in memory (seen on the v5e, PR 27: the gradient into such rows
+        # came back as garbage 1e5 times the true one), so every operand
+        # and result is cut to the valid rows, forward and backward
+        live = lambda a: jnp.where(valid[:, None], a, 0)
+        grouped = lambda a, w: live(lax.ragged_dot(
+            live(a), w, sizes, preferred_element_type=jnp.float32
+        ).astype(dt))
+        hidden = jax.nn.silu(grouped(rows, w_gate)) * grouped(rows, w_up)
+        out_rows = grouped(hidden, w_down)
+    with jax.named_scope("moe_dispatch"):
+        return out_rows.astype(jnp.float32) * row_weight[:, None]
+
+
+def _add_rows(out, y, weights, token, row_weight, ends, start):
+    """``out`` with the experts' weighted outputs for the buffer's rows
+    ``start .. start + len(token)`` added at their tokens."""
+    with jax.named_scope("moe_dispatch"):
+        rows = jnp.take(y, token, axis=0)
+    add = _expert_rows(rows, *weights, row_weight, ends, start)
+    with jax.named_scope("moe_dispatch"):
+        return out.at[token].add(add)
+
+
+def _chunk_of(chunk, j, token, row_weight):
+    """Chunk ``j``'s first row, token ids and weights."""
+    start = j * chunk
+    cut = lambda a: lax.dynamic_slice_in_dim(a, start, chunk)
+    return start, cut(token), cut(row_weight)
+
+
+def _live_chunks(chunk, ends):
+    """Chunks up to the buffer's last live row, ``ends[-1]``."""
+    return -(-ends[-1] // chunk)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _walk(chunk, y, weights, row_weight, token, ends):
+    """``zeros.at[token].add(_expert_rows(y[token], ...))`` in ``y``'s
+    dtype, the buffer walked ``chunk`` rows at a time and only as far as
+    its last live row, ``ends[-1]``: one loop with a traced trip count
+    forward and one backward, each holding the layer's body once. The
+    backward recomputes a chunk's products from ``y`` (as the block's
+    remat would) and keeps the cotangents as carries updated in place;
+    the weights' carries have the weights' dtype, as the single pass's
+    cotangents have, and a chunk's row sums are f32 inside its product."""
+    return _walk_fwd(chunk, y, weights, row_weight, token, ends)[0]
+
+
+def _walk_fwd(chunk, y, weights, row_weight, token, ends):
+    def body(j, out):
+        start, tok, weight = _chunk_of(chunk, j, token, row_weight)
+        return _add_rows(out, y, weights, tok, weight, ends, start)
+
+    with jax.named_scope("moe_dispatch"):
+        out = lax.fori_loop(0, _live_chunks(chunk, ends), body,
+                            jnp.zeros(y.shape, jnp.float32)).astype(y.dtype)
+    return out, (y, weights, row_weight, token, ends)
+
+
+def _walk_bwd(chunk, residuals, ct):
+    y, weights, row_weight, token, ends = residuals
+
+    def body(j, carry):
+        d_y, d_weights, d_row_weight = carry
+        start, tok, weight = _chunk_of(chunk, j, token, row_weight)
+        with jax.named_scope("moe_dispatch"):
+            rows = jnp.take(y, tok, axis=0)
+            ct_rows = jnp.take(ct, tok, axis=0).astype(jnp.float32)
+        _, vjp = jax.vjp(
+            lambda rows, weights, weight: _expert_rows(
+                rows, *weights, weight, ends, start),
+            rows, weights, weight)
+        d_rows, d_chunk, d_weight = vjp(ct_rows)
+        with jax.named_scope("moe_dispatch"):
+            d_y = d_y.at[tok].add(d_rows)
+            d_row_weight = lax.dynamic_update_slice_in_dim(
+                d_row_weight, d_weight, start, 0)
+        with jax.named_scope("moe_experts"):
+            d_weights = [a + d for a, d in zip(d_weights, d_chunk)]
+        return d_y, d_weights, d_row_weight
+
+    with jax.named_scope("moe_dispatch"):
+        d_y, d_weights, d_row_weight = lax.fori_loop(
+            0, _live_chunks(chunk, ends), body,
+            (jnp.zeros_like(y), [jnp.zeros_like(w) for w in weights],
+             jnp.zeros_like(row_weight)))
+    # left as a loop's outputs, the weights' gradients are read by the
+    # optimizer at the program's end and live until then: in the Laguna cell
+    # 0.55 GB more at the peak and 8 ms a step (PERF.md section 6, PR 31);
+    # pinned here, XLA schedules their readers next to the loop
+    d_weights = lax.optimization_barrier(d_weights)
+    return d_y, d_weights, d_row_weight, None, None
+
+
+_walk.defvjp(_walk_fwd, _walk_bwd)
+
+
 def moe_ffn_held(
     params: dict,
     y: jax.Array,
@@ -286,18 +408,27 @@ def moe_ffn_held(
     their weights. ``row_bound`` is static; pairs past it are dropped
     and COUNTED (``rows_dropped``; a correct run reads 0).
 
+    The bound is for the imbalance a step may meet, not for the step at
+    hand, so a buffer of more than one chunk (``chunk_rows``, twice the
+    rows uniform routing sends here) is walked chunk by chunk as far as the
+    step's own count reaches (``_walk``) and the chunks past it cost
+    nothing; ``rows_walked`` says how far that was.
+
     ``routing_grad=False`` makes the routing weights constants of the
     backward pass (``models/arch.py``, ``moe_routing_no_grad``, says when).
 
     ``y``: ``(tokens, D)``. Returns ``(out, counters, (weights,
     experts))``; the counters are f32 scalars: ``rows_held`` (pairs
-    routed to the experts held), ``load_max_over_mean`` (the fullest
-    expert's rows over the mean), ``rows_dropped`` and ``balance`` (the
-    load-balancing term, the one counter a gradient passes through).
+    routed to the experts held), ``rows_walked`` (buffer rows computed),
+    ``load_max_over_mean`` (the fullest expert's rows over the mean),
+    ``rows_dropped`` and ``balance`` (the load-balancing term, the one
+    counter a gradient passes through).
     """
     tokens, d = y.shape
     held = params["w_gate"].shape[0]
+    routed = params["router"].shape[1]
     row_bound = min(row_bound, tokens * top_k)  # there are no more pairs
+    chunk = chunk_rows(tokens, top_k, held, routed)
     with jax.named_scope("moe_router"):
         weights, experts, scores = route_top_k(
             y, params["router"], top_k, scale)
@@ -306,7 +437,6 @@ def moe_ffn_held(
         # the load-balancing term E · sum_e f_e P_e over ALL experts scored,
         # f_e the share of tokens that chose e (top_k under uniform
         # routing); its gradient passes through P alone
-        routed = scores.shape[1]
         share = jnp.bincount(experts.reshape(-1), length=routed) / tokens
         balance = routed * jnp.sum(share * scores.mean(0))
     with jax.named_scope("moe_dispatch"):
@@ -317,32 +447,29 @@ def moe_ffn_held(
         counts = jnp.bincount(key, length=held + 1)[:held]
         # rows of each expert that fit under the bound, in sorted order
         ends = jnp.minimum(jnp.cumsum(counts), row_bound)
-        sizes = jnp.diff(ends, prepend=0).astype(jnp.int32)
-        valid = jnp.arange(row_bound) < ends[-1]
         token = order // top_k
-        rows = jnp.take(y, token, axis=0)
         row_weight = jnp.where(
-            valid, jnp.take(weights.reshape(-1), order), 0.0)
-    with jax.named_scope("moe_experts"):
-        dt = y.dtype
-        # a grouped product leaves the rows past its groups as they were
-        # in memory (seen on the v5e, PR 27: the gradient into such rows
-        # came back as garbage 1e5 times the true one), so every operand
-        # and result is cut to the valid rows, forward and backward
-        live = lambda a: jnp.where(valid[:, None], a, 0)
-        grouped = lambda a, w: live(lax.ragged_dot(
-            live(a), w.astype(dt), sizes, preferred_element_type=jnp.float32
-        ).astype(dt))
-        hidden = jax.nn.silu(grouped(rows, params["w_gate"])) * grouped(
-            rows, params["w_up"])
-        out_rows = grouped(hidden, params["w_down"])
+            jnp.arange(row_bound) < ends[-1],
+            jnp.take(weights.reshape(-1), order), 0.0)
+    with jax.named_scope("moe_experts"):  # cast once, outside any loop
+        experts_w = [params[n].astype(y.dtype)
+                     for n in ("w_gate", "w_up", "w_down")]
+    if row_bound <= chunk:  # one chunk: no loop
+        out = _add_rows(jnp.zeros((tokens, d), jnp.float32), y, experts_w,
+                        token, row_weight, ends, 0).astype(y.dtype)
+        rows_walked = jnp.float32(row_bound)
+    else:
+        with jax.named_scope("moe_dispatch"):
+            # whole chunks; what lies past the bound stays past ``ends``
+            pad = (0, -row_bound % chunk)
+            token, row_weight = jnp.pad(token, pad), jnp.pad(row_weight, pad)
+        out = _walk(chunk, y, experts_w, row_weight, token, ends)
+        rows_walked = (_live_chunks(chunk, ends) * chunk).astype(jnp.float32)
     with jax.named_scope("moe_dispatch"):
-        out = jnp.zeros((tokens, d), jnp.float32).at[token].add(
-            out_rows.astype(jnp.float32) * row_weight[:, None]
-        ).astype(dt)
         total = counts.sum().astype(jnp.float32)
         counters = {
             "rows_held": total,
+            "rows_walked": rows_walked,
             "load_max_over_mean": counts.max() * held / jnp.maximum(total, 1.0),
             "rows_dropped": jnp.maximum(total - row_bound, 0.0),
             "balance": balance,

@@ -81,10 +81,15 @@ def _system_loss(model, params, tokens, targets):
     return -jnp.take_along_axis(logp, targets[..., None], -1).mean()
 
 
-@pytest.mark.parametrize("impl,remat", [("xla", False), ("flash_force", True)])
+@pytest.mark.parametrize("impl,remat,chunk", [
+    ("xla", False, None), ("flash_force", True, None),
+    ("xla", True, 32),  # the expert layers walk their 96 rows in chunks
+])
 def test_system_matches_reference_on_loss_logits_and_every_gradient_leaf(
-    problem, reference, impl, remat
+    problem, reference, impl, remat, chunk, monkeypatch
 ):
+    if chunk:
+        monkeypatch.setattr(moe, "chunk_rows", lambda *a: chunk)
     params, tokens, targets = problem
     model = _model(impl, remat)
     logits = jax.jit(lambda p: model.apply({"params": p}, tokens))(params)
@@ -219,17 +224,24 @@ def test_the_shares_add_up_to_the_uncut_layer():
     np.testing.assert_allclose(plain, whole, rtol=1e-4, atol=1e-5)
 
 
-def test_forced_imbalance_drops_nothing_and_a_short_buffer_is_counted():
-    """Every token sent to the same three experts, all held here: 192 rows
-    against the 48 uniform routing would send; nothing is dropped while the
-    buffer holds them, and a buffer that does not counts what it lost."""
-    p, y = _expert_layer(jax.random.key(5))
+@pytest.mark.parametrize("tokens,experts,walked", [
+    (64, 16, 192),  # the buffer is one chunk: a single pass
+    (1024, 32, 3072),  # three chunks of 1,024, every one full
+])
+def test_forced_imbalance_drops_nothing_and_a_short_buffer_is_counted(
+        tokens, experts, walked):
+    """Every token sent to the same three experts, all held here: three
+    rows a token against the 0.75 (or 0.375) uniform routing would send;
+    nothing is dropped while the buffer holds them, and a buffer that does
+    not counts what it lost."""
+    p, y = _expert_layer(jax.random.key(5), tokens=tokens, experts=experts)
     p["moe_router"] = p["moe_router"].at[:, 4:7].add(
         100.0 * jnp.sign(y[0].mean(0))[:, None] / y.shape[-1])
     y = jnp.abs(y) * jnp.sign(y[0].mean(0))
-    out, counters, (_, experts) = _held_part(p, y, 4, 4, 64 * 3)
-    assert set(np.unique(experts)) == {4, 5, 6}
-    assert float(counters["rows_held"]) == 192
+    out, counters, (_, chosen) = _held_part(p, y, 4, 4, tokens * 3)
+    assert set(np.unique(chosen)) == {4, 5, 6}
+    assert float(counters["rows_held"]) == tokens * 3
+    assert float(counters["rows_walked"]) == walked
     assert float(counters["rows_dropped"]) == 0
     assert float(counters["load_max_over_mean"]) == pytest.approx(4 / 3)
     share = {n: (v[4:8] if n.startswith("moe_w") else v) for n, v in p.items()}
@@ -237,8 +249,9 @@ def test_forced_imbalance_drops_nothing_and_a_short_buffer_is_counted():
         - ref.swiglu(y, p["shared_w_gate"], p["shared_w_up"],
                      p["shared_w_down"])
     np.testing.assert_allclose(out[None], want, rtol=1e-4, atol=1e-5)
-    _, short, _ = _held_part(p, y, 4, 4, 128)
-    assert float(short["rows_dropped"]) == 64
+    _, short, _ = _held_part(p, y, 4, 4, tokens * 2)
+    assert float(short["rows_dropped"]) == tokens
+    assert float(short["rows_walked"]) == tokens * 2
 
 
 def test_counters_and_routing_are_sown(problem):
@@ -246,11 +259,13 @@ def test_counters_and_routing_are_sown(problem):
     _, sown = jax.jit(lambda p: _model(remat=True).apply(
         {"params": p}, tokens, mutable=["counters", "routing"]))(params)
     counters = aggregate_counters(sown["counters"])
-    assert set(counters) == {"moe_rows_held", "moe_load_max_over_mean",
-                             "moe_rows_dropped", "moe_balance"}
+    assert set(counters) == {"moe_rows_held", "moe_rows_walked",
+                             "moe_load_max_over_mean", "moe_rows_dropped",
+                             "moe_balance"}
     assert 3 <= float(counters["moe_balance"]) < 6  # top-k 3 = uniform
     assert float(counters["moe_rows_dropped"]) == 0
     assert 0 < float(counters["moe_rows_held"]) <= 96
+    assert float(counters["moe_rows_walked"]) == 96  # one chunk: the bound
     assert sorted(sown["routing"]) == [f"Block_{l}" for l in range(1, 5)]
 
 
