@@ -37,6 +37,23 @@ weights the mean over sparse layers of the load-balancing term ``E · sum_e
 f_e P_e`` (``f_e`` the share of tokens that chose ``e``, ``P_e`` its mean
 score: transformers' ``load_balancing_loss_func`` a layer) that
 ``TransformerLM.loss_with_counters`` adds to the loss.
+
+An ``arch`` with ``hybrid_override_pattern`` is of the ``nemotron_h`` family
+and is read by that family's keys: every layer is ONE mixer, named by its
+letter in the pattern (``M`` Mamba-2, ``E`` experts, ``*`` attention; ``-``,
+a dense MLP, is not built), the first ``num_hidden_layers`` letters. Keys:
+``layer_norm_epsilon``; ``mamba_num_heads``, ``mamba_head_dim``,
+``n_groups``, ``ssm_state_size``, ``conv_kernel``, ``chunk_size``,
+``time_step_min`` / ``_max`` / ``_floor`` (the Mamba-2 mixer,
+``ops/ssd.py``); ``num_attention_heads``, ``num_key_value_heads``,
+``head_dim`` (attention without rotary or any other position signal, as in
+the family's published modelling code: ``rope_theta`` is read by nothing);
+``n_routed_experts`` (the experts HELD, as ``num_experts`` above, with
+``num_routed_experts`` / ``expert_offset`` beside it),
+``num_experts_per_tok``, ``moe_intermediate_size``,
+``moe_shared_expert_intermediate_size``, ``routed_scaling_factor``,
+``mlp_hidden_act`` (``relu2``: ungated experts ``W_down relu(W_up x)^2``),
+sigmoid scores with the choice made on ``score + e_score_correction_bias``.
 """
 
 from __future__ import annotations
@@ -69,6 +86,11 @@ class MoESpec:
     scale: float
     row_bound: int  # 0 = from the token count (four times the expectation)
     routing_grad: bool = True  # False: routing weights are constants backward
+    # "softmax", or "sigmoid": the choice made on score + a bias leaf, the
+    # weights taken from the scores without it
+    scoring: str = "softmax"
+    # the expert's function, a key of ops/moe.EXPERTS: "swiglu" | "relu2"
+    expert: str = "swiglu"
 
     def rows(self, tokens: int) -> int:
         """The dispatch buffer's rows for ``tokens`` tokens: the given
@@ -82,17 +104,47 @@ class MoESpec:
 
 
 @dataclasses.dataclass(frozen=True)
+class SSMSpec:
+    """A Mamba-2 mixer: ``heads`` of ``head_dim`` channels (``d_inner`` their
+    product, whatever ``expand`` says), ``groups`` of B and C shared by
+    ``heads / groups`` heads each, a state of ``state`` a channel."""
+    heads: int
+    head_dim: int
+    groups: int
+    state: int
+    conv_kernel: int
+    chunk: int
+    dt_min: float
+    dt_max: float
+    dt_floor: float
+
+    @property
+    def d_inner(self) -> int:
+        return self.heads * self.head_dim
+
+    @property
+    def conv_dim(self) -> int:  # x, B and C pass the convolution
+        return self.d_inner + 2 * self.groups * self.state
+
+
+@dataclasses.dataclass(frozen=True)
 class LayerSpec:
+    """One layer: the residual passes ``x + Mixer(RMSNorm(x))`` once a name
+    in ``mixers``, in order. ``attention`` reads the head counts, ``window``,
+    ``rope`` and ``gate``; ``ffn`` reads ``d_ff`` or ``moe``; ``ssm`` reads
+    ``ssm``."""
     d_model: int
     num_heads: int
     num_kv_heads: int
     head_dim: int
     window: Optional[int]  # None = full causal attention
-    rope: RopeSpec
+    rope: Optional[RopeSpec]  # None = no rotary positions
     gate: bool  # per-head sigmoid gate on the attention output
     norm_eps: float
     d_ff: int  # dense SwiGLU width (0 where the layer is sparse)
     moe: Optional[MoESpec]
+    mixers: tuple = ("attention", "ffn")
+    ssm: Optional[SSMSpec] = None
 
 
 def _rope_spec(params: dict, head_dim: int) -> RopeSpec:
@@ -113,7 +165,92 @@ def _rope_spec(params: dict, head_dim: int) -> RopeSpec:
     )
 
 
+def _check_share(moe: MoESpec) -> None:
+    if not 0 <= moe.offset <= moe.routed - moe.held:
+        raise ValueError(
+            f"arch: experts {moe.offset}..{moe.offset + moe.held} held "
+            f"of {moe.routed} routed"
+        )
+
+
+def _hybrid_specs(arch: dict) -> tuple[LayerSpec, ...]:
+    """The ``nemotron_h`` family: one mixer a layer, by its letter."""
+    n = int(arch["num_hidden_layers"])
+    pattern = str(arch["hybrid_override_pattern"])[:n]
+    if len(pattern) != n:
+        raise ValueError(
+            f"arch: {n} layers but hybrid_override_pattern has {len(pattern)}")
+    for key in ("mamba_proj_bias", "use_bias", "attention_bias", "mlp_bias"):
+        if arch.get(key):
+            raise ValueError(f"arch: {key} true is not built")
+    if not arch.get("use_conv_bias", True):
+        raise ValueError("arch: use_conv_bias false is not built")
+    if int(arch.get("n_group", 1)) != 1 or int(arch.get("topk_group", 1)) != 1:
+        raise ValueError("arch: group-limited routing (n_group > 1) is not "
+                         "built")
+    if not arch.get("norm_topk_prob", True):
+        raise ValueError("arch: norm_topk_prob false is not built")
+    if arch.get("mlp_hidden_act", "relu2") != "relu2":
+        raise ValueError(
+            f"arch: mlp_hidden_act {arch['mlp_hidden_act']!r}: have relu2")
+    if arch.get("mamba_hidden_act", "silu") != "silu":
+        raise ValueError(
+            f"arch: mamba_hidden_act {arch['mamba_hidden_act']!r}: have silu")
+    if int(arch.get("n_shared_experts", 1)) != 1:
+        raise ValueError("arch: n_shared_experts other than 1 is not built")
+    common = dict(
+        d_model=int(arch["hidden_size"]), window=None, rope=None, gate=False,
+        norm_eps=float(arch.get("layer_norm_epsilon", 1e-5)), d_ff=0)
+    kinds = {}
+    if "M" in pattern:
+        ssm = SSMSpec(
+            heads=int(arch["mamba_num_heads"]),
+            head_dim=int(arch["mamba_head_dim"]),
+            groups=int(arch["n_groups"]), state=int(arch["ssm_state_size"]),
+            conv_kernel=int(arch["conv_kernel"]),
+            chunk=int(arch["chunk_size"]),
+            dt_min=float(arch.get("time_step_min", 0.001)),
+            dt_max=float(arch.get("time_step_max", 0.1)),
+            dt_floor=float(arch.get("time_step_floor", 1e-4)),
+        )
+        if ssm.heads % ssm.groups:
+            raise ValueError(
+                f"arch: {ssm.heads} Mamba-2 heads in {ssm.groups} groups")
+        kinds["M"] = LayerSpec(**common, num_heads=0, num_kv_heads=0,
+                               head_dim=0, moe=None, mixers=("ssm",), ssm=ssm)
+    if "*" in pattern:
+        kinds["*"] = LayerSpec(
+            **common, num_heads=int(arch["num_attention_heads"]),
+            num_kv_heads=int(arch["num_key_value_heads"]),
+            head_dim=int(arch["head_dim"]), moe=None, mixers=("attention",))
+    if "E" in pattern:
+        held = int(arch["n_routed_experts"])
+        moe = MoESpec(
+            routed=int(arch.get("num_routed_experts", held)), held=held,
+            offset=int(arch.get("expert_offset", 0)),
+            top_k=int(arch["num_experts_per_tok"]),
+            width=int(arch["moe_intermediate_size"]),
+            shared_width=int(arch.get(
+                "moe_shared_expert_intermediate_size", 0)),
+            scale=float(arch.get("routed_scaling_factor", 1.0)),
+            row_bound=int(arch.get("moe_row_bound", 0)),
+            routing_grad=not arch.get("moe_routing_no_grad", False),
+            scoring="sigmoid", expert="relu2",
+        )
+        _check_share(moe)
+        kinds["E"] = LayerSpec(**common, num_heads=0, num_kv_heads=0,
+                               head_dim=0, moe=moe, mixers=("ffn",))
+    for letter in pattern:
+        if letter not in kinds:
+            raise ValueError(
+                f"arch: layer kind {letter!r} in hybrid_override_pattern: "
+                "have M (Mamba-2), E (experts), * (attention)")
+    return tuple(kinds[letter] for letter in pattern)
+
+
 def layer_specs(arch: dict) -> tuple[LayerSpec, ...]:
+    if "hybrid_override_pattern" in arch:
+        return _hybrid_specs(arch)
     n = int(arch["num_hidden_layers"])
     d, head_dim = int(arch["hidden_size"]), int(arch["head_dim"])
     kinds = list(arch.get("layer_types") or ["full_attention"] * n)[:n]
@@ -149,11 +286,7 @@ def layer_specs(arch: dict) -> tuple[LayerSpec, ...]:
             raise ValueError("arch: norm_topk_prob false is not built")
         if arch.get("moe_router_logit_softcapping"):
             raise ValueError("arch: router logit soft-capping is not built")
-        if not 0 <= moe.offset <= moe.routed - moe.held:
-            raise ValueError(
-                f"arch: experts {moe.offset}..{moe.offset + moe.held} held "
-                f"of {moe.routed} routed"
-            )
+        _check_share(moe)
     specs = []
     for kind, ffn, h in zip(kinds, ffns, heads):
         if kind not in ("full_attention", "sliding_attention"):

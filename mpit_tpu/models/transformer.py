@@ -24,6 +24,7 @@ from typing import Any, Optional
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from mpit_tpu.models.arch import (
     LayerSpec, layer_specs, rope_tables, rotate_half_matrix,
@@ -141,15 +142,14 @@ class Block(nn.Module):
         return x
 
     def _described(self, x):
-        """The block ``spec`` describes: ``h = x + Attn(RMSNorm(x))``,
-        ``x' = h + FFN(RMSNorm(h))``, no biases. Scopes as in the GPT-2
-        block, with ``rope`` and ``attn_gate`` inside ``attn_proj``,
-        ``attn_window`` / ``attn_full`` inside ``attention`` and the
-        expert layer's ``moe_*`` scopes inside ``mlp``."""
-        from mpit_tpu.ops.flash_attention import flash_attention
-        from mpit_tpu.ops.moe import swiglu
-
-        spec, dt = self.spec, self.compute_dtype
+        """The block ``spec`` describes: ``x <- x + Mixer(RMSNorm(x))`` once a
+        name in ``spec.mixers``, no biases: ``attention`` then ``ffn`` for a
+        transformer layer, one mixer alone (``ssm``, ``ffn`` or
+        ``attention``) for a hybrid model's. Scopes as in the GPT-2 block,
+        with ``rope`` and ``attn_gate`` inside ``attn_proj``,
+        ``attn_window`` / ``attn_full`` inside ``attention``, the expert
+        layer's ``moe_*`` scopes inside ``mlp``, and ``ssm`` around the whole
+        Mamba-2 mixer."""
         if self.decode or self.seq_axis is not None or self.moe_experts:
             raise ValueError(
                 "a block built from an architecture (TrainConfig.arch) "
@@ -159,27 +159,45 @@ class Block(nn.Module):
             )
         if self.attn_impl not in ("xla", "flash", "flash_force"):
             raise ValueError(f"attn_impl={self.attn_impl!r}")
+        mixer = {"attention": self._attention_mixer, "ffn": self._ffn_mixer,
+                 "ssm": self._ssm_mixer}
+        for name in self.spec.mixers:
+            x = mixer[name](x)
+        return x
+
+    def _weight(self, name, *shape):
+        return self.param(name, nn.initializers.lecun_normal(), shape,
+                          jnp.float32)
+
+    def _norm_scale(self, name, width=None):
+        return self.param(name, nn.initializers.ones_init(),
+                          (width or self.spec.d_model,), jnp.float32)
+
+    def _proj(self, a, w):
+        dt = self.compute_dtype
+        return jnp.dot(a, w.astype(dt), preferred_element_type=jnp.float32)
+
+    def _attention_mixer(self, x):
+        from mpit_tpu.ops.flash_attention import flash_attention
+
+        spec, dt = self.spec, self.compute_dtype
         b, t, d = x.shape
         h, h_kv, hd = spec.num_heads, spec.num_kv_heads, spec.head_dim
-        lecun = nn.initializers.lecun_normal()
-        weight = lambda name, *shape: self.param(
-            name, lecun, shape, jnp.float32)
-        scale = lambda name: self.param(
-            name, nn.initializers.ones_init(), (d,), jnp.float32)
-        proj = lambda a, w: jnp.dot(
-            a, w.astype(dt), preferred_element_type=jnp.float32)
+        weight, proj = self._weight, self._proj
         with jax.named_scope("attn_proj"):
-            y = rms_norm(x, scale("attn_norm"), spec.norm_eps).astype(dt)
+            y = rms_norm(x, self._norm_scale("attn_norm"),
+                         spec.norm_eps).astype(dt)
             q = proj(y, weight("wq", d, h * hd)).astype(dt)
             k = proj(y, weight("wk", d, h_kv * hd)).astype(dt)
             v = proj(y, weight("wv", d, h_kv * hd)).astype(dt)
             q = q.reshape(b, t, h, hd)
             k, v = (a.reshape(b, t, h_kv, hd) for a in (k, v))
-            with jax.named_scope("rope"):
-                cos, sin = rope_tables(spec.rope, t, hd)
-                swap = rotate_half_matrix(spec.rope, hd)
-                q, k = apply_rope(q, cos, sin, swap), apply_rope(
-                    k, cos, sin, swap)
+            if spec.rope is not None:
+                with jax.named_scope("rope"):
+                    cos, sin = rope_tables(spec.rope, t, hd)
+                    swap = rotate_half_matrix(spec.rope, hd)
+                    q, k = apply_rope(q, cos, sin, swap), apply_rope(
+                        k, cos, sin, swap)
         with jax.named_scope("attention"):
             with jax.named_scope(
                 "attn_full" if spec.window is None else "attn_window"
@@ -196,21 +214,106 @@ class Block(nn.Module):
                 with jax.named_scope("attn_gate"):
                     gate = jax.nn.sigmoid(proj(y, weight("wg", d, h)))
                     att = (att * gate[..., None]).astype(dt)
-            x = x + proj(
+            return x + proj(
                 att.reshape(b, t, h * hd), weight("wo", h * hd, d)
             ).astype(dt)
+
+    def _ffn_mixer(self, x):
+        from mpit_tpu.ops.moe import swiglu
+
+        spec, dt = self.spec, self.compute_dtype
+        b, t, d = x.shape
         with jax.named_scope("mlp"):
-            y = rms_norm(x, scale("ffn_norm"), spec.norm_eps).astype(dt)
+            y = rms_norm(x, self._norm_scale("ffn_norm"),
+                         spec.norm_eps).astype(dt)
             if spec.moe is None:
-                x = x + swiglu(
-                    y, weight("w_gate", d, spec.d_ff),
-                    weight("w_up", d, spec.d_ff),
-                    weight("w_down", spec.d_ff, d),
+                return x + swiglu(
+                    y, self._weight("w_gate", d, spec.d_ff),
+                    self._weight("w_up", d, spec.d_ff),
+                    self._weight("w_down", spec.d_ff, d),
                 )
-            else:
-                x = x + self._held_experts(y.reshape(b * t, d)).reshape(
-                    b, t, d)
-        return x
+            return x + self._held_experts(y.reshape(b * t, d)).reshape(
+                b, t, d)
+
+    def _ssm_mixer(self, x):
+        """The Mamba-2 mixer: ``[z | xBC | dt] = u W_in``; ``xBC`` through a
+        causal depthwise convolution (with bias) and SiLU, then split into
+        ``x`` (heads), ``B`` and ``C`` (groups); ``dt = softplus(dt +
+        dt_bias)``, ``A = -exp(A_log)``; the recurrence (``ops/ssd.py``);
+        ``RMSNorm`` over each group's channels of ``y * SiLU(z)`` (gate
+        first, then norm) times a weight; ``W_out``. ``ssd``'s
+        ``log_decay_min`` is sown into ``counters``."""
+        from mpit_tpu.ops import ssd as ssd_ops
+
+        spec, ssm, dt = self.spec, self.spec.ssm, self.compute_dtype
+        b, t, d = x.shape
+        f32 = jnp.float32
+        inner, gn = ssm.d_inner, ssm.groups * ssm.state
+
+        def dt_bias_init(key, shape, dtype):
+            # softplus^-1 of a step log-uniform in [dt_min, dt_max]
+            step = jnp.exp(jax.random.uniform(key, shape, dtype) * (
+                jnp.log(ssm.dt_max) - jnp.log(ssm.dt_min))
+                + jnp.log(ssm.dt_min))
+            step = jnp.maximum(step, ssm.dt_floor)
+            return step + jnp.log(-jnp.expm1(-step))
+
+        per_head = lambda name, init: self.param(
+            name, init, (ssm.heads,), f32)
+        with jax.named_scope("ssm"):
+            u = rms_norm(x, self._norm_scale("ssm_norm"),
+                         spec.norm_eps).astype(dt)
+            zxbcdt = self._proj(u, self._weight(
+                "in_proj", d, 2 * inner + 2 * gn + ssm.heads))
+            z = zxbcdt[..., :inner].astype(dt)
+            xbc = zxbcdt[..., inner:inner + ssm.conv_dim]
+            step = jax.nn.softplus(
+                zxbcdt[..., inner + ssm.conv_dim:]
+                + per_head("dt_bias", dt_bias_init))
+            with jax.named_scope("ssm_conv"):
+                conv_w = self.param(
+                    "conv_w", nn.initializers.lecun_normal(in_axis=-1,
+                                                           out_axis=-2),
+                    (ssm.conv_dim, ssm.conv_kernel), f32)
+                conv_b = self.param("conv_b", nn.initializers.zeros_init(),
+                                    (ssm.conv_dim,), f32)
+                # out_t = b + sum_k w[:, k] in_{t - (K - 1) + k}
+                kk = ssm.conv_kernel
+                padded = jnp.pad(xbc, ((0, 0), (kk - 1, 0), (0, 0)))
+                xbc = conv_b + sum(
+                    padded[:, k:k + t] * conv_w[:, k] for k in range(kk))
+                xbc = jax.nn.silu(xbc).astype(dt)
+            # what the scan and the gate read, kept by a remat'd block
+            # (_REMAT_KEEPS): its backward then recomputes neither the
+            # input projection nor the convolution
+            z, xbc, step = (checkpoint_name(a, "ssm_in")
+                            for a in (z, xbc, step))
+            with jax.named_scope("ssd"):
+                y, log_decay_min = ssd_ops.ssd(
+                    xbc[..., :inner].reshape(b, t, ssm.heads, ssm.head_dim),
+                    step,
+                    -jnp.exp(per_head("A_log", lambda key, shape, dtype:
+                                      jnp.log(jnp.arange(1, shape[0] + 1,
+                                                         dtype=dtype)))),
+                    xbc[..., inner:inner + gn].reshape(
+                        b, t, ssm.groups, ssm.state),
+                    xbc[..., inner + gn:].reshape(
+                        b, t, ssm.groups, ssm.state),
+                    per_head("D", nn.initializers.ones_init()),
+                    chunk=ssm.chunk,
+                )
+            self.sow("counters", "ssm_chunk_log_decay_min", log_decay_min)
+            with jax.named_scope("ssm_gate"):
+                gated = (y.reshape(b, t, inner).astype(f32)
+                         * jax.nn.silu(z.astype(f32)))
+                grouped = gated.reshape(b, t, ssm.groups, inner // ssm.groups)
+                gated = (grouped * jax.lax.rsqrt(
+                    jnp.mean(jnp.square(grouped), axis=-1, keepdims=True)
+                    + spec.norm_eps)).reshape(b, t, inner)
+                gated = (gated * self._norm_scale("gate_norm", inner)
+                         ).astype(dt)
+            return x + self._proj(
+                gated, self._weight("out_proj", inner, d)).astype(dt)
 
     def _held_experts(self, y2):
         """The sparse feed-forward's part that lives here
@@ -218,27 +321,32 @@ class Block(nn.Module):
         chip of the deployment computes alike. Routing counters are sown
         into the ``counters`` collection (``aggregate_counters``) and the
         chosen expert ids into ``routing``."""
-        from mpit_tpu.ops.moe import moe_ffn_held, swiglu
+        from mpit_tpu.ops import moe as moe_ops
 
         moe, d = self.spec.moe, self.spec.d_model
-        lecun = nn.initializers.lecun_normal()
         expert_init = nn.initializers.variance_scaling(
             1.0, "fan_in", "truncated_normal", in_axis=-2, out_axis=-1,
             batch_axis=(0,),
         )
-        stacked = lambda name, *shape: self.param(
-            name, expert_init, (moe.held, *shape), jnp.float32)
-        params = {
-            "router": self.param(
-                "moe_router", lecun, (d, moe.routed), jnp.float32),
-            "w_gate": stacked("moe_w_gate", d, moe.width),
-            "w_up": stacked("moe_w_up", d, moe.width),
-            "w_down": stacked("moe_w_down", moe.width, d),
-        }
-        out, counters, (_, experts) = moe_ffn_held(
+        # w_gate, w_up: (d, width); w_down: (width, d)
+        shape = lambda name, width: (
+            (width, d) if name == "w_down" else (d, width))
+        names = moe_ops.EXPERTS[moe.expert][0]
+        params = {"router": self._weight("moe_router", d, moe.routed)}
+        if moe.scoring == "sigmoid":
+            # the family's e_score_correction_bias: no gradient reaches it
+            # (the choice is discrete); small and non-zero from the seed
+            params["bias"] = self.param(
+                "moe_bias", nn.initializers.normal(0.01), (moe.routed,),
+                jnp.float32)
+        for name in names:
+            params[name] = self.param(
+                f"moe_{name}", expert_init,
+                (moe.held, *shape(name, moe.width)), jnp.float32)
+        out, counters, (_, experts) = moe_ops.moe_ffn_held(
             params, y2, top_k=moe.top_k, expert_offset=moe.offset,
             row_bound=moe.rows(y2.shape[0]), scale=moe.scale,
-            routing_grad=moe.routing_grad,
+            routing_grad=moe.routing_grad, expert=moe.expert,
         )
         for name, val in counters.items():
             self.sow("counters", name, val)
@@ -247,13 +355,10 @@ class Block(nn.Module):
         self.sow("routing", "experts", experts)
         if moe.shared_width:
             with jax.named_scope("moe_shared"):
-                shared = lambda name, *shape: self.param(
-                    name, lecun, shape, jnp.float32)
-                out = out + swiglu(
-                    y2, shared("shared_w_gate", d, moe.shared_width),
-                    shared("shared_w_up", d, moe.shared_width),
-                    shared("shared_w_down", moe.shared_width, d),
-                )
+                out = out + moe_ops.dense_expert(moe.expert, y2, *(
+                    self._weight(f"shared_{name}",
+                                 *shape(name, moe.shared_width))
+                    for name in names))
         return out
 
     def _cached_attention(self, q, k, v):
@@ -416,8 +521,12 @@ class Block(nn.Module):
 #: read (recomputing them is the dearest kernel run twice), and q, k and v
 #: as they enter it (after the rotary), which spares the recomputed
 #: projections and rotary too. A block that reaches no kernel (dense, ring,
-#: Ulysses) names nothing and keeps nothing.
-_REMAT_KEEPS = ("flash_out", "flash_lse", "flash_qkv")
+#: Ulysses) names nothing and keeps nothing. A Mamba-2 mixer names ``ssm_in``:
+#: the gate ``z``, ``xBC`` after its convolution and the step ``dt`` (169 MB a
+#: layer at 8,192 tokens), which spares the recomputed input projection and
+#: convolution; the scan itself is computed again (its own residuals, the
+#: chunks' decay masks and states, are five times that).
+_REMAT_KEEPS = ("flash_out", "flash_lse", "flash_qkv", "ssm_in")
 
 # explicit names at the call sites: nn.remat renames the wrapped class
 # (CheckpointBlock), which would fork the param tree between remat modes
@@ -450,24 +559,26 @@ def aggregate_moe_losses(collection: dict) -> dict:
 
 
 def aggregate_counters(collection: dict) -> dict:
-    """One value a step from the routing counters the expert layers sowed
-    (``model.apply(..., mutable=["counters"])``): ``moe_rows_held`` the
-    mean over layers of the pairs routed to the experts held,
-    ``moe_rows_walked`` the mean of the buffer rows computed for them,
-    ``moe_load_max_over_mean`` the worst layer's fullest expert over its
-    mean, ``moe_rows_dropped`` the sum of the rows past the bound,
-    ``moe_balance`` the mean of the load-balancing terms (top-k = uniform)."""
+    """One value a step from the counters the layers sowed
+    (``model.apply(..., mutable=["counters"])``). From the expert layers:
+    ``moe_rows_held`` the mean over layers of the pairs routed to the
+    experts held, ``moe_rows_walked`` the mean of the buffer rows computed
+    for them, ``moe_load_max_over_mean`` the worst layer's fullest expert
+    over its mean, ``moe_rows_dropped`` the sum of the rows past the bound,
+    ``moe_balance`` the mean of the load-balancing terms (top-k = uniform).
+    From the Mamba-2 layers: ``ssm_chunk_log_decay_min``, the most negative
+    sum of ``dt A`` over one chunk, over heads, chunks and layers."""
     by_name = _sown_by_name(collection)
-    if not by_name:
-        return {}
-    return {
-        "moe_rows_held": jnp.mean(jnp.stack(by_name["rows_held"])),
-        "moe_rows_walked": jnp.mean(jnp.stack(by_name["rows_walked"])),
-        "moe_load_max_over_mean": jnp.max(
-            jnp.stack(by_name["load_max_over_mean"])),
-        "moe_rows_dropped": jnp.sum(jnp.stack(by_name["rows_dropped"])),
-        "moe_balance": jnp.mean(jnp.stack(by_name["balance"])),
+    reduce = {
+        "rows_held": ("moe_rows_held", jnp.mean),
+        "rows_walked": ("moe_rows_walked", jnp.mean),
+        "load_max_over_mean": ("moe_load_max_over_mean", jnp.max),
+        "rows_dropped": ("moe_rows_dropped", jnp.sum),
+        "balance": ("moe_balance", jnp.mean),
+        "ssm_chunk_log_decay_min": ("ssm_chunk_log_decay_min", jnp.min),
     }
+    return {out: over(jnp.stack(by_name[name]))
+            for name, (out, over) in reduce.items() if name in by_name}
 
 
 class TransformerLM(nn.Module):
@@ -553,7 +664,7 @@ class TransformerLM(nn.Module):
             )
             counters = aggregate_counters(sown.get("counters", {}))
             loss = cross_entropy_loss(logits, y)
-            if coef and counters:
+            if coef and "moe_balance" in counters:
                 loss = loss + coef * counters["moe_balance"]
             return loss, counters
 

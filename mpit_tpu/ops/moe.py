@@ -238,28 +238,69 @@ def moe_ffn_dense_reference(
 
 # -- one chip's share of a dropless, sort-dispatched expert layer -----------
 
-def route_top_k(y2, router, top_k: int, scale: float = 1.0):
-    """Softmax scores over ALL experts in f32, the ``top_k`` largest,
-    their weights divided by their sum and multiplied by ``scale``.
-    ``(tokens, D) -> (tokens, k)`` weights and int32 expert ids, and the
-    ``(tokens, E)`` scores."""
+def route_top_k(y2, router, top_k: int, scale: float = 1.0, bias=None):
+    """Scores over ALL experts in f32, the ``top_k`` largest, their weights
+    divided by their sum and multiplied by ``scale``. Without ``bias`` the
+    scores are a softmax. With ``bias`` (``(E,)``, the family's
+    ``e_score_correction_bias``) they are sigmoids, the choice is the
+    ``top_k`` of ``score + bias`` and the weights are the chosen scores
+    WITHOUT it. ``(tokens, D) -> (tokens, k)`` weights and int32 expert ids,
+    and the ``(tokens, E)`` scores as shares that sum to 1 a token (the
+    load-balancing term's ``P``)."""
     logits = jnp.dot(
         y2.astype(jnp.float32), router.astype(jnp.float32),
         precision=lax.Precision.HIGHEST,
     )
-    scores = jax.nn.softmax(logits, axis=-1)
-    weights, experts = lax.top_k(scores, top_k)
-    weights = weights / weights.sum(-1, keepdims=True) * scale
-    return weights, experts.astype(jnp.int32), scores
+    if bias is None:
+        scores = jax.nn.softmax(logits, axis=-1)
+        weights, experts = lax.top_k(scores, top_k)
+        weights = weights / weights.sum(-1, keepdims=True) * scale
+        return weights, experts.astype(jnp.int32), scores
+    scores = jax.nn.sigmoid(logits)
+    _, experts = lax.top_k(scores + lax.stop_gradient(bias), top_k)
+    weights = jnp.take_along_axis(scores, experts, axis=-1)
+    weights = weights / (weights.sum(-1, keepdims=True) + 1e-20) * scale
+    return (weights, experts.astype(jnp.int32),
+            scores / scores.sum(-1, keepdims=True))
+
+
+#: what one expert computes, by name: the parameters it takes (leading
+#: dimension: the experts held) and ``(product, rows, *weights) -> rows``,
+#: ``product`` the matrix product it is to use: ``moe_ffn_held`` hands it
+#: the grouped product, ``dense_expert`` the plain one; the function's own
+#: derivative is its backward. The last weight maps out of the expert's
+#: width (on its second axis), the others into it (on their last), and a
+#: zero there gives a zero
+EXPERTS = {
+    "swiglu": (("w_gate", "w_up", "w_down"),
+               lambda product, rows, w_gate, w_up, w_down: product(
+                   jax.nn.silu(product(rows, w_gate)) * product(rows, w_up),
+                   w_down)),
+    # ungated: W_down relu(W_up x)^2
+    "relu2": (("w_up", "w_down"),
+              lambda product, rows, w_up, w_down: product(
+                  jnp.square(jax.nn.relu(product(rows, w_up))), w_down)),
+}
+
+
+def dense_expert(expert: str, x, *weights):
+    """``EXPERTS[expert]`` as one dense feed-forward without biases (a
+    shared expert, a dense layer), in ``x``'s dtype with f32 accumulation."""
+    dt = x.dtype
+    mm = lambda a, w: jnp.dot(
+        a, w.astype(dt), preferred_element_type=jnp.float32).astype(dt)
+    return EXPERTS[expert][1](mm, x, *weights)
 
 
 def swiglu(x, w_gate, w_up, w_down):
     """``W_down(silu(W_gate x) * (W_up x))`` without biases, in ``x``'s
     dtype with f32 accumulation."""
-    dt = x.dtype
-    mm = lambda a, w: jnp.dot(
-        a, w.astype(dt), preferred_element_type=jnp.float32).astype(dt)
-    return mm(jax.nn.silu(mm(x, w_gate)) * mm(x, w_up), w_down)
+    return dense_expert("swiglu", x, w_gate, w_up, w_down)
+
+
+#: the held experts' width is padded with zeros to a multiple of this
+#: (``moe_ffn_held``); the last of an expert's weights maps out of the width
+_WIDTH_TILE = 512
 
 
 def chunk_rows(tokens: int, top_k: int, held: int, routed: int) -> int:
@@ -272,11 +313,11 @@ def chunk_rows(tokens: int, top_k: int, held: int, routed: int) -> int:
     return 2 * math.ceil(tokens * top_k * held / routed / 256) * 256
 
 
-def _expert_rows(rows, w_gate, w_up, w_down, row_weight, ends, start):
-    """The SwiGLU experts over ``rows``, which are rows ``start ..
-    start + len(rows)`` of the buffer sorted by expert (``ends``: where
-    each expert's rows end in the whole buffer), times ``row_weight``, in
-    f32. The weights come in ``rows``' dtype."""
+def _expert_rows(expert, rows, weights, row_weight, ends, start):
+    """The experts (``EXPERTS[expert]``) over ``rows``, which are rows
+    ``start .. start + len(rows)`` of the buffer sorted by expert (``ends``:
+    where each expert's rows end in the whole buffer), times ``row_weight``,
+    in f32. The weights come in ``rows``' dtype."""
     n, dt = rows.shape[0], rows.dtype
     with jax.named_scope("moe_dispatch"):
         span = jnp.clip(ends - start, 0, n)
@@ -291,18 +332,17 @@ def _expert_rows(rows, w_gate, w_up, w_down, row_weight, ends, start):
         grouped = lambda a, w: live(lax.ragged_dot(
             live(a), w, sizes, preferred_element_type=jnp.float32
         ).astype(dt))
-        hidden = jax.nn.silu(grouped(rows, w_gate)) * grouped(rows, w_up)
-        out_rows = grouped(hidden, w_down)
+        out_rows = EXPERTS[expert][1](grouped, rows, *weights)
     with jax.named_scope("moe_dispatch"):
         return out_rows.astype(jnp.float32) * row_weight[:, None]
 
 
-def _add_rows(out, y, weights, token, row_weight, ends, start):
+def _add_rows(expert, out, y, weights, token, row_weight, ends, start):
     """``out`` with the experts' weighted outputs for the buffer's rows
     ``start .. start + len(token)`` added at their tokens."""
     with jax.named_scope("moe_dispatch"):
         rows = jnp.take(y, token, axis=0)
-    add = _expert_rows(rows, *weights, row_weight, ends, start)
+    add = _expert_rows(expert, rows, weights, row_weight, ends, start)
     with jax.named_scope("moe_dispatch"):
         return out.at[token].add(add)
 
@@ -319,8 +359,8 @@ def _live_chunks(chunk, ends):
     return -(-ends[-1] // chunk)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _walk(chunk, y, weights, row_weight, token, ends):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _walk(chunk, expert, y, weights, row_weight, token, ends):
     """``zeros.at[token].add(_expert_rows(y[token], ...))`` in ``y``'s
     dtype, the buffer walked ``chunk`` rows at a time and only as far as
     its last live row, ``ends[-1]``: one loop with a traced trip count
@@ -329,13 +369,13 @@ def _walk(chunk, y, weights, row_weight, token, ends):
     remat would) and keeps the cotangents as carries updated in place;
     the weights' carries have the weights' dtype, as the single pass's
     cotangents have, and a chunk's row sums are f32 inside its product."""
-    return _walk_fwd(chunk, y, weights, row_weight, token, ends)[0]
+    return _walk_fwd(chunk, expert, y, weights, row_weight, token, ends)[0]
 
 
-def _walk_fwd(chunk, y, weights, row_weight, token, ends):
+def _walk_fwd(chunk, expert, y, weights, row_weight, token, ends):
     def body(j, out):
         start, tok, weight = _chunk_of(chunk, j, token, row_weight)
-        return _add_rows(out, y, weights, tok, weight, ends, start)
+        return _add_rows(expert, out, y, weights, tok, weight, ends, start)
 
     with jax.named_scope("moe_dispatch"):
         out = lax.fori_loop(0, _live_chunks(chunk, ends), body,
@@ -343,7 +383,7 @@ def _walk_fwd(chunk, y, weights, row_weight, token, ends):
     return out, (y, weights, row_weight, token, ends)
 
 
-def _walk_bwd(chunk, residuals, ct):
+def _walk_bwd(chunk, expert, residuals, ct):
     y, weights, row_weight, token, ends = residuals
 
     def body(j, carry):
@@ -354,7 +394,7 @@ def _walk_bwd(chunk, residuals, ct):
             ct_rows = jnp.take(ct, tok, axis=0).astype(jnp.float32)
         _, vjp = jax.vjp(
             lambda rows, weights, weight: _expert_rows(
-                rows, *weights, weight, ends, start),
+                expert, rows, weights, weight, ends, start),
             rows, weights, weight)
         d_rows, d_chunk, d_weight = vjp(ct_rows)
         with jax.named_scope("moe_dispatch"):
@@ -390,21 +430,22 @@ def moe_ffn_held(
     row_bound: int,
     scale: float = 1.0,
     routing_grad: bool = True,
+    expert: str = "swiglu",
 ):
     """The part of a sparse expert layer that the experts HELD here give
     (the model-configs guide's section 4): routing scores all
     ``params["router"].shape[1]`` experts, this chip holds experts
     ``expert_offset .. expert_offset + E_held`` (the leading dimension of
-    ``params["w_gate"]``/``w_up``/``w_down``) and computes, for every
+    the expert's weights, ``EXPERTS[expert]`` names them) and computes, for every
     token, ``sum over its chosen experts held here of weight · expert``.
     What the other experts would add is left out; no code stands in for
     their chips or the exchange.
 
     Dropless by sorting, not by capacity: the (token, choice) pairs that
     name an expert held here are sorted by expert into one buffer of
-    ``row_bound`` rows, three grouped matrix products
-    (``jax.lax.ragged_dot``, the TPU compiler's own grouped kernel) run
-    the SwiGLU experts over it, and the rows are scattered back times
+    ``row_bound`` rows, grouped matrix products (``jax.lax.ragged_dot``,
+    the TPU compiler's own grouped kernel; three for SwiGLU experts, two
+    for ``relu2``) run the experts over it, and the rows are scattered back times
     their weights. ``row_bound`` is static; pairs past it are dropped
     and COUNTED (``rows_dropped``; a correct run reads 0).
 
@@ -416,6 +457,8 @@ def moe_ffn_held(
 
     ``routing_grad=False`` makes the routing weights constants of the
     backward pass (``models/arch.py``, ``moe_routing_no_grad``, says when).
+    ``params["bias"]``, where present, makes the scores sigmoids and the
+    choice that of ``score + bias`` (``route_top_k``).
 
     ``y``: ``(tokens, D)``. Returns ``(out, counters, (weights,
     experts))``; the counters are f32 scalars: ``rows_held`` (pairs
@@ -425,13 +468,14 @@ def moe_ffn_held(
     counter a gradient passes through).
     """
     tokens, d = y.shape
-    held = params["w_gate"].shape[0]
+    names = EXPERTS[expert][0]
+    held = params[names[0]].shape[0]
     routed = params["router"].shape[1]
     row_bound = min(row_bound, tokens * top_k)  # there are no more pairs
     chunk = chunk_rows(tokens, top_k, held, routed)
     with jax.named_scope("moe_router"):
         weights, experts, scores = route_top_k(
-            y, params["router"], top_k, scale)
+            y, params["router"], top_k, scale, params.get("bias"))
         if not routing_grad:
             weights = lax.stop_gradient(weights)
         # the load-balancing term E · sum_e f_e P_e over ALL experts scored,
@@ -452,18 +496,29 @@ def moe_ffn_held(
             jnp.arange(row_bound) < ends[-1],
             jnp.take(weights.reshape(-1), order), 0.0)
     with jax.named_scope("moe_experts"):  # cast once, outside any loop
-        experts_w = [params[n].astype(y.dtype)
-                     for n in ("w_gate", "w_up", "w_down")]
+        experts_w = [params[n].astype(y.dtype) for n in names]
+        # the grouped kernel wants an expert width of whole 512s: at 1,856
+        # a remat'd layer took 25.1 ms on the v5e, at 1,920 24.9 and at
+        # 2,048, with a tenth more products, 17.3 (PERF.md section 6, PR
+        # 32). Zero columns into the width and zero rows out of it leave
+        # the function as it is (every EXPERTS activation maps 0 to 0).
+        # Widths under one tile are the tests': left as they are
+        width = experts_w[-1].shape[1]
+        pad = -width % _WIDTH_TILE if width > _WIDTH_TILE else 0
+        if pad:
+            experts_w = [jnp.pad(w, ((0, 0), (0, 0), (0, pad)))
+                         for w in experts_w[:-1]] + [
+                jnp.pad(experts_w[-1], ((0, 0), (0, pad), (0, 0)))]
     if row_bound <= chunk:  # one chunk: no loop
-        out = _add_rows(jnp.zeros((tokens, d), jnp.float32), y, experts_w,
-                        token, row_weight, ends, 0).astype(y.dtype)
+        out = _add_rows(expert, jnp.zeros((tokens, d), jnp.float32), y,
+                        experts_w, token, row_weight, ends, 0).astype(y.dtype)
         rows_walked = jnp.float32(row_bound)
     else:
         with jax.named_scope("moe_dispatch"):
             # whole chunks; what lies past the bound stays past ``ends``
             pad = (0, -row_bound % chunk)
             token, row_weight = jnp.pad(token, pad), jnp.pad(row_weight, pad)
-        out = _walk(chunk, y, experts_w, row_weight, token, ends)
+        out = _walk(chunk, expert, y, experts_w, row_weight, token, ends)
         rows_walked = (_live_chunks(chunk, ends) * chunk).astype(jnp.float32)
     with jax.named_scope("moe_dispatch"):
         total = counts.sum().astype(jnp.float32)

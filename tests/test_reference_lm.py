@@ -15,7 +15,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from mpit_tpu.models import arch as arch_lib
 from mpit_tpu.models import reference_lm as ref
+from mpit_tpu.models import reference_nemotron_h as ref_h
 from mpit_tpu.models.transformer import TransformerLM, aggregate_counters
 from mpit_tpu.ops import moe
 
@@ -143,11 +145,12 @@ def test_lower_precision_operands_move_the_reference(problem, reference):
     assert 1e-4 < err < 0.5
 
 
-@pytest.mark.parametrize("heads", [4, 6])
+@pytest.mark.parametrize("heads", [4, 6, 32])
 @pytest.mark.parametrize("window", [8, None])
 def test_windowed_grouped_kernel_matches_the_dense_mask(heads, window):
-    """Interpret mode, forward and backward, 2 KV heads, tiles of 16 and
-    of 8 x 32 (a window inside one tile, and across three)."""
+    """Interpret mode, forward and backward, 2 KV heads (groups of 2, 3
+    and, as the nemotron_h family's attention has them, 16), tiles of 16
+    and of 8 x 32 (a window inside one tile, and across three)."""
     keys = jax.random.split(jax.random.key(3), 4)
     q, ct = (jax.random.normal(k, (2, 64, heads, 16)) for k in keys[:2])
     k, v = (jax.random.normal(kk, (2, 64, 2, 16)) for kk in keys[2:])
@@ -179,10 +182,22 @@ def test_window_steps_cover_exactly_the_live_tiles():
         assert q_steps == live.sum(0).max()
 
 
-def _expert_layer(key, tokens=64, d=32, width=16, experts=16):
-    ks = jax.random.split(key, 8)
+#: an expert layer of each family against its own reference: SwiGLU experts
+#: behind softmax scores (``reference_lm``), ungated ``relu2`` experts behind
+#: sigmoid scores with a bias on the choice (``reference_nemotron_h``)
+FAMILIES = {
+    "swiglu_softmax": (ref, ARCH, "swiglu", ref.swiglu),
+    "relu2_sigmoid": (ref_h, {
+        "num_experts_per_tok": 3, "routed_scaling_factor": 2.5,
+        "moe_shared_expert_intermediate_size": 16}, "relu2", ref_h.relu2_mlp),
+}
+family = pytest.mark.parametrize("family", sorted(FAMILIES))
+
+
+def _expert_layer(key, family, tokens=64, d=32, width=16, experts=16):
+    ks = jax.random.split(key, 9)
     init = lambda k, *s: jax.random.normal(k, s) / np.sqrt(s[-2])
-    return {
+    p = {
         "moe_router": init(ks[0], d, experts) * 3,
         "moe_w_gate": init(ks[1], experts, d, width),
         "moe_w_up": init(ks[2], experts, d, width),
@@ -190,68 +205,130 @@ def _expert_layer(key, tokens=64, d=32, width=16, experts=16):
         "shared_w_gate": init(ks[4], d, width),
         "shared_w_up": init(ks[5], d, width),
         "shared_w_down": init(ks[6], width, d),
-    }, jax.random.normal(ks[7], (1, tokens, d))
+    }
+    if FAMILIES[family][2] == "relu2":
+        del p["moe_w_gate"], p["shared_w_gate"]
+        p["moe_bias"] = 0.05 * jax.random.normal(ks[8], (experts,))
+    return p, jax.random.normal(ks[7], (1, tokens, d))
 
 
-def _held_part(p, y, offset, held, row_bound):
+def _shared(p, y, family):
+    names = moe.EXPERTS[FAMILIES[family][2]][0]
+    return FAMILIES[family][3](y, *(p[f"shared_{n}"] for n in names))
+
+
+def _held_part(p, y, offset, held, row_bound, family):
+    expert = FAMILIES[family][2]
     params = {"router": p["moe_router"],
               **{n: p[f"moe_{n}"][offset:offset + held]
-                 for n in ("w_gate", "w_up", "w_down")}}
+                 for n in moe.EXPERTS[expert][0]}}
+    if "moe_bias" in p:
+        params["bias"] = p["moe_bias"]
     return moe.moe_ffn_held(params, y[0], top_k=3, expert_offset=offset,
-                            row_bound=row_bound, scale=2.5)
+                            row_bound=row_bound, scale=2.5, expert=expert)
 
 
-def test_the_shares_add_up_to_the_uncut_layer():
+def _cut(p, lo, hi):
+    """The share of the layer's parameters that holds experts lo..hi."""
+    return {n: (v[lo:hi] if n.startswith("moe_w") else v)
+            for n, v in p.items()}
+
+
+@family
+def test_the_shares_add_up_to_the_uncut_layer(family):
     """16 experts as 4 shares of 4: the parts every share's chip computes,
     the shared expert counted once, sum to the uncut reference's output;
     so do the reference's own shares."""
-    p, y = _expert_layer(jax.random.key(4))
-    whole = ref.sparse_ffn(p, y, ARCH)
-    shared = ref.swiglu(y, p["shared_w_gate"], p["shared_w_up"],
-                        p["shared_w_down"])
+    module, arch = FAMILIES[family][:2]
+    p, y = _expert_layer(jax.random.key(4), family)
+    whole = module.sparse_ffn(p, y, arch)
+    shared = _shared(p, y, family)
     system, plain, rows = shared, shared, 0.0
     for offset in range(0, 16, 4):
-        out, counters, _ = _held_part(p, y, offset, 4, 64 * 3)
+        out, counters, _ = _held_part(p, y, offset, 4, 64 * 3, family)
         system = system + out[None]
         rows += float(counters["rows_held"])
         assert float(counters["rows_dropped"]) == 0
-        share = {n: (v[offset:offset + 4] if n.startswith("moe_w") else v)
-                 for n, v in p.items()}
-        plain = plain + ref.sparse_ffn(
-            share, y, ARCH, experts_held=4, expert_offset=offset) - shared
+        plain = plain + module.sparse_ffn(
+            _cut(p, offset, offset + 4), y, arch, experts_held=4,
+            expert_offset=offset) - shared
     assert rows == 64 * 3  # every (token, choice) pair lands in one share
     np.testing.assert_allclose(system, whole, rtol=1e-4, atol=1e-5)
     np.testing.assert_allclose(plain, whole, rtol=1e-4, atol=1e-5)
 
 
+@family
 @pytest.mark.parametrize("tokens,experts,walked", [
     (64, 16, 192),  # the buffer is one chunk: a single pass
     (1024, 32, 3072),  # three chunks of 1,024, every one full
 ])
 def test_forced_imbalance_drops_nothing_and_a_short_buffer_is_counted(
-        tokens, experts, walked):
+        tokens, experts, walked, family):
     """Every token sent to the same three experts, all held here: three
     rows a token against the 0.75 (or 0.375) uniform routing would send;
     nothing is dropped while the buffer holds them, and a buffer that does
     not counts what it lost."""
-    p, y = _expert_layer(jax.random.key(5), tokens=tokens, experts=experts)
+    module, arch = FAMILIES[family][:2]
+    p, y = _expert_layer(jax.random.key(5), family, tokens=tokens,
+                         experts=experts)
     p["moe_router"] = p["moe_router"].at[:, 4:7].add(
         100.0 * jnp.sign(y[0].mean(0))[:, None] / y.shape[-1])
     y = jnp.abs(y) * jnp.sign(y[0].mean(0))
-    out, counters, (_, chosen) = _held_part(p, y, 4, 4, tokens * 3)
+    if "moe_bias" in p:  # a bias the forced scores' lead of 1e-4 outweighs
+        p["moe_bias"] = 1e-4 * p["moe_bias"]
+    out, counters, (_, chosen) = _held_part(p, y, 4, 4, tokens * 3, family)
     assert set(np.unique(chosen)) == {4, 5, 6}
     assert float(counters["rows_held"]) == tokens * 3
     assert float(counters["rows_walked"]) == walked
     assert float(counters["rows_dropped"]) == 0
     assert float(counters["load_max_over_mean"]) == pytest.approx(4 / 3)
-    share = {n: (v[4:8] if n.startswith("moe_w") else v) for n, v in p.items()}
-    want = ref.sparse_ffn(share, y, ARCH, experts_held=4, expert_offset=4) \
-        - ref.swiglu(y, p["shared_w_gate"], p["shared_w_up"],
-                     p["shared_w_down"])
+    want = module.sparse_ffn(_cut(p, 4, 8), y, arch, experts_held=4,
+                             expert_offset=4) - _shared(p, y, family)
     np.testing.assert_allclose(out[None], want, rtol=1e-4, atol=1e-5)
-    _, short, _ = _held_part(p, y, 4, 4, tokens * 2)
+    _, short, _ = _held_part(p, y, 4, 4, tokens * 2, family)
     assert float(short["rows_dropped"]) == tokens
     assert float(short["rows_walked"]) == tokens * 2
+
+
+def test_the_architecture_gives_the_layers_and_the_tree_it_gave():
+    """What ``layer_specs`` and ``model.init`` gave this architecture
+    before a layer could be one mixer alone (PR 32): attention then a
+    feed-forward, the same description and the same parameter tree."""
+    specs = arch_lib.layer_specs(ARCH)
+    yarn = arch_lib.RopeSpec(500000.0, 8, yarn=(128.0, 8192, 32.0, 1.0),
+                             attention_factor=1.4852030263919618)
+    plain = arch_lib.RopeSpec(10000.0, 16)
+    held = arch_lib.MoESpec(routed=16, held=4, offset=4, top_k=3, width=16,
+                            shared_width=16, scale=2.5, row_bound=96)
+    assert (held.routing_grad, held.scoring, held.expert) == (
+        True, "softmax", "swiglu")
+    layer = lambda heads, window, rope, d_ff, moe: arch_lib.LayerSpec(
+        d_model=32, num_heads=heads, num_kv_heads=2, head_dim=16,
+        window=window, rope=rope, gate=True, norm_eps=1e-6, d_ff=d_ff,
+        moe=moe)
+    assert specs == (
+        layer(4, None, yarn, 64, None), layer(6, 8, plain, 0, held),
+        layer(6, 8, plain, 0, held), layer(6, 8, plain, 0, held),
+        layer(4, None, yarn, 0, held))
+    assert {s.mixers for s in specs} == {("attention", "ffn")}
+    assert {s.ssm for s in specs} == {None}
+    tree = jax.eval_shape(_model().init, jax.random.key(0),
+                          jnp.zeros((2, T), jnp.int32))["params"]
+    shapes = lambda block: {n: l.shape for n, l in tree[block].items()}
+    attention = lambda h: {
+        "attn_norm": (32,), "ffn_norm": (32,), "wq": (32, h * 16),
+        "wk": (32, 32), "wv": (32, 32), "wg": (32, h), "wo": (h * 16, 32)}
+    assert shapes("Block_0") == {
+        **attention(4), "w_gate": (32, 64), "w_up": (32, 64),
+        "w_down": (64, 32)}
+    sparse = {"moe_router": (32, 16), "moe_w_gate": (4, 32, 16),
+              "moe_w_up": (4, 32, 16), "moe_w_down": (4, 16, 32),
+              "shared_w_gate": (32, 16), "shared_w_up": (32, 16),
+              "shared_w_down": (16, 32)}
+    assert shapes("Block_1") == {**attention(6), **sparse}
+    assert shapes("Block_4") == {**attention(4), **sparse}
+    assert sorted(tree) == [f"Block_{l}" for l in range(5)] + [
+        "Embed_0", "final_norm", "head"]
 
 
 def test_counters_and_routing_are_sown(problem):
