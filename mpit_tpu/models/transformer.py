@@ -523,10 +523,10 @@ class Block(nn.Module):
 #: projections and rotary too. A block that reaches no kernel (dense, ring,
 #: Ulysses) names nothing and keeps nothing. A Mamba-2 mixer names ``ssm_in``:
 #: the gate ``z``, ``xBC`` after its convolution and the step ``dt`` (169 MB a
-#: layer at 8,192 tokens), which spares the recomputed input projection and
-#: convolution; the scan itself is computed again (its own residuals, the
-#: chunks' decay masks and states, are five times that).
-_REMAT_KEEPS = ("flash_out", "flash_lse", "flash_qkv", "ssm_in")
+#: layer at 8,192 tokens: spares the input projection and convolution), and
+#: ``ops/ssd.py``'s kernels name ``ssd_out``: ``y`` and the chunks' entering
+#: states (201 MB a layer: spares a second forward kernel; PERF.md, PR 33).
+_REMAT_KEEPS = ("flash_out", "flash_lse", "flash_qkv", "ssm_in", "ssd_out")
 
 # explicit names at the call sites: nn.remat renames the wrapped class
 # (CheckpointBlock), which would fork the param tree between remat modes

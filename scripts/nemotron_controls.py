@@ -11,7 +11,8 @@ which has to come out as not correct (its loss may pass).
   step that left half of them out would compute.
 - ``state_not_carried``: the same function with the Mamba-2 scan's states left
   where they are made (``ops/ssd.ssd(carry_state=False)``: every chunk of 128
-  starts from a zero state), the fault a chunked scan is most likely to have.
+  starts from a zero state; it takes the ``jax.numpy`` form, the kernels carry
+  their state in scratch), the fault a chunked scan is most likely to have.
 
 For each: the loss, the gradient's error by leaf group and the error of the
 two-step move the job's optimizer makes of that gradient, all against the
