@@ -8,10 +8,12 @@ of the GPT-2 block's fixed shape: its norm, positions, head counts, output
 gate, window and feed-forward kind.
 
 Keys read (others are ignored): ``hidden_size``, ``num_hidden_layers``,
-``head_dim``, ``num_key_value_heads``, ``num_attention_heads`` or
+``head_dim`` (absent or null: ``hidden_size / num_attention_heads``),
+``num_key_value_heads``, ``num_attention_heads`` or
 ``num_attention_heads_per_layer``, ``layer_types`` (``full_attention`` /
-``sliding_attention``), ``sliding_window``, ``rope_parameters`` (by layer
-type: ``rope_theta``, ``rope_type`` ``default`` or ``yarn`` with its
+``sliding_attention`` / ``linear_attention``), ``sliding_window``,
+``rope_parameters`` (by layer type: ``rope_theta``, null = no rotary
+positions; ``rope_type`` ``default`` or ``yarn`` with its
 ``factor``, ``original_max_position_embeddings``, ``beta_fast``,
 ``beta_slow``, ``attention_factor``; ``partial_rotary_factor``), ``gating``
 (``per-head`` or absent), ``rms_norm_eps``, ``intermediate_size``,
@@ -37,6 +39,17 @@ weights the mean over sparse layers of the load-balancing term ``E · sum_e
 f_e P_e`` (``f_e`` the share of tokens that chose ``e``, ``P_e`` its mean
 score: transformers' ``load_balancing_loss_func`` a layer) that
 ``TransformerLM.loss_with_counters`` adds to the loss.
+
+A ``linear_attention`` layer's mixer is the gated delta rule
+(``ops/gated_delta.py``), by the source's keys ``linear_num_key_heads``,
+``linear_num_value_heads`` (equal: value heads shared by groups of key heads
+are not built), ``linear_key_head_dim``, ``linear_value_head_dim``,
+``linear_conv_kernel_dim``, ``linear_allow_neg_eigval``, and this repo's
+``linear_chunk_size`` (default 64, a power of two). Two more keys are this
+repo's, for what a ``config.json`` leaves to the modelling code: ``norm_at``
+(``input``, the default, ``x + Mixer(RMSNorm(x))``; ``output``, OLMo 2's
+``x + RMSNorm(Mixer(x))``) and ``qk_norm`` (default false; true: an RMSNorm
+over the whole width of ``q`` and of ``k`` before they are split into heads).
 
 An ``arch`` with ``hybrid_override_pattern`` is of the ``nemotron_h`` family
 and is read by that family's keys: every layer is ONE mixer, named by its
@@ -128,11 +141,25 @@ class SSMSpec:
 
 
 @dataclasses.dataclass(frozen=True)
+class LinearAttentionSpec:
+    """A gated-delta-rule mixer: ``heads`` of ``key_dim`` for ``q`` and ``k``
+    and of ``value_dim`` for ``v`` and the output, a state of ``value_dim x
+    key_dim`` a head."""
+    heads: int
+    key_dim: int
+    value_dim: int
+    conv_kernel: int
+    neg_eigval: bool  # beta in (0, 2) instead of (0, 1)
+    chunk: int
+
+
+@dataclasses.dataclass(frozen=True)
 class LayerSpec:
-    """One layer: the residual passes ``x + Mixer(RMSNorm(x))`` once a name
-    in ``mixers``, in order. ``attention`` reads the head counts, ``window``,
-    ``rope`` and ``gate``; ``ffn`` reads ``d_ff`` or ``moe``; ``ssm`` reads
-    ``ssm``."""
+    """One layer: the residual passes ``x + Mixer(RMSNorm(x))`` (``norm_at``
+    ``input``) or ``x + RMSNorm(Mixer(x))`` (``output``) once a name in
+    ``mixers``, in order. ``attention`` reads the head counts, ``window``,
+    ``rope``, ``gate`` and ``qk_norm``; ``ffn`` reads ``d_ff`` or ``moe``;
+    ``ssm`` reads ``ssm``; ``linear_attention`` reads ``linattn``."""
     d_model: int
     num_heads: int
     num_kv_heads: int
@@ -145,6 +172,9 @@ class LayerSpec:
     moe: Optional[MoESpec]
     mixers: tuple = ("attention", "ffn")
     ssm: Optional[SSMSpec] = None
+    linattn: Optional[LinearAttentionSpec] = None
+    norm_at: str = "input"
+    qk_norm: bool = False  # RMSNorm over all of q and of k, before the heads
 
 
 def _rope_spec(params: dict, head_dim: int) -> RopeSpec:
@@ -248,11 +278,32 @@ def _hybrid_specs(arch: dict) -> tuple[LayerSpec, ...]:
     return tuple(kinds[letter] for letter in pattern)
 
 
+def _linear_attention_spec(arch: dict) -> LinearAttentionSpec:
+    heads = int(arch["linear_num_key_heads"])
+    if int(arch.get("linear_num_value_heads", heads)) != heads:
+        raise ValueError(
+            f"arch: linear_num_value_heads "
+            f"{arch['linear_num_value_heads']} on {heads} key heads: value "
+            "heads in groups are not built")
+    chunk = int(arch.get("linear_chunk_size", 64))
+    if chunk < 1 or chunk & (chunk - 1):
+        raise ValueError(
+            f"arch: linear_chunk_size {chunk} is not a power of two")
+    return LinearAttentionSpec(
+        heads=heads, key_dim=int(arch["linear_key_head_dim"]),
+        value_dim=int(arch["linear_value_head_dim"]),
+        conv_kernel=int(arch.get("linear_conv_kernel_dim", 4)),
+        neg_eigval=bool(arch.get("linear_allow_neg_eigval", False)),
+        chunk=chunk)
+
+
 def layer_specs(arch: dict) -> tuple[LayerSpec, ...]:
     if "hybrid_override_pattern" in arch:
         return _hybrid_specs(arch)
     n = int(arch["num_hidden_layers"])
-    d, head_dim = int(arch["hidden_size"]), int(arch["head_dim"])
+    d = int(arch["hidden_size"])
+    head_dim = int(arch.get("head_dim")
+                   or d // int(arch["num_attention_heads"]))
     kinds = list(arch.get("layer_types") or ["full_attention"] * n)[:n]
     ffns = list(arch.get("mlp_layer_types") or ["dense"] * n)[:n]
     heads = list(arch.get("num_attention_heads_per_layer")
@@ -269,6 +320,13 @@ def layer_specs(arch: dict) -> tuple[LayerSpec, ...]:
     ropes = arch["rope_parameters"]
     if "rope_theta" in ropes:  # one rope for every layer type
         ropes = {"full_attention": ropes, "sliding_attention": ropes}
+    norm_at = arch.get("norm_at", "input")
+    if norm_at not in ("input", "output"):
+        raise ValueError(f"arch: norm_at {norm_at!r}: have input, output")
+    shared = dict(d_model=d, norm_eps=float(arch.get("rms_norm_eps", 1e-6)),
+                  norm_at=norm_at)
+    linattn = (_linear_attention_spec(arch)
+               if "linear_attention" in kinds else None)
     moe = None
     if "sparse" in ffns:
         held = int(arch["num_experts"])
@@ -289,20 +347,29 @@ def layer_specs(arch: dict) -> tuple[LayerSpec, ...]:
         _check_share(moe)
     specs = []
     for kind, ffn, h in zip(kinds, ffns, heads):
-        if kind not in ("full_attention", "sliding_attention"):
+        if kind not in ("full_attention", "sliding_attention",
+                        "linear_attention"):
             raise ValueError(f"arch: layer type {kind!r}")
         if ffn not in ("dense", "sparse"):
             raise ValueError(f"arch: mlp layer type {ffn!r}")
+        feed_forward = dict(
+            d_ff=int(arch["intermediate_size"]) if ffn == "dense" else 0,
+            moe=moe if ffn == "sparse" else None)
+        if kind == "linear_attention":
+            specs.append(LayerSpec(
+                **shared, **feed_forward, num_heads=0, num_kv_heads=0,
+                head_dim=0, window=None, rope=None, gate=False,
+                mixers=("linear_attention", "ffn"), linattn=linattn))
+            continue
         specs.append(LayerSpec(
-            d_model=d, num_heads=int(h),
+            **shared, **feed_forward, num_heads=int(h),
             num_kv_heads=int(arch["num_key_value_heads"]), head_dim=head_dim,
             window=(int(arch["sliding_window"])
                     if kind == "sliding_attention" else None),
-            rope=_rope_spec(ropes[kind], head_dim),
+            rope=(None if ropes[kind].get("rope_theta") is None
+                  else _rope_spec(ropes[kind], head_dim)),
             gate=gating == "per-head",
-            norm_eps=float(arch.get("rms_norm_eps", 1e-6)),
-            d_ff=int(arch["intermediate_size"]) if ffn == "dense" else 0,
-            moe=moe if ffn == "sparse" else None,
+            qk_norm=bool(arch.get("qk_norm", False)),
         ))
     return tuple(specs)
 

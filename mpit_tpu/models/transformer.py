@@ -56,6 +56,28 @@ def apply_rope(x, cos, sin, swap):
             + partner * sin[None, :, None, :]).astype(x.dtype)
 
 
+def causal_conv_silu(x, weight, bias=None):
+    """``SiLU(conv(x))`` in float32: ``x`` ``(B, T, C)`` through a depthwise
+    causal convolution, ``out_t = bias + sum_k weight[:, k] x_{t - (K - 1) +
+    k}`` with ``weight`` ``(C, K)`` and ``K - 1`` zeros to the left."""
+    t, taps = x.shape[1], weight.shape[1]
+    padded = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0)))
+    out = sum(padded[:, k:k + t] * weight[:, k] for k in range(taps))
+    return jax.nn.silu(out if bias is None else bias + out)
+
+
+def softplus_inverse_of_a_step(dt_min, dt_max, dt_floor):
+    """An initialiser (Mamba-2's rule for ``dt_bias``): ``softplus^-1`` of a
+    step log-uniform in ``[dt_min, dt_max]``, floored at ``dt_floor``."""
+    def init(key, shape, dtype):
+        step = jnp.exp(jax.random.uniform(key, shape, dtype) * (
+            jnp.log(dt_max) - jnp.log(dt_min)) + jnp.log(dt_min))
+        step = jnp.maximum(step, dt_floor)
+        return step + jnp.log(-jnp.expm1(-step))
+
+    return init
+
+
 class Block(nn.Module):
     d_model: int
     num_heads: int
@@ -142,14 +164,16 @@ class Block(nn.Module):
         return x
 
     def _described(self, x):
-        """The block ``spec`` describes: ``x <- x + Mixer(RMSNorm(x))`` once a
-        name in ``spec.mixers``, no biases: ``attention`` then ``ffn`` for a
+        """The block ``spec`` describes: ``x <- x + Mixer(RMSNorm(x))`` (or,
+        ``spec.norm_at`` ``output``, ``x + RMSNorm(Mixer(x))``) once a
+        name in ``spec.mixers``, no biases: ``attention`` or
+        ``linear_attention`` then ``ffn`` for a
         transformer layer, one mixer alone (``ssm``, ``ffn`` or
         ``attention``) for a hybrid model's. Scopes as in the GPT-2 block,
-        with ``rope`` and ``attn_gate`` inside ``attn_proj``,
+        with ``rope``, ``qk_norm`` and ``attn_gate`` inside ``attn_proj``,
         ``attn_window`` / ``attn_full`` inside ``attention``, the expert
-        layer's ``moe_*`` scopes inside ``mlp``, and ``ssm`` around the whole
-        Mamba-2 mixer."""
+        layer's ``moe_*`` scopes inside ``mlp``, ``ssm`` around the whole
+        Mamba-2 mixer and ``linattn`` around the whole gated-delta-rule one."""
         if self.decode or self.seq_axis is not None or self.moe_experts:
             raise ValueError(
                 "a block built from an architecture (TrainConfig.arch) "
@@ -160,7 +184,8 @@ class Block(nn.Module):
         if self.attn_impl not in ("xla", "flash", "flash_force"):
             raise ValueError(f"attn_impl={self.attn_impl!r}")
         mixer = {"attention": self._attention_mixer, "ffn": self._ffn_mixer,
-                 "ssm": self._ssm_mixer}
+                 "ssm": self._ssm_mixer,
+                 "linear_attention": self._linear_attention_mixer}
         for name in self.spec.mixers:
             x = mixer[name](x)
         return x
@@ -177,6 +202,21 @@ class Block(nn.Module):
         dt = self.compute_dtype
         return jnp.dot(a, w.astype(dt), preferred_element_type=jnp.float32)
 
+    def _mixer_input(self, x, norm):
+        """What a mixer reads: ``RMSNorm(x)`` by the scale ``norm``, or ``x``
+        itself where the block's norm is on the mixer's output."""
+        if self.spec.norm_at == "output":
+            return x.astype(self.compute_dtype)
+        return rms_norm(x, self._norm_scale(norm),
+                        self.spec.norm_eps).astype(self.compute_dtype)
+
+    def _residual(self, x, out, norm):
+        """``x + out``, ``out`` through the block's norm first where that is
+        on the mixer's output (OLMo 2's reordered norm)."""
+        if self.spec.norm_at == "output":
+            out = rms_norm(out, self._norm_scale(norm), self.spec.norm_eps)
+        return x + out.astype(self.compute_dtype)
+
     def _attention_mixer(self, x):
         from mpit_tpu.ops.flash_attention import flash_attention
 
@@ -185,11 +225,17 @@ class Block(nn.Module):
         h, h_kv, hd = spec.num_heads, spec.num_kv_heads, spec.head_dim
         weight, proj = self._weight, self._proj
         with jax.named_scope("attn_proj"):
-            y = rms_norm(x, self._norm_scale("attn_norm"),
-                         spec.norm_eps).astype(dt)
+            y = self._mixer_input(x, "attn_norm")
             q = proj(y, weight("wq", d, h * hd)).astype(dt)
             k = proj(y, weight("wk", d, h_kv * hd)).astype(dt)
             v = proj(y, weight("wv", d, h_kv * hd)).astype(dt)
+            if spec.qk_norm:
+                # over the whole width, before the split into heads
+                with jax.named_scope("qk_norm"):
+                    q = rms_norm(q, self._norm_scale("q_norm", h * hd),
+                                 spec.norm_eps).astype(dt)
+                    k = rms_norm(k, self._norm_scale("k_norm", h_kv * hd),
+                                 spec.norm_eps).astype(dt)
             q = q.reshape(b, t, h, hd)
             k, v = (a.reshape(b, t, h_kv, hd) for a in (k, v))
             if spec.rope is not None:
@@ -214,9 +260,9 @@ class Block(nn.Module):
                 with jax.named_scope("attn_gate"):
                     gate = jax.nn.sigmoid(proj(y, weight("wg", d, h)))
                     att = (att * gate[..., None]).astype(dt)
-            return x + proj(
+            return self._residual(x, proj(
                 att.reshape(b, t, h * hd), weight("wo", h * hd, d)
-            ).astype(dt)
+            ), "attn_norm")
 
     def _ffn_mixer(self, x):
         from mpit_tpu.ops.moe import swiglu
@@ -224,16 +270,15 @@ class Block(nn.Module):
         spec, dt = self.spec, self.compute_dtype
         b, t, d = x.shape
         with jax.named_scope("mlp"):
-            y = rms_norm(x, self._norm_scale("ffn_norm"),
-                         spec.norm_eps).astype(dt)
+            y = self._mixer_input(x, "ffn_norm")
             if spec.moe is None:
-                return x + swiglu(
+                return self._residual(x, swiglu(
                     y, self._weight("w_gate", d, spec.d_ff),
                     self._weight("w_up", d, spec.d_ff),
                     self._weight("w_down", spec.d_ff, d),
-                )
-            return x + self._held_experts(y.reshape(b * t, d)).reshape(
-                b, t, d)
+                ), "ffn_norm")
+            return self._residual(x, self._held_experts(
+                y.reshape(b * t, d)).reshape(b, t, d), "ffn_norm")
 
     def _ssm_mixer(self, x):
         """The Mamba-2 mixer: ``[z | xBC | dt] = u W_in``; ``xBC`` through a
@@ -250,14 +295,8 @@ class Block(nn.Module):
         f32 = jnp.float32
         inner, gn = ssm.d_inner, ssm.groups * ssm.state
 
-        def dt_bias_init(key, shape, dtype):
-            # softplus^-1 of a step log-uniform in [dt_min, dt_max]
-            step = jnp.exp(jax.random.uniform(key, shape, dtype) * (
-                jnp.log(ssm.dt_max) - jnp.log(ssm.dt_min))
-                + jnp.log(ssm.dt_min))
-            step = jnp.maximum(step, ssm.dt_floor)
-            return step + jnp.log(-jnp.expm1(-step))
-
+        dt_bias_init = softplus_inverse_of_a_step(
+            ssm.dt_min, ssm.dt_max, ssm.dt_floor)
         per_head = lambda name, init: self.param(
             name, init, (ssm.heads,), f32)
         with jax.named_scope("ssm"):
@@ -277,12 +316,7 @@ class Block(nn.Module):
                     (ssm.conv_dim, ssm.conv_kernel), f32)
                 conv_b = self.param("conv_b", nn.initializers.zeros_init(),
                                     (ssm.conv_dim,), f32)
-                # out_t = b + sum_k w[:, k] in_{t - (K - 1) + k}
-                kk = ssm.conv_kernel
-                padded = jnp.pad(xbc, ((0, 0), (kk - 1, 0), (0, 0)))
-                xbc = conv_b + sum(
-                    padded[:, k:k + t] * conv_w[:, k] for k in range(kk))
-                xbc = jax.nn.silu(xbc).astype(dt)
+                xbc = causal_conv_silu(xbc, conv_w, conv_b).astype(dt)
             # what the scan and the gate read, kept by a remat'd block
             # (_REMAT_KEEPS): its backward then recomputes neither the
             # input projection nor the convolution
@@ -314,6 +348,65 @@ class Block(nn.Module):
                          ).astype(dt)
             return x + self._proj(
                 gated, self._weight("out_proj", inner, d)).astype(dt)
+
+    def _linear_attention_mixer(self, x):
+        """The gated-delta-rule mixer (``ops/gated_delta.py`` has the
+        equations): ``q``, ``k``, ``v`` each a projection, a causal depthwise
+        convolution without bias and SiLU; ``q`` and ``k`` L2-normalised a
+        head, ``q`` times ``d_k^-1/2``; ``beta = sigmoid(u W_b)`` (times 2
+        where negative eigenvalues are allowed), ``g = -exp(A_log)
+        softplus(u W_a + dt_bias)``; the recurrence; ``RMSNorm`` over each
+        head's channels times a weight, THEN the gate ``SiLU(u W_gate)``
+        (the other order from ``_ssm_mixer``'s); ``W_o``. The op's
+        ``log_decay_min`` is sown into ``counters``."""
+        from mpit_tpu.ops.gated_delta import gated_delta
+
+        spec, lin, dt = self.spec, self.spec.linattn, self.compute_dtype
+        b, t, d = x.shape
+        f32 = jnp.float32
+        h, dk, dv = lin.heads, lin.key_dim, lin.value_dim
+        conv_init = nn.initializers.lecun_normal(in_axis=-1, out_axis=-2)
+        per_head = lambda name, init: self.param(name, init, (h,), f32)
+        with jax.named_scope("linattn"):
+            u = self._mixer_input(x, "linattn_norm")
+            proj = lambda name, width: self._proj(
+                u, self._weight(name, d, width))
+            widths = (("q", h * dk), ("k", h * dk), ("v", h * dv))
+            qkv = [proj(f"lin_{name}", width).astype(dt)
+                   for name, width in widths]
+            with jax.named_scope("linattn_conv"):
+                q, k, v = (
+                    causal_conv_silu(a, self.param(
+                        f"conv_{name}", conv_init, (width, lin.conv_kernel),
+                        f32)).astype(dt)
+                    for a, (name, width) in zip(qkv, widths))
+            with jax.named_scope("delta_rule"):
+                unit = lambda a: a * jax.lax.rsqrt(
+                    jnp.sum(jnp.square(a), axis=-1, keepdims=True) + 1e-6)
+                q, k = (a.reshape(b, t, h, dk).astype(f32) for a in (q, k))
+                q, k = unit(q) * dk ** -0.5, unit(k)
+                beta = jax.nn.sigmoid(proj("lin_b", h)) * (
+                    2.0 if lin.neg_eigval else 1.0)
+                # A uniform in [1, 16], dt_bias by Mamba-2's rule
+                a_log = per_head("A_log", lambda key, shape, dtype: jnp.log(
+                    jax.random.uniform(key, shape, dtype, 1.0, 16.0)))
+                g = -jnp.exp(a_log) * jax.nn.softplus(
+                    proj("lin_a", h) + per_head(
+                        "dt_bias", softplus_inverse_of_a_step(
+                            0.001, 0.1, 1e-4)))
+                o, log_decay_min = gated_delta(
+                    q.astype(dt), k.astype(dt),
+                    v.reshape(b, t, h, dv), g, beta,
+                    chunk=lin.chunk)
+            self.sow("counters", "delta_chunk_log_decay_min", log_decay_min)
+            gate = proj("lin_gate", h * dv).astype(dt)
+            with jax.named_scope("linattn_gate"):
+                o = (rms_norm(o, self._norm_scale("gate_norm", dv),
+                              spec.norm_eps).reshape(b, t, h * dv)
+                     * jax.nn.silu(gate.astype(f32))).astype(dt)
+            return self._residual(
+                x, self._proj(o, self._weight("lin_o", h * dv, d)),
+                "linattn_norm")
 
     def _held_experts(self, y2):
         """The sparse feed-forward's part that lives here
@@ -567,7 +660,8 @@ def aggregate_counters(collection: dict) -> dict:
     over its mean, ``moe_rows_dropped`` the sum of the rows past the bound,
     ``moe_balance`` the mean of the load-balancing terms (top-k = uniform).
     From the Mamba-2 layers: ``ssm_chunk_log_decay_min``, the most negative
-    sum of ``dt A`` over one chunk, over heads, chunks and layers."""
+    sum of ``dt A`` over one chunk, over heads, chunks and layers; from the
+    gated-delta-rule layers ``delta_chunk_log_decay_min``, the same of ``g``."""
     by_name = _sown_by_name(collection)
     reduce = {
         "rows_held": ("moe_rows_held", jnp.mean),
@@ -576,6 +670,7 @@ def aggregate_counters(collection: dict) -> dict:
         "rows_dropped": ("moe_rows_dropped", jnp.sum),
         "balance": ("moe_balance", jnp.mean),
         "ssm_chunk_log_decay_min": ("ssm_chunk_log_decay_min", jnp.min),
+        "delta_chunk_log_decay_min": ("delta_chunk_log_decay_min", jnp.min),
     }
     return {out: over(jnp.stack(by_name[name]))
             for name, (out, over) in reduce.items() if name in by_name}

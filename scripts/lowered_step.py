@@ -10,13 +10,18 @@ size, traced on abstract arguments (nothing is allocated or compiled) and
 lowered for the TPU platform with the choices the chip makes: the Pallas
 kernels where ``pallas_supported()`` takes them, compiled and not
 interpreted. Prints the text's length, its SHA-256 and the number of kernel
-calls. A kernel's body carries the paths and line numbers of the source
-files that call it, this script among them, so two trees compare only under
-one path and through one copy of this script: point a symbolic link at each
-in turn,
+calls. The text is lowered without source locations
+(``jax_traceback_in_locations_limit`` 0): a kernel's body would otherwise
+carry the path and line number of every frame that reached it, so that an
+edit which moves line numbers in ``models/transformer.py`` or
+``models/arch.py`` and nothing else changed six kernel bodies by a few bytes
+each (seen in PR 34). Run ONE copy of this script on both trees:
 
-    ln -sfn <parent's tree> /root/scratch/cur && python3 scripts/lowered_step.py /root/scratch/cur CELL a.txt
-    ln -sfn <this tree>     /root/scratch/cur && python3 scripts/lowered_step.py /root/scratch/cur CELL b.txt && cmp a.txt b.txt
+    python3 scripts/lowered_step.py <parent's tree> CELL a.txt
+    python3 scripts/lowered_step.py <this tree>     CELL b.txt && cmp a.txt b.txt
+
+A cell whose model is described by a configuration (any driver but
+``train:run``) is built as its driver builds it, with ``arch_of(config)``.
 
 A text that is the same says the program is; it is no chip run.
 """
@@ -35,6 +40,7 @@ def main(root: str, cell: str, out: str) -> int:
     import jax
     import jax.numpy as jnp
 
+    jax.config.update("jax_traceback_in_locations_limit", 0)
     import mpit_tpu
     from benchmark.drivers import train as train_driver
     from mpit_tpu import run as program
@@ -57,8 +63,8 @@ def main(root: str, cell: str, out: str) -> int:
     cfg, job, sizes = train_driver.build_config({
         "workload": load(f"benchmark/workloads/{cell}.json"),
         "config": config, "rehearsal": False, "chips": 1})
-    if cfg.arch is None and "arch" in config:
-        from benchmark.drivers import train_lm
+    if cfg.arch is None and job.get("driver", "train:run") != "train:run":
+        from benchmark.drivers import train_lm  # a described model's driver
 
         cfg = dataclasses.replace(cfg, arch=train_lm.arch_of(config))
     topo = mpit_tpu.init(num_workers=1)

@@ -232,3 +232,44 @@ def test_remat_mamba2_block_compiles_at_8k(
     kept = "ssd_out" in transformer._REMAT_KEEPS
     assert calls == {"ssd_fwd": 1 if kept else 2, "ssd_bwd": 1}
     assert compiled.memory_analysis().temp_size_in_bytes < 3 * 2 ** 30
+
+
+def test_remat_linear_attention_block_compiles_at_8k(
+        one_chip, no_compile_cache):
+    """The gradient of one remat'd gated-delta-rule layer at the published
+    widths (hidden 3,840, 30 heads with keys of 96 and values of 192, chunks
+    of 64, SwiGLU 11,008, the norms on the sublayers' outputs) and 8,192
+    tokens, as ``olmo_hybrid_sync_1chip_8k`` has three of them, for the
+    described chip: plain ``jax.numpy``, no kernel of its own. Scratch under
+    3 GiB beside the layer's gradient: the chunked form is taken in segments
+    under a ``jax.checkpoint`` (``ops/gated_delta.SEGMENT``), without which
+    one layer held 5.8 GB of chunk matrices and float32 projections."""
+    from mpit_tpu.models import transformer
+
+    t = 8192
+    arch = {
+        "norm_at": "output", "num_hidden_layers": 1,
+        "layer_types": ["linear_attention"], "hidden_size": 3840,
+        "intermediate_size": 11008, "num_attention_heads": 30,
+        "num_key_value_heads": 30, "linear_num_key_heads": 30,
+        "linear_num_value_heads": 30, "linear_key_head_dim": 96,
+        "linear_value_head_dim": 192, "linear_conv_kernel_dim": 4,
+        "linear_allow_neg_eigval": True,
+        "rope_parameters": {"rope_theta": None},
+    }
+    model = transformer.TransformerLM(vocab_size=256, arch=arch, remat=True)
+    on_chip = lambda s: jax.ShapeDtypeStruct(
+        s.shape, s.dtype, sharding=one_chip)
+    params = jax.tree.map(on_chip, jax.eval_shape(
+        lambda: model.init(jax.random.key(0),
+                           jnp.zeros((1, 16), jnp.int32))["params"]))
+    assert params["Block_0"]["lin_v"].shape == (3840, 5760)
+    assert params["Block_0"]["conv_k"].shape == (2880, 4)
+    tokens = on_chip(jax.ShapeDtypeStruct((1, t), jnp.int32))
+    compiled = jax.jit(jax.grad(lambda p, x: model.loss_with_counters(
+        p, x, x)[0])).lower(params, tokens).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" not in text  # a Pallas kernel is a later PR's
+    for scope in ("linattn", "linattn_conv", "delta_rule", "linattn_gate"):
+        assert f"/{scope}/" in text, scope
+    assert compiled.memory_analysis().temp_size_in_bytes < 3 * 2 ** 30
