@@ -31,6 +31,7 @@ from mpit_tpu.models.arch import (
 )
 from mpit_tpu.ops.ring_attention import dense_attention, ring_attention
 from mpit_tpu.ops.ulysses import ulysses_attention
+from mpit_tpu.utils import profiling
 
 
 def rms_norm(x, scale, eps: float):
@@ -112,8 +113,8 @@ class Block(nn.Module):
     def __call__(self, x):
         if self.spec is not None:
             return self._described(x)
-        # jax.named_scope names the device's time by layer part (metadata
-        # only; forward and backward both carry it): "attention" is the
+        # profiling.scope names the device's time by layer part (metadata
+        # only; forward, recomputation and backward all carry it): "attention" is the
         # attention arithmetic alone, "attn_proj" the projections around
         # it with their LayerNorm and residual, "mlp" the feed-forward
         dt = self.compute_dtype
@@ -127,13 +128,13 @@ class Block(nn.Module):
                 "decode mode is single-device dense-FFN only "
                 "(seq_axis=None, moe_experts=0)"
             )
-        with jax.named_scope("attn_proj"):
+        with profiling.scope("attn_proj"):
             y = nn.LayerNorm(dtype=dt)(x)
             qkv = nn.Dense(3 * self.d_model, use_bias=False, dtype=dt)(y)
             q, k, v = jnp.split(qkv, 3, axis=-1)
             split = lambda a: a.reshape(*a.shape[:2], h, d)
             q, k, v = split(q), split(k), split(v)
-        with jax.named_scope("attention"):
+        with profiling.scope("attention"):
             if self.decode:
                 att = self._cached_attention(q, k, v)
             elif self.seq_axis is not None and self.seq_impl == "ulysses":
@@ -150,10 +151,10 @@ class Block(nn.Module):
                 )
             else:
                 att = dense_attention(q, k, v, causal=True)
-        with jax.named_scope("attn_proj"):
+        with profiling.scope("attn_proj"):
             att = att.reshape(*att.shape[:2], self.d_model)
             x = x + nn.Dense(self.d_model, use_bias=False, dtype=dt)(att)
-        with jax.named_scope("mlp"):
+        with profiling.scope("mlp"):
             y = nn.LayerNorm(dtype=dt)(x)
             if self.moe_experts:
                 x = x + self._moe(y)
@@ -224,14 +225,14 @@ class Block(nn.Module):
         b, t, d = x.shape
         h, h_kv, hd = spec.num_heads, spec.num_kv_heads, spec.head_dim
         weight, proj = self._weight, self._proj
-        with jax.named_scope("attn_proj"):
+        with profiling.scope("attn_proj"):
             y = self._mixer_input(x, "attn_norm")
             q = proj(y, weight("wq", d, h * hd)).astype(dt)
             k = proj(y, weight("wk", d, h_kv * hd)).astype(dt)
             v = proj(y, weight("wv", d, h_kv * hd)).astype(dt)
             if spec.qk_norm:
                 # over the whole width, before the split into heads
-                with jax.named_scope("qk_norm"):
+                with profiling.scope("qk_norm"):
                     q = rms_norm(q, self._norm_scale("q_norm", h * hd),
                                  spec.norm_eps).astype(dt)
                     k = rms_norm(k, self._norm_scale("k_norm", h_kv * hd),
@@ -239,13 +240,13 @@ class Block(nn.Module):
             q = q.reshape(b, t, h, hd)
             k, v = (a.reshape(b, t, h_kv, hd) for a in (k, v))
             if spec.rope is not None:
-                with jax.named_scope("rope"):
+                with profiling.scope("rope"):
                     cos, sin = rope_tables(spec.rope, t, hd)
                     swap = rotate_half_matrix(spec.rope, hd)
                     q, k = apply_rope(q, cos, sin, swap), apply_rope(
                         k, cos, sin, swap)
-        with jax.named_scope("attention"):
-            with jax.named_scope(
+        with profiling.scope("attention"):
+            with profiling.scope(
                 "attn_full" if spec.window is None else "attn_window"
             ):
                 att = flash_attention(
@@ -253,11 +254,11 @@ class Block(nn.Module):
                     use_pallas={"xla": False, "flash": None,
                                 "flash_force": True}[self.attn_impl],
                 )
-        with jax.named_scope("attn_proj"):
+        with profiling.scope("attn_proj"):
             if spec.gate:
                 # head-wise sigmoid gate from the normed input, on the
                 # attention output before the output projection
-                with jax.named_scope("attn_gate"):
+                with profiling.scope("attn_gate"):
                     gate = jax.nn.sigmoid(proj(y, weight("wg", d, h)))
                     att = (att * gate[..., None]).astype(dt)
             return self._residual(x, proj(
@@ -269,7 +270,7 @@ class Block(nn.Module):
 
         spec, dt = self.spec, self.compute_dtype
         b, t, d = x.shape
-        with jax.named_scope("mlp"):
+        with profiling.scope("mlp"):
             y = self._mixer_input(x, "ffn_norm")
             if spec.moe is None:
                 return self._residual(x, swiglu(
@@ -299,7 +300,7 @@ class Block(nn.Module):
             ssm.dt_min, ssm.dt_max, ssm.dt_floor)
         per_head = lambda name, init: self.param(
             name, init, (ssm.heads,), f32)
-        with jax.named_scope("ssm"):
+        with profiling.scope("ssm"):
             u = rms_norm(x, self._norm_scale("ssm_norm"),
                          spec.norm_eps).astype(dt)
             zxbcdt = self._proj(u, self._weight(
@@ -309,7 +310,7 @@ class Block(nn.Module):
             step = jax.nn.softplus(
                 zxbcdt[..., inner + ssm.conv_dim:]
                 + per_head("dt_bias", dt_bias_init))
-            with jax.named_scope("ssm_conv"):
+            with profiling.scope("ssm_conv"):
                 conv_w = self.param(
                     "conv_w", nn.initializers.lecun_normal(in_axis=-1,
                                                            out_axis=-2),
@@ -322,7 +323,7 @@ class Block(nn.Module):
             # input projection nor the convolution
             z, xbc, step = (checkpoint_name(a, "ssm_in")
                             for a in (z, xbc, step))
-            with jax.named_scope("ssd"):
+            with profiling.scope("ssd"):
                 y, log_decay_min = ssd_ops.ssd(
                     xbc[..., :inner].reshape(b, t, ssm.heads, ssm.head_dim),
                     step,
@@ -337,7 +338,7 @@ class Block(nn.Module):
                     chunk=ssm.chunk,
                 )
             self.sow("counters", "ssm_chunk_log_decay_min", log_decay_min)
-            with jax.named_scope("ssm_gate"):
+            with profiling.scope("ssm_gate"):
                 gated = (y.reshape(b, t, inner).astype(f32)
                          * jax.nn.silu(z.astype(f32)))
                 grouped = gated.reshape(b, t, ssm.groups, inner // ssm.groups)
@@ -367,20 +368,20 @@ class Block(nn.Module):
         h, dk, dv = lin.heads, lin.key_dim, lin.value_dim
         conv_init = nn.initializers.lecun_normal(in_axis=-1, out_axis=-2)
         per_head = lambda name, init: self.param(name, init, (h,), f32)
-        with jax.named_scope("linattn"):
+        with profiling.scope("linattn"):
             u = self._mixer_input(x, "linattn_norm")
             proj = lambda name, width: self._proj(
                 u, self._weight(name, d, width))
             widths = (("q", h * dk), ("k", h * dk), ("v", h * dv))
             qkv = [proj(f"lin_{name}", width).astype(dt)
                    for name, width in widths]
-            with jax.named_scope("linattn_conv"):
+            with profiling.scope("linattn_conv"):
                 q, k, v = (
                     causal_conv_silu(a, self.param(
                         f"conv_{name}", conv_init, (width, lin.conv_kernel),
                         f32)).astype(dt)
                     for a, (name, width) in zip(qkv, widths))
-            with jax.named_scope("delta_rule"):
+            with profiling.scope("delta_rule"):
                 unit = lambda a: a * jax.lax.rsqrt(
                     jnp.sum(jnp.square(a), axis=-1, keepdims=True) + 1e-6)
                 q, k = (a.reshape(b, t, h, dk).astype(f32) for a in (q, k))
@@ -400,7 +401,7 @@ class Block(nn.Module):
                     chunk=lin.chunk)
             self.sow("counters", "delta_chunk_log_decay_min", log_decay_min)
             gate = proj("lin_gate", h * dv).astype(dt)
-            with jax.named_scope("linattn_gate"):
+            with profiling.scope("linattn_gate"):
                 o = (rms_norm(o, self._norm_scale("gate_norm", dv),
                               spec.norm_eps).reshape(b, t, h * dv)
                      * jax.nn.silu(gate.astype(f32))).astype(dt)
@@ -447,7 +448,7 @@ class Block(nn.Module):
         # the same discrete choices (mutable=["routing"])
         self.sow("routing", "experts", experts)
         if moe.shared_width:
-            with jax.named_scope("moe_shared"):
+            with profiling.scope("moe_shared"):
                 out = out + moe_ops.dense_expert(moe.expert, y2, *(
                     self._weight(f"shared_{name}",
                                  *shape(name, moe.shared_width))
@@ -789,7 +790,8 @@ class TransformerLM(nn.Module):
         specs = layer_specs(arch)
         d = specs[0].d_model
         embed = nn.Embed(self.vocab_size, d, dtype=dt, name="Embed_0")
-        x = embed(tokens)
+        with profiling.scope("embed"):
+            x = embed(tokens)
         block_cls = _RematBlock if self.remat else Block
         for i, spec in enumerate(specs):
             x = block_cls(
@@ -805,7 +807,7 @@ class TransformerLM(nn.Module):
         if not self.head:
             return x
         hdt = self._head_operand_dtype
-        with jax.named_scope("head"):
+        with profiling.scope("head"):
             if arch.get("tie_word_embeddings", False):
                 table = embed.embedding
             else:
@@ -872,7 +874,8 @@ class TransformerLM(nn.Module):
         # scalar offset -> (t,) positions; per-row decode offset (B,) ->
         # (B, t) positions — the table gather broadcasts either way
         pos = jnp.asarray(offset)[..., None] + jnp.arange(t_local)
-        x = embed(tokens) + pos_table[pos].astype(dt)
+        with profiling.scope("embed"):
+            x = embed(tokens) + pos_table[pos].astype(dt)
         block_cls = _RematBlock if self.remat else Block
         for i in range(self.num_layers):
             x = block_cls(
@@ -906,7 +909,7 @@ class TransformerLM(nn.Module):
         # models (the equivalence-test configuration) this is bit-
         # identical to the previous all-f32 head.
         hdt = self._head_operand_dtype
-        with jax.named_scope("head"):
+        with profiling.scope("head"):
             table = embed.embedding.astype(hdt)
             return jnp.einsum(
                 "btd,vd->btv", x.astype(hdt), table,
@@ -920,7 +923,7 @@ class TransformerLM(nn.Module):
         and kept only the rows they need (chunked prefill). The embed
         table's param path is pinned by a test against a full forward."""
         hdt = self._head_operand_dtype
-        with jax.named_scope("head"):
+        with profiling.scope("head"):
             table = params["Embed_0"]["embedding"].astype(hdt)
             return jnp.einsum(
                 "bd,vd->bv", h.astype(hdt), table,

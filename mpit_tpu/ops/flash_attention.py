@@ -57,7 +57,7 @@ own TPU kernel does under ``residual_checkpoint_name``: a caller's
 then keeps them, the recomputed forward call is dead code and so is what
 only fed it. Outside a ``jax.checkpoint`` a name lowers to nothing.
 
-In a trace the three kernels run under ``jax.named_scope``s
+In a trace the three kernels run under the ``profiling.scope``s
 ``flash_fwd``, ``flash_dq`` and ``flash_dkv``, and the transposes into
 and out of the kernels' layout with the ``D`` reduction under
 ``flash_layout``.
@@ -83,6 +83,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from mpit_tpu.ops.elastic import pallas_interpret, pallas_supported
 from mpit_tpu.ops.ring_attention import dense_attention
+from mpit_tpu.utils import profiling
 
 _NEG_INF = float("-inf")
 _LANE = 128
@@ -484,7 +485,7 @@ def _fwd_call(q2, k2, v2, causal, block_q, block_k, interpret, window=None):
     q_spec, kv_spec = _k_inner_specs(d, causal, block_q, block_k, window, group)
     n_k = _k_steps(t, block_q, block_k, window)
     scope = "flash_fwd" if window is None else "flash_window_fwd"
-    with jax.named_scope(scope):
+    with profiling.scope(scope):
         return pl.pallas_call(
             functools.partial(
                 _kernel, scale=1.0 / (d ** 0.5), causal=causal,
@@ -524,7 +525,7 @@ def _dq_call(q2, k2, v2, do2, lse, dd, causal, block_q, block_k, interpret,
     # per-row residuals as one lane-major row for the q-major kernel
     row_spec = pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i))
     scope = "flash_dq" if window is None else "flash_window_dq"
-    with jax.named_scope(scope):
+    with profiling.scope(scope):
         return pl.pallas_call(
             functools.partial(
                 _dq_kernel, scale=1.0 / (d ** 0.5), causal=causal,
@@ -559,7 +560,7 @@ def _dkv_call(q2, k2, v2, do2, lse, dd, causal, block_q, block_k, interpret,
         (1, _SUBLANE, block_q), lambda b, j, s: (head(b, s), 0, inner(j, s))
     )
     scope = "flash_dkv" if window is None else "flash_window_dkv"
-    with jax.named_scope(scope):
+    with profiling.scope(scope):
         return pl.pallas_call(
             functools.partial(
                 _dkv_kernel, scale=1.0 / (d ** 0.5), causal=causal,
@@ -588,7 +589,7 @@ def _dkv_call(q2, k2, v2, do2, lse, dd, causal, block_q, block_k, interpret,
 def _flash_pallas_bwd(q, k, v, out, lse, ct, causal, blocks, interpret,
                       window=None):
     b, t, h, d = q.shape
-    with jax.named_scope("flash_layout"):
+    with profiling.scope("flash_layout"):
         q2, k2, v2, do2, o2 = (_to2d(a) for a in (q, k, v, ct, out))
         # D_i = Σ_d dO_id · O_id — cheap elementwise+reduce, XLA's job
         dd = jnp.sum(
@@ -600,7 +601,7 @@ def _flash_pallas_bwd(q, k, v, out, lse, ct, causal, blocks, interpret,
     dk, dv = _dkv_call(
         q2, k2, v2, do2, lse, dd, causal, *dkv_blocks, interpret, window
     )
-    with jax.named_scope("flash_layout"):
+    with profiling.scope("flash_layout"):
         return (_from2d(dq, b, h, t, d),
                 *(_from2d(a, b, k.shape[2], t, d) for a in (dk, dv)))
 
@@ -643,10 +644,10 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 )
 def _flash_pallas(q, k, v, causal, blocks, interpret, window=None):
     b, t, h, d = q.shape
-    with jax.named_scope("flash_layout"):
+    with profiling.scope("flash_layout"):
         q2, k2, v2 = _to2d(q), _to2d(k), _to2d(v)
     out, lse = _fwd_call(q2, k2, v2, causal, *blocks[0], interpret, window)
-    with jax.named_scope("flash_layout"):
+    with profiling.scope("flash_layout"):
         return _from2d(out, b, h, t, d), lse[..., 0]
 
 

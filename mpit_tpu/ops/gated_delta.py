@@ -107,6 +107,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from mpit_tpu.ops.elastic import pallas_interpret, pallas_supported
+from mpit_tpu.utils import profiling
 
 _HIGHEST = lax.Precision.HIGHEST
 
@@ -553,7 +554,7 @@ def _fwd_call(q, k, v, gamma, beta, interpret):
     dv, (nc, c) = v.shape[-1], (gamma.shape[1], gamma.shape[-1])
     g = _heads_a_step(h)
     keys, values, rows, states = _specs(g, c, dk, dv, lambda s: s)
-    with jax.named_scope("delta_fwd"):
+    with profiling.scope("delta_fwd"):
         return pl.pallas_call(
             _fwd_kernel,
             grid=(bsz, h // g, nc),
@@ -576,7 +577,7 @@ def _bwd_call(q, k, v, gamma, beta, entering, do, interpret):
     g = _heads_a_step(h)
     # the chunks in reverse, dS carried in scratch
     keys, values, rows, states = _specs(g, c, dk, dv, lambda s: nc - 1 - s)
-    with jax.named_scope("delta_bwd"):
+    with profiling.scope("delta_bwd"):
         return pl.pallas_call(
             _bwd_kernel,
             grid=(bsz, h // g, nc),

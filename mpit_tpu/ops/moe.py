@@ -41,6 +41,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from mpit_tpu.utils import profiling
+
 
 def init_moe_params(rng, d_model: int, d_ff: int, num_experts: int) -> dict:
     """Router + stacked expert FFN weights (E on the leading axis —
@@ -319,11 +321,11 @@ def _expert_rows(expert, rows, weights, row_weight, ends, start):
     where each expert's rows end in the whole buffer), times ``row_weight``,
     in f32. The weights come in ``rows``' dtype."""
     n, dt = rows.shape[0], rows.dtype
-    with jax.named_scope("moe_dispatch"):
+    with profiling.scope("moe_dispatch"):
         span = jnp.clip(ends - start, 0, n)
         sizes = jnp.diff(span, prepend=0).astype(jnp.int32)
         valid = jnp.arange(n) < span[-1]
-    with jax.named_scope("moe_experts"):
+    with profiling.scope("moe_experts"):
         # a grouped product leaves the rows past its groups as they were
         # in memory (seen on the v5e, PR 27: the gradient into such rows
         # came back as garbage 1e5 times the true one), so every operand
@@ -333,17 +335,17 @@ def _expert_rows(expert, rows, weights, row_weight, ends, start):
             live(a), w, sizes, preferred_element_type=jnp.float32
         ).astype(dt))
         out_rows = EXPERTS[expert][1](grouped, rows, *weights)
-    with jax.named_scope("moe_dispatch"):
+    with profiling.scope("moe_dispatch"):
         return out_rows.astype(jnp.float32) * row_weight[:, None]
 
 
 def _add_rows(expert, out, y, weights, token, row_weight, ends, start):
     """``out`` with the experts' weighted outputs for the buffer's rows
     ``start .. start + len(token)`` added at their tokens."""
-    with jax.named_scope("moe_dispatch"):
+    with profiling.scope("moe_dispatch"):
         rows = jnp.take(y, token, axis=0)
     add = _expert_rows(expert, rows, weights, row_weight, ends, start)
-    with jax.named_scope("moe_dispatch"):
+    with profiling.scope("moe_dispatch"):
         return out.at[token].add(add)
 
 
@@ -377,7 +379,7 @@ def _walk_fwd(chunk, expert, y, weights, row_weight, token, ends):
         start, tok, weight = _chunk_of(chunk, j, token, row_weight)
         return _add_rows(expert, out, y, weights, tok, weight, ends, start)
 
-    with jax.named_scope("moe_dispatch"):
+    with profiling.scope("moe_dispatch"):
         out = lax.fori_loop(0, _live_chunks(chunk, ends), body,
                             jnp.zeros(y.shape, jnp.float32)).astype(y.dtype)
     return out, (y, weights, row_weight, token, ends)
@@ -389,7 +391,7 @@ def _walk_bwd(chunk, expert, residuals, ct):
     def body(j, carry):
         d_y, d_weights, d_row_weight = carry
         start, tok, weight = _chunk_of(chunk, j, token, row_weight)
-        with jax.named_scope("moe_dispatch"):
+        with profiling.scope("moe_dispatch"):
             rows = jnp.take(y, tok, axis=0)
             ct_rows = jnp.take(ct, tok, axis=0).astype(jnp.float32)
         _, vjp = jax.vjp(
@@ -397,15 +399,15 @@ def _walk_bwd(chunk, expert, residuals, ct):
                 expert, rows, weights, weight, ends, start),
             rows, weights, weight)
         d_rows, d_chunk, d_weight = vjp(ct_rows)
-        with jax.named_scope("moe_dispatch"):
+        with profiling.scope("moe_dispatch"):
             d_y = d_y.at[tok].add(d_rows)
             d_row_weight = lax.dynamic_update_slice_in_dim(
                 d_row_weight, d_weight, start, 0)
-        with jax.named_scope("moe_experts"):
+        with profiling.scope("moe_experts"):
             d_weights = [a + d for a, d in zip(d_weights, d_chunk)]
         return d_y, d_weights, d_row_weight
 
-    with jax.named_scope("moe_dispatch"):
+    with profiling.scope("moe_dispatch"):
         d_y, d_weights, d_row_weight = lax.fori_loop(
             0, _live_chunks(chunk, ends), body,
             (jnp.zeros_like(y), [jnp.zeros_like(w) for w in weights],
@@ -473,7 +475,7 @@ def moe_ffn_held(
     routed = params["router"].shape[1]
     row_bound = min(row_bound, tokens * top_k)  # there are no more pairs
     chunk = chunk_rows(tokens, top_k, held, routed)
-    with jax.named_scope("moe_router"):
+    with profiling.scope("moe_router"):
         weights, experts, scores = route_top_k(
             y, params["router"], top_k, scale, params.get("bias"))
         if not routing_grad:
@@ -483,7 +485,7 @@ def moe_ffn_held(
         # routing); its gradient passes through P alone
         share = jnp.bincount(experts.reshape(-1), length=routed) / tokens
         balance = routed * jnp.sum(share * scores.mean(0))
-    with jax.named_scope("moe_dispatch"):
+    with profiling.scope("moe_dispatch"):
         local = experts.reshape(-1) - expert_offset  # pair p = token·k + c
         here = (local >= 0) & (local < held)
         key = jnp.where(here, local, held)  # the others sort to the end
@@ -495,7 +497,7 @@ def moe_ffn_held(
         row_weight = jnp.where(
             jnp.arange(row_bound) < ends[-1],
             jnp.take(weights.reshape(-1), order), 0.0)
-    with jax.named_scope("moe_experts"):  # cast once, outside any loop
+    with profiling.scope("moe_experts"):  # cast once, outside any loop
         experts_w = [params[n].astype(y.dtype) for n in names]
         # the grouped kernel wants an expert width of whole 512s: at 1,856
         # a remat'd layer took 25.1 ms on the v5e, at 1,920 24.9 and at
@@ -514,13 +516,13 @@ def moe_ffn_held(
                         experts_w, token, row_weight, ends, 0).astype(y.dtype)
         rows_walked = jnp.float32(row_bound)
     else:
-        with jax.named_scope("moe_dispatch"):
+        with profiling.scope("moe_dispatch"):
             # whole chunks; what lies past the bound stays past ``ends``
             pad = (0, -row_bound % chunk)
             token, row_weight = jnp.pad(token, pad), jnp.pad(row_weight, pad)
         out = _walk(chunk, expert, y, experts_w, row_weight, token, ends)
         rows_walked = (_live_chunks(chunk, ends) * chunk).astype(jnp.float32)
-    with jax.named_scope("moe_dispatch"):
+    with profiling.scope("moe_dispatch"):
         total = counts.sum().astype(jnp.float32)
         counters = {
             "rows_held": total,

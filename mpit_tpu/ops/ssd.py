@@ -63,6 +63,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from mpit_tpu.ops.elastic import pallas_interpret, pallas_supported
+from mpit_tpu.utils import profiling
 
 _LANE = 128
 _NEG_INF = float("-inf")
@@ -395,7 +396,7 @@ def _fwd_call(x, dt, cumc, cumr, b, c, d, r, interpret):
     bsz, nc, g, q, _ = dt.shape
     p, n = x.shape[2] // (g * r), b.shape[2] // g
     chan, col, row, bc, dd, states = _specs(q, r, p, n, lambda s: s)
-    with jax.named_scope("ssd_fwd"):
+    with profiling.scope("ssd_fwd"):
         return pl.pallas_call(
             functools.partial(_fwd_kernel, r=r, p=p),
             grid=(bsz, g, nc),
@@ -419,7 +420,7 @@ def _bwd_call(x, dt, cumc, cumr, b, c, d, entering, dy, r, interpret):
     chan, col, row, bc, dd, states = _specs(
         q, r, p, n, lambda s: nc - 1 - s)
     f32 = jnp.float32
-    with jax.named_scope("ssd_bwd"):
+    with profiling.scope("ssd_bwd"):
         return pl.pallas_call(
             functools.partial(_bwd_kernel, r=r, p=p),
             grid=(bsz, g, nc),

@@ -11,7 +11,7 @@ import numpy as np
 import optax
 
 from mpit_tpu.data.prefetch import prefetch_to_device
-from mpit_tpu.utils.profiling import remember_unit, span
+from mpit_tpu.utils.profiling import remember_unit, scope, span
 
 
 @flax.struct.dataclass
@@ -38,7 +38,7 @@ class TrainState:
 
 
 def cross_entropy_loss(logits: jax.Array, labels: jax.Array) -> jax.Array:
-    with jax.named_scope("loss"):
+    with scope("loss"):
         return optax.softmax_cross_entropy_with_integer_labels(
             logits, labels
         ).mean()

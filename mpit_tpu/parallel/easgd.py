@@ -33,7 +33,7 @@ from mpit_tpu.comm.topology import topology as _current_topology
 from mpit_tpu import goptim
 from mpit_tpu.comm.topology import Topology
 from mpit_tpu.parallel import common
-from mpit_tpu.utils.profiling import span
+from mpit_tpu.utils.profiling import scope, span
 
 
 @flax.struct.dataclass
@@ -114,7 +114,7 @@ class EASGDTrainer(common.RoundTrainer):
                 p, o = carry
                 bx, by = batch
                 loss, g = jax.value_and_grad(self.loss_fn)(p, bx, by)
-                with jax.named_scope("optimizer"):
+                with scope("optimizer"):
                     updates, o = self.optimizer.update(g, o, p)
                     p = optax.apply_updates(p, updates)
                 return (p, o), loss
@@ -122,7 +122,7 @@ class EASGDTrainer(common.RoundTrainer):
             (params, opt), losses = jax.lax.scan(
                 local_step, (params, opt), (x[0], y[0])
             )
-            with jax.named_scope("elastic"):
+            with scope("elastic"):
                 params, center = goptim.easgd_round(
                     params, state.center, self.alpha, axis,
                     use_pallas=self.use_pallas,

@@ -23,7 +23,7 @@ from jax.sharding import PartitionSpec as P
 from mpit_tpu.comm.topology import topology as _current_topology
 from mpit_tpu.comm.topology import Topology
 from mpit_tpu.parallel import common
-from mpit_tpu.utils.profiling import span
+from mpit_tpu.utils.profiling import scope, span
 
 
 class DataParallelTrainer:
@@ -93,11 +93,11 @@ class DataParallelTrainer:
             if counting is not None:
                 loss, counters = loss
             # the one collective of the step: grad average over workers
-            with jax.named_scope("grad_exchange"):
+            with scope("grad_exchange"):
                 grads = jax.lax.pmean(grads, axis)
                 loss = jax.lax.pmean(loss, axis)
                 counters = jax.lax.pmean(counters, axis)
-            with jax.named_scope("optimizer"):
+            with scope("optimizer"):
                 updates, opt_state = self.optimizer.update(
                     grads, state.opt_state, state.params
                 )
