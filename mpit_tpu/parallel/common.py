@@ -37,6 +37,20 @@ class TrainState:
         )
 
 
+def placed_state(make: Callable, shardings: Any, *args) -> Any:
+    """``make(*args)`` as ONE jitted program whose outputs are born with
+    ``shardings`` (a prefix of their tree), waited for, so that the caller's
+    span reads set-up done, not dispatched. Made op by op instead, a
+    model's ``init`` runs its forward one primitive at a time: dozens of
+    sub-second programs that jax's persistent cache never keeps (its floor
+    is 1 s), recompiled by every run. Every leaf comes out its own buffer,
+    an argument returned unchanged included, so a program that donates
+    the state may take it whole."""
+    return jax.block_until_ready(
+        jax.jit(make, out_shardings=shardings)(*args)
+    )
+
+
 def cross_entropy_loss(logits: jax.Array, labels: jax.Array) -> jax.Array:
     with scope("loss"):
         return optax.softmax_cross_entropy_with_integer_labels(

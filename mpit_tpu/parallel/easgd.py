@@ -163,32 +163,38 @@ class EASGDTrainer(common.RoundTrainer):
     def init_state(self, rng, sample_x=None, params: Any = None) -> EASGDState:
         """All workers and the center start from identical params (the
         reference broadcast the initial model the same way, via rank-0
-        construction + bcast)."""
+        construction + bcast). One jitted program makes the whole state,
+        each leaf born with its sharding; given ``params``, it takes them
+        as its argument and ``model.init`` is skipped."""
         with span("mpit.setup.init_state"):
-            if params is None:
-                params = self.model.init(rng, jnp.asarray(sample_x))["params"]
             w = self.topo.num_workers
-            state = EASGDState(
-                worker_params=_stack(params, w),
-                worker_opt=_stack(self.optimizer.init(params), w),
-                center=params,
-                round=jnp.zeros((), jnp.int32),
-            )
+
+            def make(params):
+                return EASGDState(
+                    worker_params=_stack(params, w),
+                    worker_opt=_stack(self.optimizer.init(params), w),
+                    center=params,
+                    round=jnp.zeros((), jnp.int32),
+                )
+
             shardings = EASGDState(
-                worker_params=jax.tree.map(
-                    lambda _: self.topo.worker_sharding(),
-                    state.worker_params,
-                ),
-                worker_opt=jax.tree.map(
-                    lambda _: self.topo.worker_sharding(), state.worker_opt
-                ),
-                center=jax.tree.map(
-                    lambda _: self.topo.replicated_sharding(), state.center
-                ),
+                worker_params=self.topo.worker_sharding(),
+                worker_opt=self.topo.worker_sharding(),
+                center=self.topo.replicated_sharding(),
                 round=self.topo.replicated_sharding(),
             )
-            # waited for, so that the span reads set-up done, not dispatched
-            return jax.block_until_ready(jax.device_put(state, shardings))
+            if params is not None:
+                return common.placed_state(make, shardings, params)
+            # the barrier draws each leaf once, into one buffer that the
+            # center and the stacked workers read: left to fuse, XLA draws
+            # GPT-2's two embedding tables twice (the workers' copy in
+            # another layout) and the TPU compile takes twice as long
+            return common.placed_state(
+                lambda key, x: make(jax.lax.optimization_barrier(
+                    self.model.init(key, x)["params"]
+                )),
+                shardings, rng, jnp.asarray(sample_x),
+            )
 
     def center_params(self, state: EASGDState):
         return state.center

@@ -130,9 +130,10 @@ class DataParallelTrainer:
                 self.model.init(key, x)["params"], self.optimizer
             )
             if self.jit_init:
-                return jax.block_until_ready(jax.jit(
-                    create, out_shardings=self.topo.replicated_sharding()
-                )(rng, jnp.asarray(sample_x)))
+                return common.placed_state(
+                    create, self.topo.replicated_sharding(),
+                    rng, jnp.asarray(sample_x),
+                )
             state = create(rng, jnp.asarray(sample_x))
             # waited for, so that the span reads set-up done, not dispatched
             return jax.block_until_ready(

@@ -177,6 +177,164 @@ def test_jitted_init_state_equals_eager(topo8, model, sample, rtol):
         )
 
 
+def _tiny_lm():
+    from mpit_tpu.models.transformer import TransformerLM
+
+    # widths no other test uses, so that no earlier program is cached
+    lm = TransformerLM(
+        vocab_size=29, num_layers=2, d_model=24, num_heads=2, max_len=12
+    )
+    return lm, np.random.default_rng(1).integers(0, 29, (2, 12)).astype(
+        np.int32
+    )
+
+
+def _own_buffers(tree) -> bool:
+    ptrs = [
+        s.data.unsafe_buffer_pointer()
+        for a in jax.tree.leaves(tree)
+        for s in a.addressable_shards
+    ]
+    return len(set(ptrs)) == len(ptrs)
+
+
+@pytest.fixture(params=[1, 8], ids=["w1", "w8"])
+def workers(request):
+    return mpit_tpu.init(num_workers=request.param)
+
+
+@pytest.mark.parametrize("model,sample,rtol", _init_cases())
+def test_easgd_init_state_is_the_eager_construction(
+    workers, model, sample, rtol
+):
+    """EASGD makes its state in one jitted program: what an eager
+    construction makes (``model.init``, ``optimizer.init``, a broadcast
+    over workers), each leaf born with its sharding and its own buffer
+    (the round donates them all)."""
+    from mpit_tpu.parallel import EASGDTrainer
+    from mpit_tpu.parallel.easgd import EASGDState
+
+    opt, w = optax.adamw(1e-3), workers.num_workers
+    state = EASGDTrainer(model, opt, workers).init_state(
+        jax.random.key(3), sample
+    )
+    params = model.init(jax.random.key(3), jnp.asarray(sample))["params"]
+    stack = lambda t: jax.tree.map(
+        lambda a: np.broadcast_to(a, (w, *a.shape)), t
+    )
+    eager = EASGDState(
+        worker_params=stack(params), worker_opt=stack(opt.init(params)),
+        center=params, round=np.zeros((), np.int32),
+    )
+    assert jax.tree.structure(state) == jax.tree.structure(eager)
+    for part in ("worker_params", "worker_opt", "center", "round"):
+        made, want = getattr(state, part), getattr(eager, part)
+        for a, b in zip(jax.tree.leaves(made), jax.tree.leaves(want)):
+            assert a.dtype == b.dtype and a.shape == b.shape
+            np.testing.assert_allclose(
+                np.asarray(a), np.asarray(b), rtol=rtol, atol=0
+            )
+            if part.startswith("worker"):
+                assert a.sharding.is_equivalent_to(
+                    workers.worker_sharding(), a.ndim
+                )
+            else:
+                assert a.sharding.is_fully_replicated
+    assert _own_buffers(state)
+
+
+def test_easgd_init_state_takes_given_params(workers):
+    """With ``params`` and no model, the program takes them as its
+    argument; the round's first call donates the state, the center that
+    holds the argument's values and AdamW's two zero moments included,
+    and leaves the caller's ``params`` alive."""
+    from mpit_tpu.parallel import EASGDTrainer
+
+    w = workers.num_workers
+    tr = EASGDTrainer(
+        model=None, optimizer=optax.adamw(0.1), topo=workers,
+        loss_fn=lambda p, x, y: jnp.sum((p["p"] - x[0]) ** 2), tau=2,
+    )
+    params = {"p": jnp.arange(2.0)}
+    state = tr.init_state(None, params=params)
+    np.testing.assert_array_equal(state.center["p"], [0.0, 1.0])
+    np.testing.assert_array_equal(
+        state.worker_params["p"], np.tile([0.0, 1.0], (w, 1))
+    )
+    assert _own_buffers((state, params))
+    state, metrics = tr.step(
+        state, np.ones((2, w, 2), np.float32), np.zeros((2, w), np.float32)
+    )
+    assert np.isfinite(float(metrics["loss"])) and int(state.round) == 1
+    np.testing.assert_array_equal(params["p"], [0.0, 1.0])
+
+
+def test_easgd_init_state_compiles_one_program(topo8):
+    """The engagement counter: ``init_state`` compiles the state's one
+    program, not the dozens of an op-by-op ``model.init`` (the
+    ``backend_compile_duration`` events ``benchmark/lib/timing.py`` sums
+    into ``compile_s``). The eager construction beside it is the control
+    that the counter sees them."""
+    import jax.monitoring as mon
+
+    from mpit_tpu.parallel import EASGDTrainer
+
+    lm, tokens = _tiny_lm()
+    opt = optax.adamw(1e-3)
+    tr = EASGDTrainer(lm, opt, topo8)
+    key = jax.random.key(5)
+    seen = []
+
+    def on_duration(event, seconds, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            seen.append(seconds)
+
+    def programs(fn) -> int:
+        seen.clear()
+        mon.register_event_duration_secs_listener(on_duration)
+        try:
+            jax.block_until_ready(fn())
+        finally:
+            mon.unregister_event_duration_listener(on_duration)
+        return len(seen)
+
+    jitted = programs(lambda: tr.init_state(key, tokens))
+    eager = programs(lambda: opt.init(lm.init(key, tokens)["params"]))
+    assert 1 <= jitted <= 3 < eager, (jitted, eager)
+
+
+def test_sync_jitted_init_lowers_as_before(topo8, monkeypatch):
+    """The sync trainer's ``jit_init`` goes through
+    ``common.placed_state``: the program it lowers is the one PR 27's
+    inline ``jax.jit(create, out_shardings=replicated)`` lowered."""
+    from mpit_tpu.parallel import common
+
+    lm, tokens = _tiny_lm()
+    opt, key = optax.adamw(1e-3), jax.random.key(2)
+    tr = DataParallelTrainer(lm, opt, topo8, jit_init=True)
+    texts, real_jit = [], jax.jit
+
+    def spy(fun, **kw):
+        jitted = real_jit(fun, **kw)
+
+        def call(*args):
+            texts.append(jitted.lower(*args).as_text())
+            return jitted(*args)
+
+        return call
+
+    monkeypatch.setattr(jax, "jit", spy)
+    tr.init_state(key, tokens)
+    monkeypatch.undo()
+    before = real_jit(
+        lambda key, x: common.TrainState.create(
+            lm.init(key, x)["params"], opt
+        ),
+        out_shardings=topo8.replicated_sharding(),
+    )
+    assert texts == [before.lower(key, jnp.asarray(tokens)).as_text()]
+
+
 def test_batches_shapes_and_determinism(mnist):
     x_tr, y_tr, *_ = mnist
     b = Batches(x_tr, y_tr, global_batch=128, seed=7)
