@@ -1,33 +1,41 @@
 """``drivers/train_lm.py``'s procedure for any model described by
 ``TrainConfig.arch``: the reference module, the grouping of the parameter
-leaves, the counters kept and the limits are data, named by the
-configuration's file under ``comparison``:
+leaves, the counters kept, the limits and the kernels' shapes are data, named
+by the configuration's file under ``comparison``:
 
     "comparison": {
       "reference": "reference_nemotron_h",      a module of benchmark/lib/ with
                                                  loss_and_grad_by_layer
-      "experts_held_key": "n_routed_experts",   arch's count of experts held
+      "kernel_shapes": "ssm_kernels:kernel_shapes",
+                                                 a function of benchmark/lib/:
+                                                 (arch, per_chip, seq_len) ->
+                                                 run["kernels"]
+      "experts_held_key": "n_routed_experts",   arch's count of experts held;
+                                                 only where the model has
+                                                 expert layers
       "leaf_groups": {"ssm": ["in_proj", ...]}, group -> leaf names
       "counters": ["moe_rows_held", ...],       the step's metrics to keep
       "limits": {...}, "limits_why": {...}      replace train_lm.LIMITS singly
     }
 
-so a further described model needs a configuration and a reference, not a
-driver. The comparison that decides ``correct`` is ``train_lm.decide``, on
-the numbers ``train_lm.run`` reads, in its order (the parameters alone; the
-reference's loss and gradient a layer at a time on the system's own expert
-choices; the step program's own gradient by leaf group; two units through
-``fit`` and the move against the same optimizer's from the reference's
-gradient; then the window, every step of which must route within the band
-and drop nothing). ``setup_s`` leaves the comparison's seconds out as
-``train_lm.run`` does. Everything ``train_lm`` and ``train`` export is
-imported; what is written again here is what their ``run`` hard-wires to one
-model (the reference module, ``leaf_group``, ``kernel_shapes``, the key of
-the experts held).
+so a further described model needs a configuration, a reference and its
+kernels' module, not a driver. The comparison that decides ``correct`` is
+``train_lm.decide``, on the numbers ``train_lm.run`` reads, in its order (the
+parameters alone; the reference's loss and gradient a layer at a time, on
+the system's own expert choices where the model has experts; the step
+program's own gradient by leaf group; two units through ``fit`` and the move
+against the same optimizer's from the reference's gradient; then the window,
+every step of which must route within the band and drop nothing). A model
+without ``experts_held_key`` has no routing to read: its routing reads are
+vacuous (``rows_held`` empty, mismatch 0, dropped 0) and pass as written.
+``setup_s`` leaves the comparison's seconds out as ``train_lm.run`` does.
+Everything ``train_lm`` and ``train`` export is imported; what is written
+again here is what their ``run`` hard-wires to one model (the reference
+module, ``leaf_group``, the kernels' shapes, the key of the experts held).
 
-``run["kernels"]`` gives the readers the shapes of what the step runs:
-``flash_causal`` (``lib/lm_kernels``) where the model has attention layers,
-``ssd`` (``lib/ssm_kernels``) where it has Mamba-2 layers.
+A traced run also prints ``lm_spans``' detail line where the step runs one of
+the kernels that file reads (``flash_causal`` in the shapes): no metric of
+such a cell's is read from it.
 """
 
 import dataclasses
@@ -88,25 +96,31 @@ def system_choices(model, params, bx, layers):
 def reference_check(reference, compare, model, trainer, arch, key, bx, by,
                     detail):
     """Steps 1 to 3 of ``train_lm``'s docstring. Returns the start parameters
-    and the reference's gradient (both on the host), its loss and the
-    numbers compared so far."""
+    and the reference's gradient (both on the host), its loss, the numbers
+    compared so far and the worst layer's routing mismatch (0 without
+    experts)."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
     bx, by = jnp.asarray(bx), jnp.asarray(by)
     params = jax.jit(lambda k, x: model.init(k, x)["params"])(key, bx)
-    choices = system_choices(model, params, bx, arch["num_hidden_layers"])
+    held_key = compare.get("experts_held_key")
+    routing, differ = {}, []
+    if held_key:
+        choices = system_choices(model, params, bx, arch["num_hidden_layers"])
+        routing = {"experts_held": arch[held_key],
+                   "expert_offset": arch.get("expert_offset", 0),
+                   "choices": choices}
     ref_loss, ref_grads, own = reference.loss_and_grad_by_layer(
-        params, bx, by, arch, experts_held=arch[compare["experts_held_key"]],
-        expert_offset=arch.get("expert_offset", 0), choices=choices,
-        to_host=True)
-    k = arch["num_experts_per_tok"]
-    differ = [
-        float(1.0 - jnp.mean(jnp.any(
-            c.reshape(-1, k, 1) == o.reshape(-1, 1, k), axis=-1)))
-        for c, o in zip(choices, own) if c is not None
-    ]
+        params, bx, by, arch, **routing, to_host=True)
+    if held_key:
+        k = arch["num_experts_per_tok"]
+        differ = [
+            float(1.0 - jnp.mean(jnp.any(
+                c.reshape(-1, k, 1) == o.reshape(-1, 1, k), axis=-1)))
+            for c, o in zip(choices, own) if c is not None
+        ]
     # the step program's own value-and-gradient function, not a second
     # loss: what is compared is what the timed step differentiates
     _, sys_grads = jax.jit(trainer._local_vg)(params, bx, by)
@@ -122,24 +136,14 @@ def reference_check(reference, compare, model, trainer, arch, key, bx, by,
     return start, ref_grads, float(ref_loss), grad_err, max(differ, default=0.0)
 
 
-def kernel_shapes(arch: dict, per_chip: int, seq_len: int) -> dict:
-    """The shapes of the kernels and scans the step runs (bfloat16
-    operands), by what ``arch`` has."""
-    n = arch["num_hidden_layers"]
-    pattern = arch.get("hybrid_override_pattern", "")[:n]
-    out = {}
-    if "*" in pattern:
-        out["flash_causal"] = {
-            "batch": per_chip, "heads": arch["num_attention_heads"],
-            "kv_heads": arch["num_key_value_heads"], "t": seq_len,
-            "d": arch["head_dim"], "window": None, "itemsize": 2}
-    if "M" in pattern:
-        out["ssd"] = {
-            "batch": per_chip, "t": seq_len, "layers": pattern.count("M"),
-            "heads": arch["mamba_num_heads"],
-            "head_dim": arch["mamba_head_dim"], "groups": arch["n_groups"],
-            "state": arch["ssm_state_size"], "itemsize": 2}
-    return out
+def kernel_shapes(compare: dict, arch: dict, per_chip: int,
+                  seq_len: int) -> dict:
+    """``run["kernels"]``: what the function the ``comparison`` block names
+    gives for this ``arch``."""
+    module, _, function = compare["kernel_shapes"].partition(":")
+    shapes = getattr(importlib.import_module(f"benchmark.lib.{module}"),
+                     function)
+    return shapes(arch, per_chip, seq_len)
 
 
 def run(ctx) -> dict:
@@ -244,9 +248,9 @@ def run(ctx) -> dict:
                  < statistics.fmean(losses[:quarter]))
     failed = sum(not math.isfinite(l) for l in losses)
     limits = {**LIMITS, **compare.get("limits", {}), **job.get("limits", {})}
-    dropped = sum(counters.get("moe_rows_dropped", [])) + sum(
-        float(m.get("moe_rows_dropped", 0.0)) for m in warm.metrics)
-    held = arch.get(compare["experts_held_key"], 0)
+    dropped = float(sum(counters.get("moe_rows_dropped", [])) + sum(
+        float(m.get("moe_rows_dropped", 0.0)) for m in warm.metrics))
+    held = arch.get(compare.get("experts_held_key"), 0)
     # rows a layer under uniform routing
     rows_expected = (per_chip * cfg.seq_len * arch.get("num_experts_per_tok", 0)
                      * held / arch.get("num_routed_experts", held or 1))
@@ -288,6 +292,7 @@ def run(ctx) -> dict:
         for s in stats
     )
 
+    shapes = kernel_shapes(compare, arch, per_chip, cfg.seq_len)
     reduced, flops_per_sample = None, None
     if args.trace:
         reduced = trace_reduce.reduce_trace(
@@ -303,7 +308,8 @@ def run(ctx) -> dict:
         # this cell's is read from them
         from benchmark.lib import lm_spans
 
-        lm_spans.traced()
+        if set(shapes) & set(lm_spans.KERNELS):
+            lm_spans.traced()
 
     return {
         "correct": all(checks.values()),
@@ -328,6 +334,6 @@ def run(ctx) -> dict:
             "flops_per_sample": flops_per_sample,
             "trace": reduced,
             "counters": counters,
-            "kernels": kernel_shapes(arch, per_chip, cfg.seq_len),
+            "kernels": shapes,
         },
     }

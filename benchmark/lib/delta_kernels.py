@@ -1,5 +1,7 @@
-"""Operations and bytes the gated delta rule needs a layer and pass, from
-shapes: what ``delta_roofline_pct`` is computed from.
+"""The gated-delta-rule mixer as the benchmark reads it: the scopes it sets,
+the shapes of the recurrences a step runs, and the operations and bytes the
+rule needs a layer and pass, from shapes: what ``delta_roofline_pct`` is
+computed from.
 
 The work is the recurrence's own, ``S' = exp(g_t) S_{t-1}``, ``S_t = S' +
 beta_t (v_t - S' k_t) k_t^T`` and ``o_t = S_t q_t`` over ``T`` steps, ``H``
@@ -18,6 +20,29 @@ a little under what the scan does.
 """
 
 from benchmark.lib.peaks import peak
+
+#: the scopes ``Block._linear_attention_mixer`` sets, and the detail line a
+#: traced run prints of the time under them (``lib/scope_spans.py``)
+SCOPES = ("linattn", "linattn_conv", "delta_rule", "linattn_gate")
+SPANS = "delta_spans"
+
+
+def kernel_shapes(arch: dict, per_chip: int, seq_len: int) -> dict:
+    """The shapes of the recurrences the step runs that a reader reads
+    (bfloat16 operands), by the first ``num_hidden_layers`` of
+    ``layer_types``. The full-attention layers' causal flash kernels have no
+    reader in such a cell (PERF.md section 7), so no shape is given for
+    them."""
+    kinds = list(arch.get("layer_types", []))[:arch["num_hidden_layers"]]
+    out = {}
+    if "linear_attention" in kinds:
+        out["delta"] = {
+            "batch": per_chip, "t": seq_len,
+            "layers": kinds.count("linear_attention"),
+            "heads": arch["linear_num_key_heads"],
+            "key_dim": arch["linear_key_head_dim"],
+            "value_dim": arch["linear_value_head_dim"], "itemsize": 2}
+    return out
 
 
 def flops(kind: str, shape: dict) -> float:

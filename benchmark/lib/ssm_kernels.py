@@ -1,5 +1,6 @@
-"""Operations and bytes the Mamba-2 recurrence needs a layer and pass, from
-shapes: what ``ssd_roofline_pct`` is computed from.
+"""The Mamba-2 mixer as the benchmark reads it: the scopes it sets, the shapes
+of the kernels a step runs, and the operations and bytes the recurrence needs
+a layer and pass, from shapes: what ``ssd_roofline_pct`` is computed from.
 
 The work is the recurrence's own, ``S_t = exp(dt_t A) S_{t-1} + dt_t x_t
 B_t^T`` and ``y_t = S_t C_t + D x_t`` over ``T`` steps, ``H`` heads, ``P``
@@ -17,6 +18,34 @@ out, so a share is a little under what the scan does.
 """
 
 from benchmark.lib.peaks import peak
+
+#: the scopes ``Block._ssm_mixer`` sets, and the detail line a traced run
+#: prints of the time under them (``lib/scope_spans.py``)
+SCOPES = ("ssm", "ssm_conv", "ssd", "ssm_gate")
+SPANS = "ssm_spans"
+
+
+def kernel_shapes(arch: dict, per_chip: int, seq_len: int) -> dict:
+    """The shapes of the kernels and scans the step runs (bfloat16
+    operands), by the first ``num_hidden_layers`` letters of
+    ``hybrid_override_pattern``: ``flash_causal`` (``lib/lm_kernels``) where
+    it has attention layers, ``ssd`` (this file) where it has Mamba-2
+    layers."""
+    n = arch["num_hidden_layers"]
+    pattern = arch.get("hybrid_override_pattern", "")[:n]
+    out = {}
+    if "*" in pattern:
+        out["flash_causal"] = {
+            "batch": per_chip, "heads": arch["num_attention_heads"],
+            "kv_heads": arch["num_key_value_heads"], "t": seq_len,
+            "d": arch["head_dim"], "window": None, "itemsize": 2}
+    if "M" in pattern:
+        out["ssd"] = {
+            "batch": per_chip, "t": seq_len, "layers": pattern.count("M"),
+            "heads": arch["mamba_num_heads"],
+            "head_dim": arch["mamba_head_dim"], "groups": arch["n_groups"],
+            "state": arch["ssm_state_size"], "itemsize": 2}
+    return out
 
 
 def flops(kind: str, shape: dict) -> float:

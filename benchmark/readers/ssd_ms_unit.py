@@ -1,7 +1,7 @@
 """ssd_ms_unit: device time under the scope ssd (the recurrence alone) a unit."""
 
-from benchmark.lib import ssm_spans
+from benchmark.lib import scope_spans
 
 
 def read(run):
-    return ssm_spans.under_ms_unit(run, "ssd")
+    return scope_spans.under_ms_unit(run, "ssm_kernels", "ssd")

@@ -1,7 +1,7 @@
 """ssm_ms_unit: device time under the scope ssm (the whole Mamba-2 mixer) a unit."""
 
-from benchmark.lib import ssm_spans
+from benchmark.lib import scope_spans
 
 
 def read(run):
-    return ssm_spans.under_ms_unit(run, "ssm")
+    return scope_spans.under_ms_unit(run, "ssm_kernels", "ssm")

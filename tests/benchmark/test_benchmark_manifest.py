@@ -162,68 +162,163 @@ def test_per_layer_metrics_resolve_and_move_what_their_cells_report(manifest):
         assert any(w["name"] in cells_of(manifest, m) for m in metrics)
 
 
-THROWAWAY_DRIVER = '''
-def run(ctx):
-    return {"correct": True, "attempted": ctx["config"]["marker"], "failed": 0,
-            "memory_peak_bytes": 1, "setup_s": 1.0,
-            "end_to_end": {"samples_per_s_chip": 2.0, "unit_ms_p90": 3.0},
-            "run": {"answer": ctx["workload"]["answer"], "trace": None}}
+def test_a_described_models_comparison_block_resolves(manifest):
+    """Every configuration with a ``comparison`` block names what the one
+    driver of described models reads: a reference under ``benchmark/lib/``,
+    the function that gives its kernels' shapes, leaf groups and counters,
+    and where it has experts, the key of their count in its architecture."""
+    from benchmark.drivers import train_lm
+
+    described = 0
+    for c in manifest["configs"]:
+        body = load(c["file"])
+        if "comparison" not in body:
+            continue
+        described += 1
+        compare = body["comparison"]
+        reference = importlib.import_module(
+            f"benchmark.lib.{compare['reference']}")
+        assert callable(reference.loss_and_grad_by_layer), c["name"]
+        module, _, function = compare["kernel_shapes"].partition(":")
+        kernels = importlib.import_module(f"benchmark.lib.{module}")
+        assert callable(getattr(kernels, function)), c["name"]
+        assert compare["leaf_groups"] and isinstance(compare["counters"], list)
+        if "experts_held_key" in compare:
+            assert compare["experts_held_key"] in train_lm.arch_of(body)
+    assert described >= 2
+
+
+#: what a later PR adds for a described model: its kernels' module, with its
+#: family of scopes for lib/scope_spans.py (this one reads the gated delta
+#: rule's under new names)
+THROWAWAY_KERNELS = '''
+import json
+
+from benchmark.lib import delta_kernels
+
+SCOPES = ("linattn", "delta_rule")
+SPANS = "throwaway_spans"
+least_seconds_unit = delta_kernels.least_seconds_unit
+
+
+def kernel_shapes(arch, per_chip, seq_len):
+    out = {"throwaway": delta_kernels.kernel_shapes(
+        arch, per_chip, seq_len)["delta"]}
+    print(json.dumps({"detail": "throwaway_kernels", "value": out}))
+    return out
 '''
-THROWAWAY_READER = '''
+THROWAWAY_READERS = {
+    # the one scope reduction: left out on the CPU, which traces no device
+    "throwaway_scope_ms_unit": ("device_trace", "ms", '''
+from benchmark.lib import scope_spans
+
+
 def read(run):
-    return run["answer"]
-'''
+    return scope_spans.under_ms_unit(run, "throwaway_kernels", "linattn")
+'''),
+    # the step's own counter: read on the CPU too
+    "throwaway_decay_min": ("program_counter", "nat", '''
+def read(run):
+    seen = (run.get("counters") or {}).get("delta_chunk_log_decay_min")
+    return min(seen) if seen else None
+'''),
+}
+CELL = "throwaway_sync_cell"
 
 
-def test_a_later_pr_adds_files_and_entries_and_edits_nothing(tmp_path, manifest):
-    """A throw-away configuration, cell, layer metric and driver in a copy of
-    ``benchmark/``: new files plus manifest entries, no edit to a file that is
-    there, and the one command runs the new cell and reads the new metric."""
-    shutil.copytree(os.path.join(ROOT, "benchmark"), tmp_path / "benchmark",
-                    ignore=shutil.ignore_patterns("__pycache__"))
-    before = {p: p.read_bytes() for p in (tmp_path / "benchmark").rglob("*")
-              if p.is_file()}
-    bench = tmp_path / "benchmark"
-    (bench / "configs" / "throwaway.json").write_text(json.dumps(
-        {"source": "https://example.org/throwaway", "reduced": [],
-         "train_config": {}, "marker": 41}))
-    (bench / "workloads" / "throwaway_cell.json").write_text(json.dumps(
-        {"config": "throwaway", "traffic": "none", "chips": 1, "why": "test",
-         "driver": "throwaway:run", "answer": 42.5, "per_chip_batch": 1,
-         "rehearsal": {}}))
-    (bench / "layer_metrics" / "throwaway_metric.json").write_text(json.dumps(
-        {"unit": "x", "better": "lower", "source": "program_counter",
-         "layer": "Entry", "moves": "setup_s", "cells": ["throwaway_cell"],
-         "reader": "throwaway_metric:read"}))
-    (bench / "drivers" / "throwaway.py").write_text(THROWAWAY_DRIVER)
-    (bench / "readers" / "throwaway_metric.py").write_text(THROWAWAY_READER)
-    grown = json.loads(json.dumps(manifest))
+@pytest.fixture(scope="module")
+def grown(tmp_path_factory):
+    """A copy of the checkout with a described remat cell added as new files
+    and manifest entries: a configuration whose comparison block names the
+    one driver's reference and a kernels' module of its own, a workload on
+    ``train_lm_ref:run``, and two per-layer metrics. Returns the copy and the
+    bytes of every file that was there before."""
+    root = tmp_path_factory.mktemp("grown")
+    for part in ("benchmark", os.path.join("tests", "benchmark")):
+        shutil.copytree(os.path.join(ROOT, part), root / part,
+                        ignore=shutil.ignore_patterns("__pycache__"))
+    shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), root / "BENCHMARK.json")
+    # the program, as a checkout has it
+    os.symlink(os.path.join(ROOT, "mpit_tpu"), root / "mpit_tpu")
+    # every file but the manifest, which takes entries
+    before = {p: p.read_bytes() for p in root.rglob("*")
+              if p.is_file() and "mpit_tpu" not in p.parts
+              and p.name != "BENCHMARK.json"}
+    bench = root / "benchmark"
+    config = load("benchmark", "configs", "olmo-hybrid-7b.json")
+    config["source"] = "https://example.org/throwaway-hybrid"
+    config["comparison"]["kernel_shapes"] = "throwaway_kernels:kernel_shapes"
+    (bench / "configs" / "throwaway-hybrid.json").write_text(json.dumps(config))
+    (bench / "lib" / "throwaway_kernels.py").write_text(THROWAWAY_KERNELS)
+    job = load("benchmark", "workloads", "olmo_hybrid_sync_1chip_8k.json")
+    job.update(config="throwaway-hybrid", why="a described remat cell",
+               driver="train_lm_ref:run")
+    (bench / "workloads" / f"{CELL}.json").write_text(json.dumps(job))
+    grown = load("BENCHMARK.json")
     grown["configs"].append(
-        {"name": "throwaway", "source": "https://example.org/throwaway",
-         "file": "benchmark/configs/throwaway.json", "reduced": [], "why": "t"})
-    grown["workloads"].append(
-        {"name": "throwaway_cell", "config": "throwaway", "traffic": "none",
-         "chips": 1, "why": "test"})
-    grown["per_layer"].append(
-        {"name": "throwaway_metric", "unit": "x", "better": "lower",
-         "source": "program_counter", "layer": "Entry", "moves": "setup_s",
-         "workloads": ["throwaway_cell"]})
-    (tmp_path / "BENCHMARK.json").write_text(json.dumps(grown))
+        {"name": "throwaway-hybrid", "source": config["source"],
+         "file": "benchmark/configs/throwaway-hybrid.json",
+         "reduced": config["reduced"], "why": "t"})
+    grown["workloads"].append({"name": CELL, "config": "throwaway-hybrid",
+                               "traffic": job["traffic"], "chips": 1,
+                               "why": job["why"]})
+    for name, (source, unit, reader) in THROWAWAY_READERS.items():
+        (bench / "readers" / f"{name}.py").write_text(reader)
+        entry = {"name": name, "unit": unit, "better": "lower",
+                 "source": source, "layer": "Collective trainers",
+                 "moves": "samples_per_s_chip"}
+        (bench / "layer_metrics" / f"{name}.json").write_text(json.dumps(
+            {**{k: v for k, v in entry.items() if k != "name"},
+             "cells": [CELL], "reader": f"{name}:read"}))
+        grown["per_layer"].append({**entry, "workloads": [CELL]})
+    (root / "BENCHMARK.json").write_text(json.dumps(grown, indent=2))
+    return root, before
 
-    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=ROOT,
+
+def test_a_later_pr_adds_files_and_entries_and_edits_nothing(grown):
+    """The one command rehearses the added cell through the one driver and
+    reads its metrics, and no file that was there is edited."""
+    root, before = grown
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=str(root),
                XLA_FLAGS="--xla_force_host_platform_device_count=1")
     out = subprocess.run(
-        [sys.executable, "benchmark/run_cell.py", "--workload",
-         "throwaway_cell", "--seed", "1", "--seconds", "1", "--trace", "1",
+        [sys.executable, "benchmark/run_cell.py", "--workload", CELL,
+         "--seed", "2147483659", "--seconds", "2", "--trace", "1",
          "--rehearsal"],
-        cwd=tmp_path, env=env, capture_output=True, text=True, timeout=120,
+        cwd=root, env=env, capture_output=True, text=True, timeout=600,
     )
     assert out.returncode == 0, out.stderr[-2000:]
-    line = json.loads(out.stdout.strip().splitlines()[-1])
-    assert line["attempted"] == 41  # the new configuration's file was read
-    assert line["metrics"]["throwaway_metric"] == {"value": 42.5, "unit": "x"}
-    # cells' own metrics only: none of the other layer metrics lists this cell
-    assert set(line["metrics"]) <= {"throwaway_metric"} | {
+    lines = [json.loads(l) for l in out.stdout.splitlines()
+             if l.startswith("{")]
+    line = lines[-1]
+    assert line["correct"] is True and line["failed"] == 0
+    # the configuration's own kernels' module gave the shapes
+    shapes = next(l["value"] for l in lines
+                  if l.get("detail") == "throwaway_kernels")
+    assert shapes["throwaway"]["layers"] == 3
+    assert line["metrics"]["throwaway_decay_min"]["value"] < 0
+    assert "throwaway_scope_ms_unit" not in line["metrics"]
+    # cells' own metrics only: no accepted list names the new cell
+    manifest = load("BENCHMARK.json")
+    assert set(line["metrics"]) <= set(THROWAWAY_READERS) | {
         m["name"] for m in manifest["per_layer"] if "workloads" not in m}
     for p, content in before.items():
         assert p.read_bytes() == content, f"{p} was edited"
+
+
+def test_the_contract_tests_hold_on_the_grown_manifest(grown):
+    """Every test of ``tests/benchmark/`` that checks files and the manifest,
+    run in the grown copy against its own ``BENCHMARK.json``: none pins the
+    manifest as it stands. The rehearsals and the program-against-copy
+    comparisons, which the addition does not touch, are left out."""
+    root, _ = grown
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=str(root))
+    out = subprocess.run(
+        [sys.executable, "-m", "pytest", "tests/benchmark", "-q",
+         "-p", "no:cacheprovider", "-p", "no:randomly", "-p", "no:xdist",
+         "-k", "not rehearsal and not reference_is_the_programs "
+               "and not a_later_pr and not contract_tests_hold"],
+        cwd=root, env=env, capture_output=True, text=True, timeout=600,
+    )
+    assert out.returncode == 0, out.stdout[-3000:]
+    assert " passed" in out.stdout.strip().splitlines()[-1]

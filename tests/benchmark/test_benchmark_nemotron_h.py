@@ -17,7 +17,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
-from benchmark.lib import ssm_kernels, ssm_spans  # noqa: E402
+from benchmark.lib import scope_spans, ssm_kernels  # noqa: E402
 
 CELL = "nemotron3_nano_sync_1chip_8k"
 NAME = "nemotron-3-nano-30b-a3b"
@@ -108,6 +108,10 @@ def test_the_job_is_the_issues_and_the_assumptions_are_stated():
     assert job["data"] == {"kind": "tokens", "pool": 512, "epoch_repeats": 64}
     assert job["loss_must_fall"] is True and job["trace_seconds"] == 4.0
     assert job["driver"] == "train_lm_ref:run"
+    compare = CONFIG["comparison"]
+    assert compare["reference"] == "reference_nemotron_h"
+    assert compare["kernel_shapes"] == "ssm_kernels:kernel_shapes"
+    assert compare["experts_held_key"] == "n_routed_experts"
     cell = next(w for w in MANIFEST["workloads"] if w["name"] == CELL)
     assert cell == {"name": CELL, "config": NAME, "traffic": "sync_b1_t8192",
                     "chips": 1, "why": job["why"]}
@@ -123,11 +127,10 @@ def test_the_job_is_the_issues_and_the_assumptions_are_stated():
     departures = " ".join(CONFIG["departures"])
     assert "e_score_correction_bias" in departures
     assert "rescale_prenorm_residual" in departures
-    for m in MANIFEST["per_layer"]:
-        if m["name"] in NEW:
-            assert m["workloads"] == [CELL]
-        elif "workloads" in m:  # the accepted lists are not extended here
-            assert CELL not in m["workloads"], m["name"]
+    # its own metrics, found by name, name its cell alone
+    per_layer = {m["name"]: m for m in MANIFEST["per_layer"]}
+    for name in NEW:
+        assert per_layer[name]["workloads"] == [CELL], name
 
 
 def test_the_parameter_table_is_the_models():
@@ -261,24 +264,25 @@ def hand_made_plane():
 
 
 def test_time_is_counted_under_every_scope_on_the_path():
-    out = ssm_spans.reduce(hand_made_plane(), OP_NAMES)
+    out = scope_spans.reduce(hand_made_plane(), OP_NAMES, ssm_kernels.SCOPES)
     assert out["units"] == 2
     assert out["under_ms_unit"] == pytest.approx({
         "ssm": 6 + 2 + 5 + 1 + 9 + 3, "ssm_conv": 2, "ssd": 5 + 1 + 9,
         "ssm_gate": 3})
     assert out["scopes_in_program"] == ["ssd", "ssm", "ssm_conv", "ssm_gate"]
     # a program without the scopes: nothing under them, and no error
-    bare = ssm_spans.reduce(hand_made_plane(), {
+    bare = scope_spans.reduce(hand_made_plane(), {
         k: v.replace("/ssm", "").replace("/ssd", "") for k, v in
-        OP_NAMES.items()})
+        OP_NAMES.items()}, ssm_kernels.SCOPES)
     assert bare["scopes_in_program"] == [] == list(bare["under_ms_unit"])
 
 
 def test_readers_on_a_fixture_run(monkeypatch):
     """Every new reader returns a number from a run that holds the trace,
     the counter and the scan's shape, and None from one that does not."""
-    reduced = ssm_spans.reduce(hand_made_plane(), OP_NAMES)
-    monkeypatch.setattr(ssm_spans, "traced", lambda: reduced)
+    reduced = scope_spans.reduce(hand_made_plane(), OP_NAMES,
+                                 ssm_kernels.SCOPES)
+    monkeypatch.setattr(scope_spans, "traced", lambda name: reduced)
     run = {"trace": {"busy_s": 1.0}, "device_kind": "TPU v5 lite",
            "counters": {"ssm_chunk_log_decay_min": [-310.5, -402.25, -120.0]},
            "kernels": {"ssd": SHAPE}}
@@ -294,7 +298,7 @@ def test_readers_on_a_fixture_run(monkeypatch):
     for name in NEW:  # an accepted cell's run: nothing to read, no error
         assert read(name, {"trace": None}) is None
     # the parent's program under these files: a trace, none of the scopes
-    monkeypatch.setattr(ssm_spans, "traced", lambda: {
+    monkeypatch.setattr(scope_spans, "traced", lambda name: {
         "units": 2, "under_ms_unit": {}, "scopes_in_program": []})
     for name in NEW[:3]:
         assert read(name, {**run, "counters": {}}) is None
@@ -308,15 +312,36 @@ def test_readers_on_a_fixture_run(monkeypatch):
                                   "moves")}
 
 
+def test_the_readers_give_the_recorded_line_to_the_last_digit(monkeypatch):
+    """A traced line the chip printed under the readers of ``ssm_spans.py``,
+    recorded with its ``ssm_spans`` detail: the one scope reduction's readers,
+    under the family they name, give the very numbers of that line."""
+    recorded = SOUND["traced"]
+    monkeypatch.setattr(scope_spans, "traced", lambda name: (
+        recorded["ssm_spans"] if name == "ssm_kernels" else None))
+    run = {"trace": {"busy_s": recorded["device"]["busy_s"]},
+           "device_kind": recorded["device"]["kind"],
+           "kernels": {"ssd": SHAPE}}
+    for name in NEW[:3]:
+        read = importlib.import_module(f"benchmark.readers.{name}").read
+        assert read(run) == recorded["metrics"][name]["value"], name
+    assert set(NEW) | set(UNLISTED) <= set(recorded["metrics"])
+    assert recorded["device"]["memory_peak_bytes"] >= 0.25 * 16909336064
+
+
 def test_the_drivers_shapes_are_the_configurations():
+    """Through the function the configuration's comparison block names."""
     from benchmark.drivers import train_lm, train_lm_ref
 
-    shapes = train_lm_ref.kernel_shapes(train_lm.arch_of(CONFIG), 1, 8192)
+    compare = CONFIG["comparison"]
+    shapes = train_lm_ref.kernel_shapes(
+        compare, train_lm.arch_of(CONFIG), 1, 8192)
     assert shapes["ssd"] == SHAPE
     assert shapes["flash_causal"] == {
         "batch": 1, "heads": 32, "kv_heads": 2, "t": 8192, "d": 128,
         "window": None, "itemsize": 2}
-    assert train_lm_ref.kernel_shapes({"num_hidden_layers": 2}, 1, 64) == {}
+    assert train_lm_ref.kernel_shapes(
+        compare, {"num_hidden_layers": 2}, 1, 64) == {}
 
 
 # -- what decides correct, on readings taken on the chip ------------------------

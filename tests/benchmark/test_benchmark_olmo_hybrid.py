@@ -18,7 +18,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
-from benchmark.lib import delta_kernels, delta_spans  # noqa: E402
+from benchmark.lib import delta_kernels, scope_spans  # noqa: E402
 
 CELL = "olmo_hybrid_sync_1chip_8k"
 NAME = "olmo-hybrid-7b"
@@ -71,7 +71,6 @@ def test_every_published_key_is_held_and_only_the_two_cuts_differ():
     assert entry["source"] == CONFIG["source"]
     assert entry["reduced"] == CONFIG["reduced"]
     assert entry["file"] == f"benchmark/configs/{NAME}.json"
-    assert MANIFEST["configs"][-1] == entry  # appended, nothing moved
 
 
 def test_the_source_config_is_the_catalog_row():
@@ -114,11 +113,10 @@ def test_the_job_is_the_issues_and_the_assumptions_are_stated():
     assert JOB["data"] == {"kind": "tokens", "pool": 512, "epoch_repeats": 64}
     assert JOB["loss_must_fall"] is True and JOB["trace_seconds"] == 4.0
     assert JOB["units_per_interval"] == 1
-    assert JOB["driver"] == "train_lm_dense:run"
+    assert JOB["driver"] == "train_lm_ref:run"
     cell = next(w for w in MANIFEST["workloads"] if w["name"] == CELL)
     assert cell == {"name": CELL, "config": NAME, "traffic": "sync_b1_t8192",
                     "chips": 1, "why": JOB["why"]}
-    assert MANIFEST["workloads"][-1] == cell
     assert "packed 32k" in cell["why"] and "8,192" in cell["why"]
     assumed = " ".join(CONFIG["assumed"])
     for word in ("OUTPUT", "QK norm", "no rotary", "chunk 64", "WITHOUT bias",
@@ -129,15 +127,14 @@ def test_the_job_is_the_issues_and_the_assumptions_are_stated():
     compare = CONFIG["comparison"]
     assert "experts_held_key" not in compare
     assert compare["reference"] == "reference_olmo_hybrid"
+    assert compare["kernel_shapes"] == "delta_kernels:kernel_shapes"
     assert compare["counters"] == ["delta_chunk_log_decay_min"]
     for limit in ("loss_rtol", "grad_rtol", "move_rtol"):
         assert limit in compare["limits_why"], limit
-    for m in MANIFEST["per_layer"]:
-        if m["name"] in NEW:
-            assert m["workloads"] == [CELL]
-        elif "workloads" in m:  # the accepted lists are not extended here
-            assert CELL not in m["workloads"], m["name"]
-    assert [m["name"] for m in MANIFEST["per_layer"]][-4:] == NEW
+    # its own metrics, found by name, name its cell alone
+    per_layer = {m["name"]: m for m in MANIFEST["per_layer"]}
+    for name in NEW:
+        assert per_layer[name]["workloads"] == [CELL], name
 
 
 def test_the_parameter_table_is_the_models():
@@ -290,7 +287,7 @@ def hand_made_plane():
 
 
 def test_time_is_counted_under_every_scope_on_the_path():
-    out = delta_spans.reduce(hand_made_plane(), OP_NAMES)
+    out = scope_spans.reduce(hand_made_plane(), OP_NAMES, delta_kernels.SCOPES)
     assert out["units"] == 2
     assert out["under_ms_unit"] == pytest.approx({
         "linattn": 6 + 2 + 5 + 1 + 9 + 3, "linattn_conv": 2,
@@ -298,9 +295,9 @@ def test_time_is_counted_under_every_scope_on_the_path():
     assert out["scopes_in_program"] == [
         "delta_rule", "linattn", "linattn_conv", "linattn_gate"]
     # a program without the scopes: nothing under them, and no error
-    bare = delta_spans.reduce(hand_made_plane(), {
+    bare = scope_spans.reduce(hand_made_plane(), {
         k: v.replace("/linattn", "").replace("/delta_rule", "")
-        for k, v in OP_NAMES.items()})
+        for k, v in OP_NAMES.items()}, delta_kernels.SCOPES)
     assert bare["scopes_in_program"] == [] == list(bare["under_ms_unit"])
 
 
@@ -308,8 +305,9 @@ def test_readers_on_a_fixture_run(monkeypatch):
     """Every new reader returns a number from a run that holds the trace,
     the counter and the recurrence's shape, and None from one that does
     not."""
-    reduced = delta_spans.reduce(hand_made_plane(), OP_NAMES)
-    monkeypatch.setattr(delta_spans, "traced", lambda: reduced)
+    reduced = scope_spans.reduce(hand_made_plane(), OP_NAMES,
+                                 delta_kernels.SCOPES)
+    monkeypatch.setattr(scope_spans, "traced", lambda name: reduced)
     run = {"trace": {"busy_s": 1.0}, "device_kind": "TPU v5 lite",
            "counters": {"delta_chunk_log_decay_min": [-31.5, -40.25, -12.0]},
            "kernels": {"delta": SHAPE}}
@@ -326,7 +324,7 @@ def test_readers_on_a_fixture_run(monkeypatch):
     for name in NEW:  # an accepted cell's run: nothing to read, no error
         assert read(name, {"trace": None}) is None
     # the parent's program under these files: a trace, none of the scopes
-    monkeypatch.setattr(delta_spans, "traced", lambda: {
+    monkeypatch.setattr(scope_spans, "traced", lambda name: {
         "units": 2, "under_ms_unit": {}, "scopes_in_program": []})
     for name in NEW[:3]:
         assert read(name, {**run, "counters": {}}) is None
@@ -361,12 +359,31 @@ def test_the_readers_on_the_chips_recorded_line():
     assert recorded["device"]["memory_peak_bytes"] >= 0.25 * 16909336064
 
 
-def test_the_drivers_shapes_are_the_configurations():
-    from benchmark.drivers import train_lm, train_lm_dense
+def test_the_readers_give_the_recorded_line_to_the_last_digit(monkeypatch):
+    """The recorded ``delta_spans`` detail through the one scope reduction,
+    under the family the readers name: the very numbers the chip's line
+    printed, not near them."""
+    recorded = READINGS["traced"]
+    monkeypatch.setattr(scope_spans, "traced", lambda name: (
+        recorded["delta_spans"] if name == "delta_kernels" else None))
+    run = {"trace": {"busy_s": recorded["device"]["busy_s"]},
+           "device_kind": recorded["device"]["kind"],
+           "kernels": {"delta": SHAPE}}
+    for name in NEW[:3]:
+        read = importlib.import_module(f"benchmark.readers.{name}").read
+        assert read(run) == recorded["metrics"][name]["value"], name
 
-    shapes = train_lm_dense.kernel_shapes(train_lm.arch_of(CONFIG), 1, 8192)
+
+def test_the_drivers_shapes_are_the_configurations():
+    """Through the function the configuration's comparison block names."""
+    from benchmark.drivers import train_lm, train_lm_ref
+
+    compare = CONFIG["comparison"]
+    shapes = train_lm_ref.kernel_shapes(
+        compare, train_lm.arch_of(CONFIG), 1, 8192)
     assert shapes == {"delta": SHAPE}  # the causal kernels have no reader here
-    assert train_lm_dense.kernel_shapes({"num_hidden_layers": 2}, 1, 64) == {}
+    assert train_lm_ref.kernel_shapes(
+        compare, {"num_hidden_layers": 2}, 1, 64) == {}
 
 
 # -- what decides correct, on readings taken on the chip ------------------------

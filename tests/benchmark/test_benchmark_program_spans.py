@@ -204,14 +204,28 @@ def test_readers_find_nothing_in_a_run_that_holds_nothing(monkeypatch):
 
 
 def test_the_manifest_holds_the_nine_metrics_for_every_cell():
+    """A metric without a list is reported by every cell; one with a list
+    has that list as its file's ``cells``. ``elastic_ms_unit`` lists the cells
+    whose program exchanges: a one-chip sync step has no exchange left to
+    read (XLA removes a one-device all-reduce)."""
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        per_layer = {m["name"]: m for m in json.load(f)["per_layer"]}
+        manifest = json.load(f)
+    per_layer = {m["name"]: m for m in manifest["per_layer"]}
     assert set(NEW) <= set(per_layer)
     for name in NEW:
-        assert "workloads" not in per_layer[name]
+        with open(os.path.join(ROOT, "benchmark", "layer_metrics",
+                               f"{name}.json")) as f:
+            own = json.load(f)
+        assert own["cells"] == per_layer[name].get("workloads", "all"), name
         assert per_layer[name]["better"] == "lower"
     assert per_layer["init_state_s"]["moves"] == "setup_s"
     assert per_layer["elastic_ms_unit"]["layer"] == "World"
+    for cell in per_layer["elastic_ms_unit"].get("workloads", []):
+        w = next(w for w in manifest["workloads"] if w["name"] == cell)
+        with open(os.path.join(ROOT, "benchmark", "workloads",
+                               f"{cell}.json")) as f:
+            algo = json.load(f)["train_config"].get("algo")
+        assert algo == "easgd" or w["chips"] > 1, cell
 
 
 def test_a_traced_rehearsal_reads_the_programs_registry():
