@@ -67,6 +67,41 @@ the family's published modelling code: ``rope_theta`` is read by nothing);
 ``moe_shared_expert_intermediate_size``, ``routed_scaling_factor``,
 ``mlp_hidden_act`` (``relu2``: ungated experts ``W_down relu(W_up x)^2``),
 sigmoid scores with the choice made on ``score + e_score_correction_bias``.
+
+An ``arch`` whose ``layer_types`` hold ``hybrid`` (the ``zaya`` family) is
+read by :func:`_cca_specs` and by that family's keys alone; any other key
+raises, and so does any value of a known key that is not built. Every layer is
+a compressed-convolutional-attention (CCA) sublayer and then a sparse-expert
+sublayer: mixers ``("cca", "ffn")``. Keys: ``hidden_size``,
+``num_hidden_layers``, ``num_attention_heads``, ``num_key_value_heads``,
+``head_dim`` (the latent is ``num_attention_heads x head_dim`` wide: the
+compression), ``cca_time0`` / ``cca_time1`` (taps of the depthwise and the
+per-head causal convolution over ``[q ; k]``), ``rope_parameters.hybrid``
+(``rope_type`` ``default``, ``rope_theta``, ``partial_rotary_factor``; the
+top-level ``partial_rotary_factor`` must agree), ``rms_norm_eps``,
+``router_hidden_size`` (the router MLP's width), ``num_experts`` (HELD, as
+above, with ``num_routed_experts`` / ``expert_offset`` / ``moe_row_bound`` /
+``moe_routing_no_grad`` / ``router_aux_loss_coef`` beside it),
+``num_experts_per_tok``, ``moe_intermediate_size``, ``tie_word_embeddings``;
+``hidden_act`` must be ``silu``, ``attention_bias`` and ``lm_head_bias``
+false, ``sliding_window`` null (a ``hybrid_sliding`` layer is not built);
+``model_type``, ``vocab_size`` and ``max_position_embeddings`` are read by
+nothing here. This repo's key ``embedding_multiplier`` (any family; default 1)
+multiplies the looked-up embedding rows, not the tied head's table. The forms the config does not fix are assumed, after the CCA
+paper (Figliolia et al., arXiv:2510.04476) and the family's technical report
+(``ops/cca.py`` has the equations): the value shift gives half of each value
+head's channels from the previous token; the convolutions carry no bias and no
+activation between them; the q-k mean adds ``(q0_i + k0_g) / 2`` to query
+head ``i`` and ``(mean_{i in g} q0_i + k0_g) / 2`` to key head ``g``; q and k
+are L2-normalised a head to ``sqrt(head_dim)``, q times a learned temperature
+a head (from 1); the router is ``W_3 gelu(W_2 gelu(W_1 r_l))`` over ``r_l =
+RMSNorm(x) W_down + gamma_l r_{l-1}`` (exponential depth averaging: ``r``
+passes from layer to layer, ``gamma_l`` one learned scalar a layer, from 0.5;
+exact GELU, no biases); the top-k weights are the chosen softmax
+probabilities, not renormalised (the Switch rule); each sublayer's residual is
+``s_res * x + s_out * out`` with both scales a channel, from 1. Departure: the
+family moves a balancing bias on the router's choice outside the gradient;
+none is built (held at zero).
 """
 
 from __future__ import annotations
@@ -104,6 +139,9 @@ class MoESpec:
     scoring: str = "softmax"
     # the expert's function, a key of ops/moe.EXPERTS: "swiglu" | "relu2"
     expert: str = "swiglu"
+    # True: the top-k weights divided by their sum (then times ``scale``);
+    # False: the chosen scores themselves (the Switch rule at top-1)
+    renormalize: bool = True
 
     def rows(self, tokens: int) -> int:
         """The dispatch buffer's rows for ``tokens`` tokens: the given
@@ -154,12 +192,26 @@ class LinearAttentionSpec:
 
 
 @dataclasses.dataclass(frozen=True)
+class CCASpec:
+    """A compressed-convolutional-attention mixer (``ops/cca.py``) over the
+    layer's query and KV heads, which live in a latent narrower than the
+    model: ``time0`` taps of the depthwise and ``time1`` of the per-head
+    causal convolution."""
+    time0: int
+    time1: int
+
+
+@dataclasses.dataclass(frozen=True)
 class LayerSpec:
     """One layer: the residual passes ``x + Mixer(RMSNorm(x))`` (``norm_at``
     ``input``) or ``x + RMSNorm(Mixer(x))`` (``output``) once a name in
     ``mixers``, in order. ``attention`` reads the head counts, ``window``,
     ``rope``, ``gate`` and ``qk_norm``; ``ffn`` reads ``d_ff`` or ``moe``;
-    ``ssm`` reads ``ssm``; ``linear_attention`` reads ``linattn``."""
+    ``ssm`` reads ``ssm``; ``linear_attention`` reads ``linattn``; ``cca``
+    reads the head counts, ``cca`` and ``rope``. ``router_hidden`` > 0
+    replaces the expert layer's linear router by an MLP of that width whose
+    state passes from block to block (``Block._router_mlp``);
+    ``residual_scales`` makes each residual ``s_res * x + s_out * out``."""
     d_model: int
     num_heads: int
     num_kv_heads: int
@@ -175,6 +227,9 @@ class LayerSpec:
     linattn: Optional[LinearAttentionSpec] = None
     norm_at: str = "input"
     qk_norm: bool = False  # RMSNorm over all of q and of k, before the heads
+    cca: Optional[CCASpec] = None
+    router_hidden: int = 0
+    residual_scales: bool = False
 
 
 def _rope_spec(params: dict, head_dim: int) -> RopeSpec:
@@ -278,6 +333,81 @@ def _hybrid_specs(arch: dict) -> tuple[LayerSpec, ...]:
     return tuple(kinds[letter] for letter in pattern)
 
 
+#: every key an ``arch`` of ``hybrid`` layers may hold: the family's, then
+#: this repo's for one chip's share (above)
+_CCA_KEYS = frozenset((
+    "model_type", "vocab_size", "max_position_embeddings", "hidden_size",
+    "num_hidden_layers", "layer_types", "num_attention_heads",
+    "num_key_value_heads", "head_dim", "cca_time0", "cca_time1",
+    "rope_parameters", "partial_rotary_factor", "rms_norm_eps",
+    "router_hidden_size", "num_experts", "num_experts_per_tok",
+    "moe_intermediate_size", "tie_word_embeddings", "hidden_act",
+    "attention_bias", "lm_head_bias", "sliding_window",
+    "num_routed_experts", "expert_offset", "moe_row_bound",
+    "moe_routing_no_grad", "router_aux_loss_coef", "embedding_multiplier",
+))
+
+
+def _cca_specs(arch: dict) -> tuple[LayerSpec, ...]:
+    """The ``zaya`` family: every layer a CCA sublayer, then a top-k
+    sparse-expert sublayer routed by an MLP with depth averaging."""
+    unknown = sorted(set(arch) - _CCA_KEYS)
+    if unknown:
+        raise ValueError(f"arch: {unknown} not built for hybrid layers")
+    n = int(arch["num_hidden_layers"])
+    kinds = list(arch["layer_types"])[:n]
+    if len(kinds) != n:
+        raise ValueError(f"arch: {n} layers but layer_types has {len(kinds)}")
+    for kind in kinds:
+        if kind != "hybrid":
+            raise ValueError(f"arch: layer type {kind!r} beside hybrid: have "
+                             "hybrid (CCA, then experts)")
+    for key in ("attention_bias", "lm_head_bias"):
+        if arch.get(key):
+            raise ValueError(f"arch: {key} true is not built")
+    if arch.get("hidden_act", "silu") != "silu":
+        raise ValueError(f"arch: hidden_act {arch['hidden_act']!r}: have silu")
+    if arch.get("sliding_window") is not None:
+        raise ValueError("arch: sliding_window is not built for hybrid layers")
+    heads, kv_heads = (int(arch["num_attention_heads"]),
+                       int(arch["num_key_value_heads"]))
+    head_dim = int(arch["head_dim"])
+    if heads % kv_heads or head_dim % 2:
+        raise ValueError(f"arch: {heads} query heads on {kv_heads} KV heads "
+                         f"of {head_dim}")
+    rope = dict(arch["rope_parameters"]["hybrid"])
+    if rope.get("rope_type", "default") != "default":
+        raise ValueError(f"arch: rope_type {rope['rope_type']!r}: have default")
+    if float(arch.get("partial_rotary_factor",
+                      rope.get("partial_rotary_factor", 1.0))) != float(
+            rope.get("partial_rotary_factor", 1.0)):
+        raise ValueError("arch: partial_rotary_factor differs from "
+                         "rope_parameters.hybrid's")
+    held = int(arch["num_experts"])
+    moe = MoESpec(
+        routed=int(arch.get("num_routed_experts", held)), held=held,
+        offset=int(arch.get("expert_offset", 0)),
+        top_k=int(arch["num_experts_per_tok"]),
+        width=int(arch["moe_intermediate_size"]), shared_width=0, scale=1.0,
+        row_bound=int(arch.get("moe_row_bound", 0)),
+        routing_grad=not arch.get("moe_routing_no_grad", False),
+        renormalize=False,
+    )
+    _check_share(moe)
+    spec = LayerSpec(
+        d_model=int(arch["hidden_size"]), num_heads=heads,
+        num_kv_heads=kv_heads, head_dim=head_dim, window=None,
+        rope=_rope_spec(rope, head_dim), gate=False,
+        norm_eps=float(arch.get("rms_norm_eps", 1e-5)), d_ff=0, moe=moe,
+        mixers=("cca", "ffn"),
+        cca=CCASpec(time0=int(arch["cca_time0"]),
+                    time1=int(arch["cca_time1"])),
+        router_hidden=int(arch["router_hidden_size"]),
+        residual_scales=True,
+    )
+    return (spec,) * n
+
+
 def _linear_attention_spec(arch: dict) -> LinearAttentionSpec:
     heads = int(arch["linear_num_key_heads"])
     if int(arch.get("linear_num_value_heads", heads)) != heads:
@@ -300,6 +430,8 @@ def _linear_attention_spec(arch: dict) -> LinearAttentionSpec:
 def layer_specs(arch: dict) -> tuple[LayerSpec, ...]:
     if "hybrid_override_pattern" in arch:
         return _hybrid_specs(arch)
+    if "hybrid" in (arch.get("layer_types") or ()):
+        return _cca_specs(arch)
     n = int(arch["num_hidden_layers"])
     d = int(arch["hidden_size"])
     head_dim = int(arch.get("head_dim")

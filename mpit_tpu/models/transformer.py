@@ -110,9 +110,9 @@ class Block(nn.Module):
     spec: Optional[LayerSpec] = None
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, r=None):
         if self.spec is not None:
-            return self._described(x)
+            return self._described(x, r)
         # profiling.scope names the device's time by layer part (metadata
         # only; forward, recomputation and backward all carry it): "attention" is the
         # attention arithmetic alone, "attn_proj" the projections around
@@ -164,17 +164,20 @@ class Block(nn.Module):
                 x = x + nn.Dense(self.d_model, dtype=dt)(y)
         return x
 
-    def _described(self, x):
+    def _described(self, x, r=None):
         """The block ``spec`` describes: ``x <- x + Mixer(RMSNorm(x))`` (or,
-        ``spec.norm_at`` ``output``, ``x + RMSNorm(Mixer(x))``) once a
-        name in ``spec.mixers``, no biases: ``attention`` or
-        ``linear_attention`` then ``ffn`` for a
+        ``spec.norm_at`` ``output``, ``x + RMSNorm(Mixer(x))``; or, with
+        ``spec.residual_scales``, ``s_res * x + s_out * Mixer(RMSNorm(x))``)
+        once a name in ``spec.mixers``, no biases: ``attention``,
+        ``linear_attention`` or ``cca`` then ``ffn`` for a
         transformer layer, one mixer alone (``ssm``, ``ffn`` or
         ``attention``) for a hybrid model's. Scopes as in the GPT-2 block,
         with ``rope``, ``qk_norm`` and ``attn_gate`` inside ``attn_proj``,
         ``attn_window`` / ``attn_full`` inside ``attention``, the expert
         layer's ``moe_*`` scopes inside ``mlp``, ``ssm`` around the whole
-        Mamba-2 mixer and ``linattn`` around the whole gated-delta-rule one."""
+        Mamba-2 mixer and ``linattn`` around the whole gated-delta-rule one.
+        ``r`` is the router's state carried from the block before (None
+        where the router keeps none); the block then returns ``(x, r)``."""
         if self.decode or self.seq_axis is not None or self.moe_experts:
             raise ValueError(
                 "a block built from an architecture (TrainConfig.arch) "
@@ -184,12 +187,15 @@ class Block(nn.Module):
             )
         if self.attn_impl not in ("xla", "flash", "flash_force"):
             raise ValueError(f"attn_impl={self.attn_impl!r}")
-        mixer = {"attention": self._attention_mixer, "ffn": self._ffn_mixer,
-                 "ssm": self._ssm_mixer,
-                 "linear_attention": self._linear_attention_mixer}
+        mixer = {"attention": self._attention_mixer, "ssm": self._ssm_mixer,
+                 "linear_attention": self._linear_attention_mixer,
+                 "cca": self._cca_mixer}
         for name in self.spec.mixers:
-            x = mixer[name](x)
-        return x
+            if name == "ffn":
+                x, r = self._ffn_mixer(x, r)
+            else:
+                x = mixer[name](x)
+        return x if r is None else (x, r)
 
     def _weight(self, name, *shape):
         return self.param(name, nn.initializers.lecun_normal(), shape,
@@ -213,9 +219,16 @@ class Block(nn.Module):
 
     def _residual(self, x, out, norm):
         """``x + out``, ``out`` through the block's norm first where that is
-        on the mixer's output (OLMo 2's reordered norm)."""
+        on the mixer's output (OLMo 2's reordered norm); with
+        ``spec.residual_scales``, ``s_res * x + s_out * out`` in float32, the
+        scales a channel from 1, named after ``norm``'s sublayer."""
         if self.spec.norm_at == "output":
             out = rms_norm(out, self._norm_scale(norm), self.spec.norm_eps)
+        if self.spec.residual_scales:
+            part, f32 = norm.removesuffix("_norm"), jnp.float32
+            return (x.astype(f32) * self._norm_scale(f"{part}_res_scale")
+                    + out.astype(f32) * self._norm_scale(f"{part}_out_scale")
+                    ).astype(self.compute_dtype)
         return x + out.astype(self.compute_dtype)
 
     def _attention_mixer(self, x):
@@ -265,21 +278,100 @@ class Block(nn.Module):
                 att.reshape(b, t, h * hd), weight("wo", h * hd, d)
             ), "attn_norm")
 
-    def _ffn_mixer(self, x):
-        from mpit_tpu.ops.moe import swiglu
+    def _ffn_mixer(self, x, r=None):
+        """The feed-forward: ``(x, r)``, ``r`` the router's carried state
+        (``_router_mlp``) or None, passed on."""
+        from mpit_tpu.ops import moe as moe_ops
 
-        spec, dt = self.spec, self.compute_dtype
+        spec = self.spec
         b, t, d = x.shape
         with profiling.scope("mlp"):
             y = self._mixer_input(x, "ffn_norm")
             if spec.moe is None:
-                return self._residual(x, swiglu(
+                return self._residual(x, moe_ops.swiglu(
                     y, self._weight("w_gate", d, spec.d_ff),
                     self._weight("w_up", d, spec.d_ff),
                     self._weight("w_down", spec.d_ff, d),
-                ), "ffn_norm")
+                ), "ffn_norm"), r
+            routing = None
+            if spec.router_hidden:
+                with profiling.scope("moe_router"), profiling.scope(
+                        "router_mlp"):
+                    logits, r = self._router_mlp(y, r)
+                    routing = moe_ops.route_top_k(
+                        logits.reshape(b * t, -1), None, spec.moe.top_k,
+                        spec.moe.scale, renormalize=spec.moe.renormalize)
             return self._residual(x, self._held_experts(
-                y.reshape(b * t, d)).reshape(b, t, d), "ffn_norm")
+                y.reshape(b * t, d), routing).reshape(b, t, d),
+                "ffn_norm"), r
+
+    def _router_mlp(self, y, r):
+        """The MLP router: the state ``y W_down + gamma r`` (``r`` the block
+        before's: exponential depth averaging, ``gamma`` a learned scalar
+        from 0.5) and the logits ``W_3 gelu(W_2 gelu(W_1 state))``, exact
+        GELU, no biases, all in float32 at full precision as the linear
+        router's are. Returns ``(logits, state)``."""
+        f32 = jnp.float32
+        d, width = self.spec.d_model, self.spec.router_hidden
+        mm = lambda a, w: jnp.dot(a.astype(f32), w,
+                                  precision=jax.lax.Precision.HIGHEST)
+        state = mm(y, self._weight("router_down", d, width)) + self.param(
+            "router_eda", nn.initializers.constant(0.5), (), f32) * r
+        hidden = state
+        for name in ("router_w1", "router_w2"):
+            hidden = jax.nn.gelu(mm(hidden, self._weight(name, width, width)),
+                                 approximate=False)
+        return mm(hidden, self._weight(
+            "router_w3", width, self.spec.moe.routed)), state
+
+    def _cca_mixer(self, x):
+        """Compressed convolutional attention (``ops/cca.py`` has the
+        equations): ``q0``, ``k0`` and the two halves of ``v`` projected
+        into the latent, the value shift, the two causal convolutions over
+        ``[q0 ; k0]``, the q-k mean and the L2 norms with the temperature
+        (scope ``cca_mix``), rotary, grouped causal attention, ``W_o``."""
+        from mpit_tpu.ops import cca as cca_ops
+        from mpit_tpu.ops.flash_attention import flash_attention
+
+        spec, cca, dt = self.spec, self.spec.cca, self.compute_dtype
+        f32 = jnp.float32
+        b, t, d = x.shape
+        h, h_kv, hd = spec.num_heads, spec.num_kv_heads, spec.head_dim
+        weight, proj = self._weight, self._proj
+        with profiling.scope("attn_proj"):
+            y = self._mixer_input(x, "cca_norm")
+            q0 = proj(y, weight("cca_q", d, h * hd)).astype(dt)
+            k0 = proj(y, weight("cca_k", d, h_kv * hd)).astype(dt)
+            v_now, v_back = (proj(y, weight(name, d, h_kv * hd // 2)).astype(dt)
+                             for name in ("cca_v_now", "cca_v_back"))
+            with profiling.scope("cca_mix"):
+                taps0 = self.param(
+                    "cca_conv0",
+                    nn.initializers.lecun_normal(in_axis=-1, out_axis=-2),
+                    ((h + h_kv) * hd, cca.time0), f32)
+                taps1 = self.param(
+                    "cca_conv1", nn.initializers.lecun_normal(
+                        in_axis=(-3, -1), out_axis=-2, batch_axis=(0,)),
+                    (h + h_kv, hd, hd, cca.time1), f32)
+                q, k, v = cca_ops.cca_mix(
+                    q0.reshape(b, t, h, hd), k0.reshape(b, t, h_kv, hd),
+                    v_now, v_back, taps0, taps1.astype(dt),
+                    self.param("cca_temperature", nn.initializers.ones_init(),
+                               (h,), f32))
+            with profiling.scope("rope"):
+                cos, sin = rope_tables(spec.rope, t, hd)
+                swap = rotate_half_matrix(spec.rope, hd)
+                q, k = (apply_rope(a.astype(dt), cos, sin, swap)
+                        for a in (q, k))
+        with profiling.scope("attention"):
+            att = flash_attention(
+                q, k, v.astype(dt), causal=True,
+                use_pallas={"xla": False, "flash": None,
+                            "flash_force": True}[self.attn_impl])
+        with profiling.scope("attn_proj"):
+            return self._residual(x, proj(
+                att.reshape(b, t, h * hd), weight("cca_o", h * hd, d)
+            ), "cca_norm")
 
     def _ssm_mixer(self, x):
         """The Mamba-2 mixer: ``[z | xBC | dt] = u W_in``; ``xBC`` through a
@@ -409,12 +501,14 @@ class Block(nn.Module):
                 x, self._proj(o, self._weight("lin_o", h * dv, d)),
                 "linattn_norm")
 
-    def _held_experts(self, y2):
+    def _held_experts(self, y2, routing=None):
         """The sparse feed-forward's part that lives here
         (``ops/moe.moe_ffn_held``) plus the shared expert, which every
         chip of the deployment computes alike. Routing counters are sown
         into the ``counters`` collection (``aggregate_counters``) and the
-        chosen expert ids into ``routing``."""
+        chosen expert ids into ``routing``. ``routing``, where given, is
+        the choice a router other than one linear map made
+        (``_router_mlp``); no ``moe_router`` leaf is made then."""
         from mpit_tpu.ops import moe as moe_ops
 
         moe, d = self.spec.moe, self.spec.d_model
@@ -426,7 +520,8 @@ class Block(nn.Module):
         shape = lambda name, width: (
             (width, d) if name == "w_down" else (d, width))
         names = moe_ops.EXPERTS[moe.expert][0]
-        params = {"router": self._weight("moe_router", d, moe.routed)}
+        params = {} if routing else {
+            "router": self._weight("moe_router", d, moe.routed)}
         if moe.scoring == "sigmoid":
             # the family's e_score_correction_bias: no gradient reaches it
             # (the choice is discrete); small and non-zero from the seed
@@ -440,7 +535,7 @@ class Block(nn.Module):
         out, counters, (_, experts) = moe_ops.moe_ffn_held(
             params, y2, top_k=moe.top_k, expert_offset=moe.offset,
             row_bound=moe.rows(y2.shape[0]), scale=moe.scale,
-            routing_grad=moe.routing_grad, expert=moe.expert,
+            routing_grad=moe.routing_grad, expert=moe.expert, routing=routing,
         )
         for name, val in counters.items():
             self.sow("counters", name, val)
@@ -792,13 +887,26 @@ class TransformerLM(nn.Module):
         embed = nn.Embed(self.vocab_size, d, dtype=dt, name="Embed_0")
         with profiling.scope("embed"):
             x = embed(tokens)
+            # this repo's key: the looked-up rows times a constant (the
+            # shared table of Vaswani et al., section 3.4, times sqrt(d))
+            scale = float(arch.get("embedding_multiplier", 1.0))
+            if scale != 1.0:
+                x = (x.astype(jnp.float32) * scale).astype(dt)
         block_cls = _RematBlock if self.remat else Block
+        # an MLP router carries its state from block to block, from zeros
+        width = max(s.router_hidden for s in specs)
+        r = (jnp.zeros((*tokens.shape, width), jnp.float32) if width
+             else None)
         for i, spec in enumerate(specs):
-            x = block_cls(
+            block = block_cls(
                 d_model=d, num_heads=spec.num_heads, d_ff=spec.d_ff,
                 compute_dtype=dt, seq_axis=None, attn_impl=self.attn_impl,
                 spec=spec, name=f"Block_{i}",
-            )(x)
+            )
+            if r is None:
+                x = block(x)
+            else:
+                x, r = block(x, r)
         x = rms_norm(
             x, self.param("final_norm", nn.initializers.ones_init(), (d,),
                           jnp.float32),

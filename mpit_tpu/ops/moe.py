@@ -240,7 +240,8 @@ def moe_ffn_dense_reference(
 
 # -- one chip's share of a dropless, sort-dispatched expert layer -----------
 
-def route_top_k(y2, router, top_k: int, scale: float = 1.0, bias=None):
+def route_top_k(y2, router, top_k: int, scale: float = 1.0, bias=None,
+                renormalize: bool = True):
     """Scores over ALL experts in f32, the ``top_k`` largest, their weights
     divided by their sum and multiplied by ``scale``. Without ``bias`` the
     scores are a softmax. With ``bias`` (``(E,)``, the family's
@@ -248,16 +249,20 @@ def route_top_k(y2, router, top_k: int, scale: float = 1.0, bias=None):
     ``top_k`` of ``score + bias`` and the weights are the chosen scores
     WITHOUT it. ``(tokens, D) -> (tokens, k)`` weights and int32 expert ids,
     and the ``(tokens, E)`` scores as shares that sum to 1 a token (the
-    load-balancing term's ``P``)."""
-    logits = jnp.dot(
+    load-balancing term's ``P``). ``router`` None: ``y2`` is already the
+    ``(tokens, E)`` logits, computed elsewhere (an MLP router).
+    ``renormalize`` False (softmax scores): the weights are the chosen
+    probabilities themselves times ``scale``, the Switch rule at top-1."""
+    logits = y2.astype(jnp.float32) if router is None else jnp.dot(
         y2.astype(jnp.float32), router.astype(jnp.float32),
         precision=lax.Precision.HIGHEST,
     )
     if bias is None:
         scores = jax.nn.softmax(logits, axis=-1)
         weights, experts = lax.top_k(scores, top_k)
-        weights = weights / weights.sum(-1, keepdims=True) * scale
-        return weights, experts.astype(jnp.int32), scores
+        if renormalize:
+            weights = weights / weights.sum(-1, keepdims=True)
+        return weights * scale, experts.astype(jnp.int32), scores
     scores = jax.nn.sigmoid(logits)
     _, experts = lax.top_k(scores + lax.stop_gradient(bias), top_k)
     weights = jnp.take_along_axis(scores, experts, axis=-1)
@@ -433,6 +438,7 @@ def moe_ffn_held(
     scale: float = 1.0,
     routing_grad: bool = True,
     expert: str = "swiglu",
+    routing=None,
 ):
     """The part of a sparse expert layer that the experts HELD here give
     (the model-configs guide's section 4): routing scores all
@@ -460,7 +466,10 @@ def moe_ffn_held(
     ``routing_grad=False`` makes the routing weights constants of the
     backward pass (``models/arch.py``, ``moe_routing_no_grad``, says when).
     ``params["bias"]``, where present, makes the scores sigmoids and the
-    choice that of ``score + bias`` (``route_top_k``).
+    choice that of ``score + bias`` (``route_top_k``). ``routing``, where
+    given, is ``route_top_k``'s ``(weights, experts, scores)`` made by the
+    caller (a router that is not one linear map); ``params`` then holds no
+    ``router`` and ``scale`` is the caller's to apply.
 
     ``y``: ``(tokens, D)``. Returns ``(out, counters, (weights,
     experts))``; the counters are f32 scalars: ``rows_held`` (pairs
@@ -472,11 +481,11 @@ def moe_ffn_held(
     tokens, d = y.shape
     names = EXPERTS[expert][0]
     held = params[names[0]].shape[0]
-    routed = params["router"].shape[1]
+    routed = (params["router"] if routing is None else routing[2]).shape[1]
     row_bound = min(row_bound, tokens * top_k)  # there are no more pairs
     chunk = chunk_rows(tokens, top_k, held, routed)
     with profiling.scope("moe_router"):
-        weights, experts, scores = route_top_k(
+        weights, experts, scores = routing or route_top_k(
             y, params["router"], top_k, scale, params.get("bias"))
         if not routing_grad:
             weights = lax.stop_gradient(weights)

@@ -287,3 +287,54 @@ def test_remat_linear_attention_block_compiles_at_8k(
     for scope in ("linattn", "linattn_conv", "delta_rule", "linattn_gate"):
         assert f"/{scope}/" in text, scope
     assert compiled.memory_analysis().temp_size_in_bytes < 2.25 * 2 ** 30
+
+
+def test_remat_cca_block_compiles_at_8k(one_chip, no_compile_cache,
+                                        monkeypatch):
+    """The gradient of one remat'd compressed-convolutional-attention layer
+    and its expert layer at the published widths (hidden 2,048, 8 query and 2
+    KV heads of 128 in the latent, two taps each convolution, a 256-wide MLP
+    router with its carried state, 8 of 16 SwiGLU experts of 2,048, top-1)
+    and 8,192 tokens, as ``zaya1_sync_1chip_8k`` has six of them, with the
+    grouped causal flash kernels compiled for the described chip: one of
+    each, the forward's output kept by the remat. The trace's metrics read
+    the mixing and the router under these scopes. Scratch under 2 GiB beside
+    the layer's gradient."""
+    from mpit_tpu.models import transformer
+
+    monkeypatch.setattr(fa, "pallas_supported", lambda: True)
+    monkeypatch.setattr(fa, "pallas_interpret", lambda: False)
+    t = 8192
+    arch = {
+        "num_hidden_layers": 1, "layer_types": ["hybrid"],
+        "hidden_size": 2048, "num_attention_heads": 8,
+        "num_key_value_heads": 2, "head_dim": 128, "cca_time0": 2,
+        "cca_time1": 2, "router_hidden_size": 256, "num_experts": 8,
+        "num_routed_experts": 16, "num_experts_per_tok": 1,
+        "moe_intermediate_size": 2048, "moe_row_bound": 8192,
+        "moe_routing_no_grad": True, "router_aux_loss_coef": 0.001,
+        "tie_word_embeddings": True, "rms_norm_eps": 1e-5,
+        "rope_parameters": {"hybrid": {"partial_rotary_factor": 0.5,
+                                       "rope_theta": 5000000}},
+    }
+    model = transformer.TransformerLM(vocab_size=256, arch=arch, remat=True,
+                                      attn_impl="flash")
+    on_chip = lambda s: jax.ShapeDtypeStruct(
+        s.shape, s.dtype, sharding=one_chip)
+    params = jax.tree.map(on_chip, jax.eval_shape(
+        lambda: model.init(jax.random.key(0),
+                           jnp.zeros((1, 128), jnp.int32))["params"]))
+    assert params["Block_0"]["cca_conv1"].shape == (10, 128, 128, 2)
+    assert params["Block_0"]["moe_w_up"].shape == (8, 2048, 2048)
+    tokens = on_chip(jax.ShapeDtypeStruct((1, t), jnp.int32))
+    compiled = jax.jit(jax.grad(lambda p, x: model.loss_with_counters(
+        p, x, x)[0])).lower(params, tokens).compile()
+    text = compiled.as_text()
+    calls = {name: len(re.findall(
+        rf"%{name}[. ][^\n]*custom_call_target=\"tpu_custom_call\"", text))
+        for name in ("flash_fwd", "flash_dq", "flash_dkv")}
+    assert calls == {"flash_fwd": 1, "flash_dq": 1, "flash_dkv": 1}
+    for scope in ("cca_mix", "rope", "router_mlp", "moe_router",
+                  "moe_experts", "attention"):
+        assert f"/{scope}/" in text, scope
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * 2 ** 30
