@@ -752,7 +752,9 @@ def aggregate_counters(collection: dict) -> dict:
     (``model.apply(..., mutable=["counters"])``). From the expert layers:
     ``moe_rows_held`` the mean over layers of the pairs routed to the
     experts held, ``moe_rows_walked`` the mean of the buffer rows computed
-    for them, ``moe_load_max_over_mean`` the worst layer's fullest expert
+    for them, ``moe_rows_tiled`` the mean of the rows the grouped products
+    compute (over ``moe_rows_held``: the padding the row tiles pay),
+    ``moe_load_max_over_mean`` the worst layer's fullest expert
     over its mean, ``moe_rows_dropped`` the sum of the rows past the bound,
     ``moe_balance`` the mean of the load-balancing terms (top-k = uniform).
     From the Mamba-2 layers: ``ssm_chunk_log_decay_min``, the most negative
@@ -762,6 +764,7 @@ def aggregate_counters(collection: dict) -> dict:
     reduce = {
         "rows_held": ("moe_rows_held", jnp.mean),
         "rows_walked": ("moe_rows_walked", jnp.mean),
+        "rows_tiled": ("moe_rows_tiled", jnp.mean),
         "load_max_over_mean": ("moe_load_max_over_mean", jnp.max),
         "rows_dropped": ("moe_rows_dropped", jnp.sum),
         "balance": ("moe_balance", jnp.mean),

@@ -41,6 +41,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from mpit_tpu.ops import grouped
 from mpit_tpu.utils import profiling
 
 
@@ -320,36 +321,51 @@ def chunk_rows(tokens: int, top_k: int, held: int, routed: int) -> int:
     return 2 * math.ceil(tokens * top_k * held / routed / 256) * 256
 
 
-def _expert_rows(expert, rows, weights, row_weight, ends, start):
+def _group_sizes(ends, start, n):
+    """Each expert's rows among the buffer's rows ``start .. start + n``
+    (``ends``: where each expert's rows end in the whole buffer)."""
+    span = jnp.clip(ends - start, 0, n)
+    return span, jnp.diff(span, prepend=0).astype(jnp.int32)
+
+
+def _expert_rows(expert, rows, weights, row_weight, ends, start, per_group):
     """The experts (``EXPERTS[expert]``) over ``rows``, which are rows
     ``start .. start + len(rows)`` of the buffer sorted by expert (``ends``:
     where each expert's rows end in the whole buffer), times ``row_weight``,
-    in f32. The weights come in ``rows``' dtype."""
+    in f32. The weights come in ``rows``' dtype. ``per_group``: the rows an
+    expert holds under uniform routing where the products are ``ops/
+    grouped``'s kernels (``moe_ffn_held`` decides), else None."""
     n, dt = rows.shape[0], rows.dtype
     with profiling.scope("moe_dispatch"):
-        span = jnp.clip(ends - start, 0, n)
-        sizes = jnp.diff(span, prepend=0).astype(jnp.int32)
-        valid = jnp.arange(n) < span[-1]
+        span, sizes = _group_sizes(ends, start, n)
+        if not per_group:
+            valid = jnp.arange(n) < span[-1]
     with profiling.scope("moe_experts"):
-        # a grouped product leaves the rows past its groups as they were
-        # in memory (seen on the v5e, PR 27: the gradient into such rows
-        # came back as garbage 1e5 times the true one), so every operand
-        # and result is cut to the valid rows, forward and backward
-        live = lambda a: jnp.where(valid[:, None], a, 0)
-        grouped = lambda a, w: live(lax.ragged_dot(
-            live(a), w, sizes, preferred_element_type=jnp.float32
-        ).astype(dt))
-        out_rows = EXPERTS[expert][1](grouped, rows, *weights)
+        if per_group:  # the kernels write 0 past the groups, both ways
+            product = lambda a, w: grouped.gmm(
+                a, w, sizes, grouped.choose_tiles(per_group, *w.shape[1:], dt))
+        else:
+            # a grouped product leaves the rows past its groups as they were
+            # in memory (seen on the v5e: the gradient into such rows came
+            # back as garbage 1e5 times the true one), so every operand
+            # and result is cut to the valid rows, forward and backward
+            live = lambda a: jnp.where(valid[:, None], a, 0)
+            product = lambda a, w: live(lax.ragged_dot(
+                live(a), w, sizes, preferred_element_type=jnp.float32
+            ).astype(dt))
+        out_rows = EXPERTS[expert][1](product, rows, *weights)
     with profiling.scope("moe_dispatch"):
         return out_rows.astype(jnp.float32) * row_weight[:, None]
 
 
-def _add_rows(expert, out, y, weights, token, row_weight, ends, start):
+def _add_rows(expert, per_group, out, y, weights, token, row_weight, ends,
+              start):
     """``out`` with the experts' weighted outputs for the buffer's rows
     ``start .. start + len(token)`` added at their tokens."""
     with profiling.scope("moe_dispatch"):
         rows = jnp.take(y, token, axis=0)
-    add = _expert_rows(expert, rows, weights, row_weight, ends, start)
+    add = _expert_rows(expert, rows, weights, row_weight, ends, start,
+                       per_group)
     with profiling.scope("moe_dispatch"):
         return out.at[token].add(add)
 
@@ -366,8 +382,8 @@ def _live_chunks(chunk, ends):
     return -(-ends[-1] // chunk)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
-def _walk(chunk, expert, y, weights, row_weight, token, ends):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
+def _walk(chunk, expert, per_group, y, weights, row_weight, token, ends):
     """``zeros.at[token].add(_expert_rows(y[token], ...))`` in ``y``'s
     dtype, the buffer walked ``chunk`` rows at a time and only as far as
     its last live row, ``ends[-1]``: one loop with a traced trip count
@@ -376,13 +392,15 @@ def _walk(chunk, expert, y, weights, row_weight, token, ends):
     remat would) and keeps the cotangents as carries updated in place;
     the weights' carries have the weights' dtype, as the single pass's
     cotangents have, and a chunk's row sums are f32 inside its product."""
-    return _walk_fwd(chunk, expert, y, weights, row_weight, token, ends)[0]
+    return _walk_fwd(chunk, expert, per_group, y, weights, row_weight, token,
+                     ends)[0]
 
 
-def _walk_fwd(chunk, expert, y, weights, row_weight, token, ends):
+def _walk_fwd(chunk, expert, per_group, y, weights, row_weight, token, ends):
     def body(j, out):
         start, tok, weight = _chunk_of(chunk, j, token, row_weight)
-        return _add_rows(expert, out, y, weights, tok, weight, ends, start)
+        return _add_rows(expert, per_group, out, y, weights, tok, weight,
+                         ends, start)
 
     with profiling.scope("moe_dispatch"):
         out = lax.fori_loop(0, _live_chunks(chunk, ends), body,
@@ -390,7 +408,7 @@ def _walk_fwd(chunk, expert, y, weights, row_weight, token, ends):
     return out, (y, weights, row_weight, token, ends)
 
 
-def _walk_bwd(chunk, expert, residuals, ct):
+def _walk_bwd(chunk, expert, per_group, residuals, ct):
     y, weights, row_weight, token, ends = residuals
 
     def body(j, carry):
@@ -401,7 +419,7 @@ def _walk_bwd(chunk, expert, residuals, ct):
             ct_rows = jnp.take(ct, tok, axis=0).astype(jnp.float32)
         _, vjp = jax.vjp(
             lambda rows, weights, weight: _expert_rows(
-                expert, rows, weights, weight, ends, start),
+                expert, rows, weights, weight, ends, start, per_group),
             rows, weights, weight)
         d_rows, d_chunk, d_weight = vjp(ct_rows)
         with profiling.scope("moe_dispatch"):
@@ -451,10 +469,11 @@ def moe_ffn_held(
 
     Dropless by sorting, not by capacity: the (token, choice) pairs that
     name an expert held here are sorted by expert into one buffer of
-    ``row_bound`` rows, grouped matrix products (``jax.lax.ragged_dot``,
-    the TPU compiler's own grouped kernel; three for SwiGLU experts, two
-    for ``relu2``) run the experts over it, and the rows are scattered back times
-    their weights. ``row_bound`` is static; pairs past it are dropped
+    ``row_bound`` rows, grouped matrix products (three for SwiGLU experts,
+    two for ``relu2``) run the experts over it (``ops/grouped``'s Pallas
+    kernels on a TPU where the shape tiles, ``grouped.takes``; the
+    compiler's ``jax.lax.ragged_dot`` everywhere else), and the rows are
+    scattered back times their weights. ``row_bound`` is static; pairs past it are dropped
     and COUNTED (``rows_dropped``; a correct run reads 0).
 
     The bound is for the imbalance a step may meet, not for the step at
@@ -474,9 +493,11 @@ def moe_ffn_held(
     ``y``: ``(tokens, D)``. Returns ``(out, counters, (weights,
     experts))``; the counters are f32 scalars: ``rows_held`` (pairs
     routed to the experts held), ``rows_walked`` (buffer rows computed),
-    ``load_max_over_mean`` (the fullest expert's rows over the mean),
-    ``rows_dropped`` and ``balance`` (the load-balancing term, the one
-    counter a gradient passes through).
+    ``rows_tiled`` (the rows the grouped products compute: where they are
+    ``ops/grouped``'s kernels the row tiles they visit times the tile,
+    else ``rows_walked``), ``load_max_over_mean`` (the fullest expert's
+    rows over the mean), ``rows_dropped`` and ``balance`` (the
+    load-balancing term, the one counter a gradient passes through).
     """
     tokens, d = y.shape
     names = EXPERTS[expert][0]
@@ -520,22 +541,41 @@ def moe_ffn_held(
             experts_w = [jnp.pad(w, ((0, 0), (0, 0), (0, pad)))
                          for w in experts_w[:-1]] + [
                 jnp.pad(experts_w[-1], ((0, 0), (0, pad), (0, 0)))]
-    if row_bound <= chunk:  # one chunk: no loop
-        out = _add_rows(expert, jnp.zeros((tokens, d), jnp.float32), y,
-                        experts_w, token, row_weight, ends, 0).astype(y.dtype)
+    one_pass = row_bound <= chunk
+    rows = row_bound if one_pass else chunk  # the rows a product takes
+    # the rows an expert holds under uniform routing, where the products
+    # are ``ops/grouped``'s kernels (a TPU, a shape that tiles), else None
+    per_group = chunk // 2 // held
+    if not grouped.takes(grouped.pallas_supported(), rows, d,
+                         experts_w[-1].shape[1], per_group):
+        per_group = None
+    if one_pass:  # no loop
+        out = _add_rows(expert, per_group,
+                        jnp.zeros((tokens, d), jnp.float32), y, experts_w,
+                        token, row_weight, ends, 0).astype(y.dtype)
         rows_walked = jnp.float32(row_bound)
     else:
         with profiling.scope("moe_dispatch"):
             # whole chunks; what lies past the bound stays past ``ends``
             pad = (0, -row_bound % chunk)
             token, row_weight = jnp.pad(token, pad), jnp.pad(row_weight, pad)
-        out = _walk(chunk, expert, y, experts_w, row_weight, token, ends)
+        out = _walk(chunk, expert, per_group, y, experts_w, row_weight,
+                    token, ends)
         rows_walked = (_live_chunks(chunk, ends) * chunk).astype(jnp.float32)
     with profiling.scope("moe_dispatch"):
+        if per_group:  # the row tiles the kernels visit, over every pass
+            tm = grouped.choose_tiles(per_group, d, experts_w[-1].shape[1],
+                                      y.dtype)[0][0]
+            rows_tiled = sum(
+                grouped.rows_tiled(_group_sizes(ends, start, rows)[1], tm)
+                for start in range(0, row_bound, rows)).astype(jnp.float32)
+        else:
+            rows_tiled = rows_walked
         total = counts.sum().astype(jnp.float32)
         counters = {
             "rows_held": total,
             "rows_walked": rows_walked,
+            "rows_tiled": rows_tiled,
             "load_max_over_mean": counts.max() * held / jnp.maximum(total, 1.0),
             "rows_dropped": jnp.maximum(total - row_bound, 0.0),
             "balance": balance,

@@ -46,7 +46,8 @@ def main(root: str, cell: str, out: str) -> int:
     from mpit_tpu import run as program
     from mpit_tpu.parallel import common, easgd
 
-    for name in ("flash_attention", "ssd", "gated_delta"):  # as the chip chooses
+    # as the chip chooses
+    for name in ("flash_attention", "ssd", "gated_delta", "grouped"):
         try:
             ops = importlib.import_module(f"mpit_tpu.ops.{name}")
         except ImportError:

@@ -338,3 +338,64 @@ def test_remat_cca_block_compiles_at_8k(one_chip, no_compile_cache,
                   "moe_experts", "attention"):
         assert f"/{scope}/" in text, scope
     assert compiled.memory_analysis().temp_size_in_bytes < 2 * 2 ** 30
+
+
+@pytest.mark.parametrize("rows_per_group,rows,d,width", [
+    (320, 5120, 3072, 1024),  # laguna_s21_sync_1chip_8k: a 5,120-row chunk
+    (384, 6144, 2688, 2048),  # nemotron3_nano_sync_1chip_8k, width padded
+    (512, 8192, 2048, 2048),  # zaya1_sync_1chip_8k: one 8,192-row pass
+], ids=["laguna", "nemotron", "zaya"])
+def test_grouped_kernels_compile_at_the_expert_cells_shapes(
+        rows_per_group, rows, d, width, one_chip, no_compile_cache):
+    """The held experts' grouped products (``ops/grouped``) into the width
+    and out of it, forward and both cotangents, at the tiles
+    ``choose_tiles`` gives each cell's shape: three Mosaic kernels a
+    product, named after their scopes."""
+    from mpit_tpu.ops import grouped
+
+    on_chip = lambda *s, dt=jnp.bfloat16: jax.ShapeDtypeStruct(
+        s, dt, sharding=one_chip)
+    sizes = on_chip(8, dt=jnp.int32)
+    for k, n in ((d, width), (width, d)):
+        tiles = grouped.choose_tiles(rows_per_group, k, n, jnp.bfloat16)
+
+        def loss(a, w, s):  # squared, so that the backward needs the output
+            out = grouped._gmm(a, w, s, tiles, False)
+            return (out.astype(jnp.float32) ** 2).sum()
+
+        text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+            on_chip(rows, k), on_chip(8, k, n), sizes).compile().as_text()
+        calls = {name: len(re.findall(
+            rf"%{name}\.\d+ [^\n]*custom_call_target=\"tpu_custom_call\"",
+            text)) for name in ("moe_gmm", "moe_tgmm")}
+        assert calls == {"moe_gmm": 2, "moe_tgmm": 1}, (k, n, calls)
+
+
+def test_expert_layer_takes_the_grouped_kernels_on_the_chip(
+        one_chip, no_compile_cache, monkeypatch):
+    """One remat'd expert layer at ``zaya1_sync_1chip_8k``'s shape (8,192
+    tokens of 2,048, top-1 of 16, 8 SwiGLU experts of 2,048 held), chosen
+    as on the chip: the grouped products are the kernels, under
+    ``moe_experts``, and no ``ragged_dot`` is left."""
+    from mpit_tpu.ops import grouped, moe
+
+    monkeypatch.setattr(grouped, "pallas_supported", lambda: True)
+    monkeypatch.setattr(grouped, "pallas_interpret", lambda: False)
+    on_chip = lambda *s, dt=jnp.float32: jax.ShapeDtypeStruct(
+        s, dt, sharding=one_chip)
+    params = {"router": on_chip(2048, 16), "w_gate": on_chip(8, 2048, 2048),
+              "w_up": on_chip(8, 2048, 2048), "w_down": on_chip(8, 2048, 2048)}
+
+    def loss(params, y):
+        out, counters, _ = moe.moe_ffn_held(params, y, top_k=1,
+                                            row_bound=8192, routing_grad=False)
+        return (out.astype(jnp.float32) ** 2).sum(), counters
+
+    text = jax.jit(jax.grad(jax.checkpoint(loss), argnums=(0, 1),
+                            has_aux=True)).lower(
+        params, on_chip(8192, 2048, dt=jnp.bfloat16)).compile().as_text()
+    kernels = re.findall(r"%(moe_t?gmm)\.\d+ [^\n]*op_name=\"([^\"]*)\"", text)
+    assert {name for name, _ in kernels} == {"moe_gmm", "moe_tgmm"}
+    for name, path in kernels:  # moe_experts/jit(_rows_call)/moe_gmm/...
+        assert re.search(rf"/moe_experts/(jit\([^/]*\)/)?{name}/", path), path
+    assert "ragged" not in text

@@ -337,12 +337,14 @@ def test_counters_and_routing_are_sown(problem):
         {"params": p}, tokens, mutable=["counters", "routing"]))(params)
     counters = aggregate_counters(sown["counters"])
     assert set(counters) == {"moe_rows_held", "moe_rows_walked",
-                             "moe_load_max_over_mean", "moe_rows_dropped",
-                             "moe_balance"}
+                             "moe_rows_tiled", "moe_load_max_over_mean",
+                             "moe_rows_dropped", "moe_balance"}
     assert 3 <= float(counters["moe_balance"]) < 6  # top-k 3 = uniform
     assert float(counters["moe_rows_dropped"]) == 0
     assert 0 < float(counters["moe_rows_held"]) <= 96
     assert float(counters["moe_rows_walked"]) == 96  # one chunk: the bound
+    # on the CPU the products are ragged_dot's, which walk the whole chunk
+    assert float(counters["moe_rows_tiled"]) == 96
     assert sorted(sown["routing"]) == [f"Block_{l}" for l in range(1, 5)]
 
 
